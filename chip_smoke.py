@@ -1,0 +1,743 @@
+#!/usr/bin/env python
+"""Quickest proof that the system still starts on the chip.
+
+    python chip_smoke.py             # one TPU chip: train, kernels, serve
+    python chip_smoke.py --chips 4   # four chips: the dp train step and the
+                                     # one-chip step it is compared with
+    python chip_smoke.py --rehearse [--chips 4]
+                                     # the same control flow at toy sizes on
+                                     # whatever JAX finds (JAX_PLATFORMS=cpu)
+
+It drives the two normal paths through their ordinary entry points:
+
+* ``train``   ResNet-50 v1 (224x224, 1000 classes) as a Gluon HybridBlock ->
+  ``amp.convert_block`` -> ``fuse.make_fused_train_step``, batch 256 resident on
+  the device; plus the plain reference: the float32 net at batch 8 for one step
+  on the chip and on the host's CPU device in the same process.
+* ``kernels`` every Pallas kernel the default mode dispatches on a TPU, once,
+  forward and backward at a real width, against its XLA composition.
+* ``serve``   ``deploy.export_model`` with AOT buckets on the chip, then
+  ``python -m incubator_mxnet_tpu.serving.server`` answering ``:predict`` over
+  HTTP with nothing compiled, then SIGTERM.
+
+A chip belongs to one process at a time, so this parent never imports JAX: every
+phase that needs the chip is one child of its own, one at a time.  Any phase
+failing makes the exit code non-zero.  Only a run whose every phase ran on a TPU
+prints, as its last line,
+``{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}``;
+a rehearsal's last line says it was one and never holds ``"ok"``.
+Everything the run uses comes from files git would commit, from ``--seed``, or
+from the run itself: the native library is rebuilt from ``src/`` first.
+"""
+from __future__ import annotations
+
+import argparse
+import json
+import math
+import os
+import shutil
+import signal
+import subprocess
+import sys
+import tempfile
+import threading
+import time
+import urllib.request
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+RESULT_TAG = "PHASE_RESULT "
+CHILD_TIMEOUT_S = 900
+SGD = {"learning_rate": 0.1, "momentum": 0.9, "wd": 1e-4}
+
+
+def say(msg):
+    print(msg, flush=True)
+
+
+class PhaseFailed(Exception):
+    pass
+
+
+def check(cond, msg):
+    if not cond:
+        raise PhaseFailed(msg)
+
+
+# ======================================================================
+# children: everything below this line runs in a process that owns the chip
+# ======================================================================
+
+def _device():
+    """The device as JAX reports it (a backend that cannot start raises)."""
+    sys.path.insert(0, HERE)
+    import jax
+    dev = jax.devices()[0]
+    return {"platform": dev.platform, "kind": dev.device_kind,
+            "count": len(jax.devices())}
+
+
+def _child_setup(args):
+    """Common start of a child: the device, and the compile-cache rule."""
+    device = _device()
+    check(args.rehearse or device["platform"] == "tpu",
+          f"JAX's default backend is {device['platform']!r}, not a TPU "
+          "(only --rehearse tolerates that)")
+    import jax
+    from incubator_mxnet_tpu import executor_cache
+    cache_dir = executor_cache.ensure_compile_cache()
+    say(f"[{args.child}] device {device}  compile cache {cache_dir}")
+    return jax, device
+
+
+def _build_net(rehearse, seed):
+    """ResNet-50 v1 at published widths (a toy bottleneck net when
+    rehearsing), initialized from the seed through the Gluon entry points."""
+    import incubator_mxnet_tpu as mx
+    from incubator_mxnet_tpu import nd
+    from incubator_mxnet_tpu.gluon.model_zoo.vision import resnet
+    mx.random.seed(seed)
+    if rehearse:
+        net = resnet.ResNetV1(resnet.BottleneckV1, [1, 1], [8, 32, 64],
+                              classes=10, thumbnail=True)
+    else:
+        net = resnet.resnet50_v1()
+    net.initialize()
+    net(nd.random.uniform(shape=(1, 3, 32, 32)))   # resolve deferred shapes
+    return net
+
+
+def _batch(rehearse, seed, bs, dtype, sharding=None):
+    """One fixed batch made on the host from the seed, resident on the
+    device(s)."""
+    import numpy as onp
+    import jax
+    import jax.numpy as jnp
+    px, classes = (32, 10) if rehearse else (224, 1000)
+    rng = onp.random.RandomState(seed)
+    x = jnp.asarray(rng.rand(bs, 3, px, px).astype(onp.float32), dtype)
+    y = jnp.asarray(rng.randint(0, classes, (bs,)), jnp.int32)
+    where = sharding if sharding is not None else jax.devices()[0]
+    return jax.device_put(x, where), jax.device_put(y, where), classes
+
+
+def _make_step(net, **kw):
+    from incubator_mxnet_tpu import gluon
+    from incubator_mxnet_tpu.fuse import make_fused_train_step
+    return make_fused_train_step(
+        net, gluon.loss.SoftmaxCrossEntropyLoss(), "sgd", dict(SGD), **kw)
+
+
+def _state_leaves(step):
+    import jax
+    return jax.tree_util.tree_leaves((step.params, step.aux, step.opt_state))
+
+
+def _check_losses(losses, classes):
+    """Finite, and the first within tolerance of ln(classes)."""
+    check(all(math.isfinite(v) for v in losses), f"non-finite loss: {losses}")
+    # ln C is the loss of uniform logits; the zoo's Dense init gives logits a
+    # standard deviation of 1-2, which adds up to ~2.5 to it (9.13 at float32
+    # batch 8 on the CPU)
+    check(abs(losses[0] - math.log(classes)) < 3.0,
+          f"first loss {losses[0]:.3f} is not within 3.0 of "
+          f"ln {classes} = {math.log(classes):.3f}")
+
+
+def child_train(args):
+    jax, device = _child_setup(args)
+    import jax.numpy as jnp
+    from bench import PEAK_FLOPS, TRAIN_FLOPS_PER_IMG  # one table of peaks
+    from incubator_mxnet_tpu import amp, native
+    check(native.available(), "libmxtpu.so did not load after the rebuild")
+    say(f"[train] native runtime library loaded: {native._LIB_PATH}")
+    bs = 8 if args.rehearse else 256
+    steps = 10
+
+    net = _build_net(args.rehearse, args.seed)
+    amp.convert_block(net, "bfloat16")
+    step = _make_step(net)
+    x, y, classes = _batch(args.rehearse, args.seed, bs, jnp.bfloat16)
+    off_chip = [leaf for leaf in _state_leaves(step) + [x, y]
+                if {d.platform for d in leaf.devices()} != {device["platform"]}]
+    check(not off_chip, f"{len(off_chip)} train-state leaves are not on a "
+          f"{device['platform']} device")
+    say(f"[train] {len(_state_leaves(step))} leaves of params/aux/"
+        f"opt_state all report a {device['platform']} device")
+
+    t0 = time.perf_counter()
+    losses = [float(step(x, y))]
+    compile_s = time.perf_counter() - t0
+    # two timed windows closed in the two ways the repo has used: a host
+    # readback of the loss, and block_until_ready
+    half = (steps - 1) // 2
+    t0 = time.perf_counter()
+    window = [step(x, y) for _ in range(half)]
+    losses += [float(v) for v in window]          # readback of the last syncs
+    readback_ms = 1e3 * (time.perf_counter() - t0) / half
+    t0 = time.perf_counter()
+    window = [step(x, y) for _ in range(steps - 1 - half)]
+    jax.block_until_ready(window[-1])
+    block_ms = 1e3 * (time.perf_counter() - t0) / len(window)
+    losses += [float(v) for v in window]
+    say(f"[train] bs={bs} bfloat16 compile+first step {compile_s:.1f}s; "
+        f"step {block_ms:.2f} ms closed by block_until_ready, "
+        f"{readback_ms:.2f} ms closed by host readback")
+    say(f"[train] losses {' '.join(f'{v:.3f}' for v in losses)}")
+    _check_losses(losses, classes)
+    # lr 0.1 with momentum on a fresh net overshoots for a few steps (the
+    # loss climbs before it settles); memorizing one batch it must get below
+    # where it started at some point of the ten
+    check(min(losses[1:]) < losses[0],
+          f"loss never fell below its first value on one fixed batch: "
+          f"{losses}")
+    check(step._executor.compile_count == 1,
+          f"the train step compiled {step._executor.compile_count} times "
+          "for one batch shape")
+    say("[train] compile_count == 1 for the step")
+    img_s = None
+    if not args.rehearse:
+        # block_until_ready has to wait for the device: a window it closes
+        # cannot be shorter than the chip's compute-bound minimum, nor much
+        # shorter than the same steps closed by a readback
+        check(device["kind"] in PEAK_FLOPS, f"no peak FLOP/s on record for "
+              f"device kind {device['kind']!r}")
+        floor_ms = 1e3 * bs * TRAIN_FLOPS_PER_IMG / PEAK_FLOPS[device["kind"]]
+        check(block_ms >= floor_ms and block_ms >= 0.8 * readback_ms,
+              f"block_until_ready returned early: {block_ms:.2f} ms a step "
+              f"against a {floor_ms:.2f} ms compute floor and "
+              f"{readback_ms:.2f} ms by readback")
+        img_s = bs * 1e3 / block_ms
+        say(f"[train] sync: block_until_ready waits for the device "
+            f"(>= the {floor_ms:.1f} ms compute floor, and agrees with the "
+            f"readback window); the readback-only discipline is not needed. "
+            f"Smoke observation, not a benchmark: {img_s:.0f} img/s")
+
+    # plain reference: the float32 net, one step, on the chip and on the host
+    ref_bs = 8
+    ref_net = _build_net(args.rehearse, args.seed)  # a step copies its params
+
+    def one_step_loss(target, precision):
+        with jax.default_matmul_precision(precision):
+            ref_step = _make_step(ref_net)
+            ref_step.params, ref_step.aux, ref_step.opt_state, \
+                ref_step._key = jax.device_put(
+                    (ref_step.params, ref_step.aux, ref_step.opt_state,
+                     ref_step._key), target)
+            xr, yr, _ = _batch(args.rehearse, args.seed, ref_bs,
+                               jnp.float32)
+            loss = ref_step(jax.device_put(xr, target),
+                            jax.device_put(yr, target))
+        check(loss.devices() == {target},
+              f"a reference step ran on {loss.devices()}, not {target}")
+        return float(loss)
+
+    want = one_step_loss(jax.devices("cpu")[0], "highest")
+    got = one_step_loss(jax.devices()[0], "highest")
+    default = one_step_loss(jax.devices()[0], "default")
+    # the same float32 function on both: what is left is summation order
+    tol = 1e-3 * abs(want)
+    # by default the MXU multiplies float32 in bfloat16 passes; through 53
+    # layers with batch-8 BatchNorm that moves the loss by a percent or two
+    # (1.8% seen), so the default mode is held to 5% of the reference only
+    loose = 5e-2 * abs(want)
+    say(f"[train] float32 bs={ref_bs} one-step loss: host cpu {want:.5f}  "
+        f"chip at highest matmul precision {got:.5f} (|diff| "
+        f"{abs(got - want):.1e}, tolerance {tol:.1e})  chip at default "
+        f"precision {default:.5f} (|diff| {abs(default - want):.1e}, "
+        f"tolerance {loose:.1e})")
+    check(abs(got - want) <= tol and abs(default - want) <= loose,
+          "chip and host-CPU losses disagree")
+    return {"device": device, "compile_s": round(compile_s, 1),
+            "step_ms": round(block_ms, 2),
+            "img_per_s": img_s and round(img_s)}
+
+
+# ---------------------------------------------------------------- kernels
+
+def _kernel_cases(rehearse):
+    """(name, fn, arg specs, has_kernel[, reference fn]) — fn goes through the op layer's own
+    dispatch (a registered op's ``.fn`` is its body: calling the Op itself
+    would replay the first trace from its per-op jit cache, whatever
+    MXNET_USE_PALLAS says by then); specs are (shape, dtype, kind): normal = N(0,1) activations,
+    weight = N(0, 1/fan_in) so a matmul keeps them O(1), ones = 1 + 0.1 N(0,1)
+    scales, label = class ids.  Differentiable arguments come first."""
+    import jax.numpy as jnp
+    from incubator_mxnet_tpu.ops import nn_ops, pallas_kernels as pk
+    from incubator_mxnet_tpu.ops.fused_block import fused_matmul_bn
+    from incubator_mxnet_tpu.ops.fused_conv import fused_conv3_bn
+    bf, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    t = rehearse   # toy shapes keep every branch (padding, several blocks)
+    cases = [
+        # BERT-base activations (batch 32 x seq 512, hidden 768)
+        ("layer_norm", lambda x, g, b: nn_ops.layer_norm.fn(x, g, b),
+         [((264, 130) if t else (16384, 768), bf, "normal"),
+          ((130,) if t else (768,), f32, "ones"),
+          ((130,) if t else (768,), f32, "normal")], True),
+        ("rms_norm", lambda x, g: nn_ops.rms_norm.fn(x, g),
+         [((264, 130) if t else (8192, 1024), f32, "normal"),
+          ((130,) if t else (1024,), f32, "ones")], True),
+        # BERT-base attention probabilities (8 x 12 heads x 512 x 512)
+        ("softmax", lambda x: nn_ops.softmax.fn(x, axis=-1),
+         [((2, 2, 16, 130) if t else (8, 12, 512, 512), bf, "normal")], True),
+        ("softmax_xent", lambda x, l: nn_ops.softmax_xent.fn(x, l),
+         [((16, 10) if t else (256, 1000), f32, "normal"),
+          ((16,) if t else (256,), i32, "label")], True),
+        # off-TPU flash_attention always runs its (interpreted) kernel, so its
+        # XLA composition is named here and not reached through the flag
+        ("flash_attention",
+         lambda q, k, v: pk.flash_attention(q, k, v, causal=True),
+         [((1, 2, 256, 32) if t else (8, 16, 2048, 64), bf, "normal")] * 3,
+         True, lambda q, k, v: pk._xla_attention(
+             q, k, v, q.shape[-1] ** -0.5, True)),
+    ]
+    if not t:
+        # a 32k-vocabulary LM loss: rows wider than pallas_kernels._MAX_COLS
+        # are routed to the XLA formulation by shape — checked as that route
+        cases.append(
+            ("softmax_xent_32k", lambda x, l: nn_ops.softmax_xent.fn(x, l),
+             [((4096, 32000), bf, "normal"), ((4096,), i32, "label")], False))
+    # the fused bottleneck's kernels at the four ResNet-50 stage widths, bs 256
+    bs = 2 if t else 256
+    stages = [(8, 16, 64)] if t else [(56, 64, 256), (28, 128, 512),
+                                      (14, 256, 1024), (7, 512, 2048)]
+    for hw, cm, co in stages:
+        m = bs * hw * hw
+        cases += [
+            (f"fused_matmul_bn[{hw}x{hw} {co}->{cm}]",
+             lambda x, w: fused_matmul_bn(x, w),
+             [((m, co), bf, "normal"), ((co, cm), bf, "weight")], True),
+            (f"fused_matmul_bn[{hw}x{hw} {cm}->{co} prologue]",
+             lambda x, w, s, b: fused_matmul_bn(x, w, s, b),
+             [((m, cm), bf, "normal"), ((cm, co), bf, "weight"),
+              ((cm,), f32, "ones"), ((cm,), f32, "normal")], True),
+            (f"fused_conv3_bn[{hw}x{hw}x{cm} prologue]",
+             lambda x, w, s, b: fused_conv3_bn(x, w, s, b),
+             [((bs, hw, hw, cm), bf, "normal"), ((3, 3, cm, cm), bf, "weight"),
+              ((cm,), f32, "ones"), ((cm,), f32, "normal")], True),
+        ]
+    return cases
+
+
+def child_kernels(args):
+    jax, device = _child_setup(args)
+    import jax.numpy as jnp
+    on_tpu = device["platform"] == "tpu"
+
+    def make_args(specs, key):
+        out = []
+        for i, (shape, dtype, kind) in enumerate(specs):
+            k = jax.random.fold_in(key, i)
+            if kind == "label":
+                out.append(jax.random.randint(k, shape, 0, 10, dtype))
+                continue
+            v = jax.random.normal(k, shape, jnp.float32)
+            if kind == "weight":
+                v = v * math.prod(shape[:-1]) ** -0.5
+            elif kind == "ones":
+                v = 1.0 + 0.1 * v
+            out.append(v.astype(dtype))
+        return out
+
+    def rel_errs(got, want):
+        def one(a, b):
+            a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+            return jnp.max(jnp.abs(a - b)) / (jnp.max(jnp.abs(b)) + 1e-6)
+        return jnp.stack(jax.tree_util.tree_leaves(
+            jax.tree_util.tree_map(one, got, want)))
+
+    def fwd_bwd(fn, n_diff):
+        def run(cts, *a):
+            out, vjp = jax.vjp(lambda *d: fn(*d, *a[n_diff:]), *a[:n_diff])
+            return out, vjp(cts)
+        return run
+
+    def compiled_under(flag, run, *a):
+        # MXNET_USE_PALLAS is read while tracing: "auto" is the default mode
+        # on a TPU, "1" reaches the same kernels interpreted off-TPU
+        # (rehearsal), "0" is the repo's own XLA composition — the reference
+        os.environ["MXNET_USE_PALLAS"] = flag
+        try:
+            return jax.jit(lambda *b: run(*b)).lower(*a).compile()
+        finally:
+            del os.environ["MXNET_USE_PALLAS"]
+
+    key = jax.random.PRNGKey(args.seed)
+    for n, (name, fn, specs, has_kernel, *ref_fn) in enumerate(_kernel_cases(
+            args.rehearse)):
+        # everything the case needs, and its verdict, are one program each:
+        # every eager op on the chip is a small compile of its own
+        out_shape = jax.eval_shape(
+            fn, *(jax.ShapeDtypeStruct(sh, dt) for sh, dt, _k in specs))
+        a, cts = jax.jit(lambda k: (
+            make_args(specs, k),
+            jax.tree_util.tree_map(
+                lambda s: jax.random.normal(
+                    jax.random.fold_in(k, 99), s.shape, jnp.float32
+                ).astype(s.dtype), out_shape)))(jax.random.fold_in(key, n))
+        n_diff = sum(1 for _, dtype, _k in specs if dtype != jnp.int32)
+        run = fwd_bwd(fn, n_diff)
+        t0 = time.perf_counter()
+        kernel = compiled_under("auto" if on_tpu else "1", run, cts, *a)
+        ref = compiled_under(
+            "0", fwd_bwd(ref_fn[0], n_diff) if ref_fn else run, cts, *a)
+        compile_s = time.perf_counter() - t0
+        calls = kernel.as_text().count("tpu_custom_call")
+        if on_tpu:
+            check((calls > 0) == has_kernel and "tpu_custom_call"
+                  not in ref.as_text(),
+                  f"{name}: {calls} tpu_custom_call(s) in the default-mode "
+                  f"program, expected {'some' if has_kernel else 'none'}")
+        errs = [float(e) for e in jax.jit(rel_errs)(kernel(cts, *a),
+                                                     ref(cts, *a))]
+        check(all(math.isfinite(e) for e in errs), f"{name}: non-finite")
+        # both sides accumulate in float32; what differs is the order of
+        # summation and where a bfloat16 rounding falls
+        tol = 5e-2 if any(d == jnp.bfloat16 for _, d, _k in specs) else 2e-3
+        route = (f"tpu_custom_call x{calls}" if calls else
+                 "interpreted kernel" if has_kernel else
+                 "XLA by shape (cols > _MAX_COLS)")
+        say(f"[kernels] {name}: {route}; fwd+bwd max rel err "
+            f"{max(errs):.1e} (tolerance {tol:.0e}); compiled both in "
+            f"{compile_s:.1f}s")
+        check(max(errs) <= tol, f"{name}: kernel and XLA composition disagree "
+              f"(rel errs {errs})")
+    return {"device": device}
+
+
+# ------------------------------------------------------------------ serve
+
+def child_export(args):
+    """Export ResNet-50 v1 inference (bf16, NCHW) with AOT buckets, and save
+    the answers the server will be held to."""
+    jax, device = _child_setup(args)
+    import numpy as onp
+    import jax.numpy as jnp
+    from incubator_mxnet_tpu import amp, deploy
+    net = _build_net(args.rehearse, args.seed)
+    amp.convert_block(net, "bfloat16")
+    x8, _, _ = _batch(args.rehearse, args.seed + 1, 8, jnp.bfloat16)
+    prefix = os.path.join(args.workdir, "r50")
+    t0 = time.perf_counter()
+    meta = deploy.export_model(net, (x8,), prefix, aot_buckets=[1, 8])
+    say(f"[serve] exported {prefix} in {time.perf_counter() - t0:.1f}s; "
+        f"aot {meta['aot']['buckets']} for {meta['aot']['compat']}")
+    check(meta["aot"]["buckets"] == [1, 8], "AOT buckets missing from meta")
+    pred = deploy.load_predictor(prefix)
+    check(pred.device.platform == device["platform"]
+          and pred.aot_buckets == [1, 8] and pred.aot_load_failures == 0,
+          f"exporter's own load: device {pred.device}, aot {pred.aot_buckets}, "
+          f"failures {pred.aot_load_failures}")
+    rows = onp.asarray(x8.astype(jnp.float32))
+    want = onp.stack([onp.asarray(pred(rows[i:i + 1].astype(jnp.bfloat16))
+                                  ).astype(onp.float32)[0]
+                      for i in range(8)])
+    check(pred.compile_count == 0, "the exporter's AOT predictor compiled")
+    check(onp.isfinite(want).all(), "non-finite reference logits")
+    onp.savez(os.path.join(args.workdir, "answers.npz"), rows=rows, want=want)
+    return {"device": device}
+
+
+def _http(port, path, body=None, timeout=120):
+    req = urllib.request.Request(
+        f"http://127.0.0.1:{port}{path}",
+        data=None if body is None else json.dumps(body).encode(),
+        headers={"Content-Type": "application/json"})
+    with urllib.request.urlopen(req, timeout=timeout) as resp:
+        return json.loads(resp.read())
+
+
+def serve_and_query(args, env, device):
+    """Start the server CLI as a child (it owns the chip now), hold its
+    answers to the exporter's, read /healthz, SIGTERM."""
+    import numpy as onp      # the parent may use numpy; never JAX
+    answers = onp.load(os.path.join(args.workdir, "answers.npz"))
+    env = dict(env, MXNET_SERVING_BATCH_BUCKETS="1,8",
+               MXNET_SERVING_MAX_BATCH="8",
+               PYTHONPATH=HERE + os.pathsep + env.get("PYTHONPATH", ""))
+    cmd = [sys.executable, "-m", "incubator_mxnet_tpu.serving.server",
+           "--model", f"r50={os.path.join(args.workdir, 'r50')}",
+           "--host", "127.0.0.1", "--port", "0"]
+    t0 = time.perf_counter()
+    proc = subprocess.Popen(cmd, env=env, cwd=HERE, stdout=subprocess.PIPE,
+                            stderr=subprocess.STDOUT, text=True)
+    port, lines = [], []
+
+    def pump():
+        for line in proc.stdout:
+            lines.append(line.rstrip())
+            say(f"[serve] server: {line.rstrip()}")
+            if "] listening on " in line and not port:
+                port.append(int(line.rsplit(":", 1)[1]))
+
+    reader = threading.Thread(target=pump, daemon=True)
+    reader.start()
+    try:
+        while not port and proc.poll() is None \
+                and time.perf_counter() - t0 < CHILD_TIMEOUT_S:
+            time.sleep(0.2)
+        check(port, f"the server never listened (exit {proc.poll()})")
+        say(f"[serve] server ready {time.perf_counter() - t0:.1f}s after "
+            f"spawn on port {port[0]}")
+
+        def ask(i):
+            out = _http(port[0], "/v1/models/r50:predict",
+                        {"inputs": [answers["rows"][i].tolist()]})
+            return onp.asarray(out["outputs"][0], onp.float32)
+
+        def agree(i, got, what):
+            want = answers["want"][i]
+            err = float(onp.abs(got - want).max() / (onp.abs(want).max()
+                                                     + 1e-6))
+            say(f"[serve] {what} row {i}: logits {got.shape}, max rel err vs "
+                f"the exporter's answer {err:.1e}")
+            # bucket 1 replays the exporter's own executable; in bucket 8 the
+            # row shares a bfloat16 batch with seven others
+            check(got.shape == want.shape and onp.isfinite(got).all()
+                  and err <= 2e-2, f"request {i} disagrees with the export")
+
+        for i in range(3):                       # one at a time: bucket 1
+            agree(i, ask(i), "single")
+        results = [None] * 8                     # eight at once: bucket 8
+        threads = [threading.Thread(
+            target=lambda i=i: results.__setitem__(i, ask(i)))
+            for i in range(8)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(CHILD_TIMEOUT_S)
+        check(all(r is not None for r in results), "a concurrent request "
+              "got no answer")
+        for i in (0, 7):
+            agree(i, results[i], "concurrent")
+
+        health = _http(port[0], "/healthz")
+        m = health["models"]["r50"]
+        say(f"[serve] /healthz r50: state {m['state']} compile_count "
+            f"{m['compile_count']} aot_buckets {m['aot_buckets']} "
+            f"aot_load_failures {m['aot_load_failures']} device {m['device']} "
+            f"cold_start_ms {m['cold_start_ms']}")
+        check(m["state"] == "ready" and m["compile_count"] == 0
+              and m["aot_buckets"] == [1, 8] and m["aot_load_failures"] == 0,
+              "the server compiled, or an AOT bucket fell back")
+        check(m["device"] == {"platform": device["platform"],
+                              "kind": device["kind"]},
+              f"the server's model is on {m['device']}, the export ran on "
+              f"{device}")
+    finally:
+        if proc.poll() is None:
+            proc.send_signal(signal.SIGTERM)
+        try:
+            rc = proc.wait(120)
+        except subprocess.TimeoutExpired:
+            proc.kill()
+            rc = proc.wait()
+        reader.join(10)
+    say(f"[serve] SIGTERM -> server exit code {rc}")
+    check(rc == 0, "the server did not drain and exit 0 on SIGTERM")
+
+
+# ------------------------------------------------------------- four chips
+
+def child_dp4(args):
+    """The data-parallel train step over four devices, and the one-chip step
+    on the same batch it is compared with."""
+    jax, device = _child_setup(args)
+    import numpy as onp
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from incubator_mxnet_tpu import amp
+    check(device["count"] >= 4, f"--chips 4 needs four devices, JAX has "
+          f"{device['count']}")
+    bs, steps = (8, 3) if args.rehearse else (256, 3)
+    mesh = Mesh(onp.array(jax.devices()[:4]), ("dp",))
+    report = {}
+    for name, kw, sharding in (
+            ("dp4", {"mesh": mesh}, NamedSharding(mesh, P("dp"))),
+            ("one-chip", {}, None)):
+        net = _build_net(args.rehearse, args.seed)
+        amp.convert_block(net, "bfloat16")
+        step = _make_step(net, **kw)
+        x, y, classes = _batch(args.rehearse, args.seed, bs,
+                               jnp.bfloat16, sharding)
+        if name == "dp4":
+            spans = {len(leaf.sharding.device_set)
+                     for leaf in _state_leaves(step)}
+            check(spans == {4} and len(x.sharding.device_set) == 4
+                  and not x.sharding.is_fully_replicated,
+                  f"params span {spans} devices, the batch "
+                  f"{len(x.sharding.device_set)}")
+            text = step._executor.jfn.lower(
+                step.params, step.aux, step.opt_state, x, y,
+                step._key).compile().as_text()
+            n_ar = text.count("all-reduce(") + text.count("all-reduce-start(")
+            check(n_ar > 0, "no all-reduce in the dp4 step's program")
+            say(f"[dp4] every leaf of params/aux/opt_state has a four-member "
+                f"device_set; the batch is split over "
+                f"{len(x.sharding.device_set)} devices; {n_ar} all-reduce "
+                f"op(s) in the compiled step")
+        t0 = time.perf_counter()
+        losses = [float(step(x, y))]
+        compile_s = time.perf_counter() - t0
+        t0 = time.perf_counter()
+        tail = [step(x, y) for _ in range(steps - 1)]
+        jax.block_until_ready(tail[-1])
+        step_ms = 1e3 * (time.perf_counter() - t0) / (steps - 1)
+        losses += [float(v) for v in tail]
+        check(step._executor.compile_count == 1,
+              f"{name}: {step._executor.compile_count} compiles")
+        if name == "dp4":
+            where = {len(leaf.sharding.device_set)
+                     for leaf in _state_leaves(step)}
+            check(where == {4}, f"after {steps} steps params span {where}")
+        checksum = float(sum(jnp.sum(jnp.abs(p.astype(jnp.float32)))
+                             for p in step.params.values()))
+        _check_losses(losses, classes)
+        say(f"[dp4] {name}: global batch {bs}, compile+first step "
+            f"{compile_s:.1f}s, step {step_ms:.2f} ms (smoke observation), "
+            f"losses {' '.join(f'{v:.4f}' for v in losses)}, "
+            f"sum|params| {checksum:.4f}")
+        report[name] = (losses, checksum)
+    (l4, c4), (l1, c1) = report["dp4"], report["one-chip"]
+    loss_err = max(abs(a - b) / abs(b) for a, b in zip(l4, l1))
+    sum_err = abs(c4 - c1) / abs(c1)
+    # the same bfloat16 program but for the order the batch is reduced in:
+    # BatchNorm statistics and gradients are summed per shard, then across
+    say(f"[dp4] dp4 vs one chip after {steps} steps: max rel loss diff "
+        f"{loss_err:.1e} (tolerance 5e-2), rel sum|params| diff "
+        f"{sum_err:.1e} (tolerance 1e-3)")
+    check(loss_err <= 5e-2 and sum_err <= 1e-3,
+          "the dp4 step and the one-chip step disagree")
+    return {"device": device}
+
+
+def child_probe(args):
+    return {"device": _device()}
+
+
+CHILDREN = {"probe": child_probe, "train": child_train,
+            "kernels": child_kernels, "export": child_export,
+            "dp4": child_dp4}
+
+
+# ======================================================================
+# parent: never touches JAX
+# ======================================================================
+
+def run_child(name, args, env):
+    """One child at a time; its lines stream through; its last tagged line
+    is the phase's result."""
+    cmd = [sys.executable, os.path.abspath(__file__), "--child", name,
+           "--seed", str(args.seed), "--workdir", args.workdir]
+    if args.rehearse:
+        cmd.append("--rehearse")
+    t0 = time.perf_counter()
+    proc = subprocess.Popen(cmd, env=env, cwd=HERE, stdout=subprocess.PIPE,
+                            text=True)
+    timer = threading.Timer(CHILD_TIMEOUT_S, proc.kill)
+    timer.start()
+    result = None
+    try:
+        for line in proc.stdout:
+            if line.startswith(RESULT_TAG):
+                result = json.loads(line[len(RESULT_TAG):])
+            else:
+                print(line, end="", flush=True)
+        rc = proc.wait()
+    finally:
+        timer.cancel()
+    took = time.perf_counter() - t0
+    if rc != 0 or result is None:
+        raise PhaseFailed(f"child {name!r} exited {rc} after {took:.0f}s "
+                          "with no result")
+    say(f"[{name}] PASSED in {took:.0f}s")
+    return result
+
+
+def rebuild_native(rehearse):
+    """libmxtpu.so comes from src/, now: a stale build product on disk must
+    not be what the run passes with.  (A rehearsal only brings it up to date:
+    it shares the checkout with whatever else is running.)"""
+    cmd = ["make", "-C", os.path.join(HERE, "src"), f"-j{os.cpu_count() or 2}"]
+    if not rehearse:
+        cmd.append("-B")
+    t0 = time.perf_counter()
+    proc = subprocess.run(cmd, capture_output=True, text=True)
+    if proc.returncode != 0:
+        raise PhaseFailed("make -C src failed:\n"
+                          + (proc.stderr or proc.stdout)[-2000:])
+    say(f"[build] {'brought up to date' if rehearse else 'rebuilt'} "
+        f"incubator_mxnet_tpu/native/libmxtpu.so from src/ with make in "
+        f"{time.perf_counter() - t0:.1f}s")
+
+
+def parent(args):
+    env = dict(os.environ)
+    if args.rehearse and args.chips == 4:
+        # four virtual devices where the backend is the CPU
+        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
+                            + " --xla_force_host_platform_device_count=4")
+    device = run_child("probe", args, env)["device"]
+    say(f"[smoke] JAX reports {device}")
+    check(args.rehearse or device["platform"] == "tpu",
+          f"no accelerator: JAX's default backend is "
+          f"{device['platform']!r}.  Nothing ran and there is no result "
+          f"(--rehearse walks the control flow on a CPU).")
+    check(device["count"] >= args.chips,
+          f"--chips {args.chips} but JAX has {device['count']} device(s)")
+    rebuild_native(args.rehearse)
+    # every phase runs even after one failed: one run shows all that is wrong
+    failed = []
+    for name in ["dp4"] if args.chips == 4 else ["train", "kernels", "serve"]:
+        try:
+            if name == "serve":
+                seen = run_child("export", args, env)["device"]
+                serve_and_query(args, env, device)
+                say("[serve] PASSED")
+            else:
+                seen = run_child(name, args, env)["device"]
+            check(seen == device, f"phase {name} ran on {seen}, the probe "
+                  f"saw {device}")
+        except PhaseFailed as e:
+            say(f"[{name}] FAILED: {e}")
+            failed.append(name)
+    check(not failed, f"phase(s) {', '.join(failed)} failed")
+    return device
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--rehearse", action="store_true",
+                    help="toy sizes on whatever platform JAX finds; never "
+                         'prints "ok": true')
+    ap.add_argument("--child", choices=sorted(CHILDREN), help=argparse.SUPPRESS)
+    ap.add_argument("--workdir", help=argparse.SUPPRESS)
+    args = ap.parse_args()
+
+    if args.child:
+        try:
+            result = CHILDREN[args.child](args)
+        except PhaseFailed as e:
+            say(f"[{args.child}] FAILED: {e}")
+            sys.exit(1)
+        say(RESULT_TAG + json.dumps(result))
+        return
+
+    args.workdir = tempfile.mkdtemp(prefix="chip_smoke_")
+    try:
+        device = parent(args)
+    except PhaseFailed as e:
+        say(f"[smoke] FAILED: {e}")
+        sys.exit(1)
+    finally:
+        shutil.rmtree(args.workdir, ignore_errors=True)
+    if args.rehearse:
+        say(json.dumps({"rehearsal": True, "phases_passed": True,
+                        "device": device}))
+    else:
+        say(json.dumps({"ok": True, "device": device}))
+
+
+if __name__ == "__main__":
+    main()

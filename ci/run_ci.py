@@ -26,7 +26,7 @@ Stages (each isolated, failures collected, nonzero exit if any fail):
              a seeded reshard violation failing its own strict-mode
              subprocess — the stage's negative control
   multichip  __graft_entry__.dryrun_multichip on a virtual 8-device mesh
-  bench      bench.py CPU fallback emits a well-formed JSON line
+  bench      bench.py refuses to publish a result without a TPU
   chaos      kvstore + checkpoint test subset re-run under a fixed
              MXNET_FAULT_SPEC (deterministic transient faults on the
              PS transport, delays on checkpoint writes) so every PR
@@ -195,7 +195,7 @@ def stage_sanity(args):
               timeout=300)
     if proc.returncode != 0:
         return False, proc.stderr[-400:]
-    # imports must stay CPU-safe (a wedged accelerator cannot hang them)
+    # import smoke on the host CPU (CI never takes a chip)
     code = ("import jax; jax.config.update('jax_platforms','cpu'); "
             "import incubator_mxnet_tpu as mx; "
             "assert mx.nd.ones((2,2)).sum().asscalar() == 4.0")
@@ -997,14 +997,12 @@ def stage_multichip(args):
 
 
 def stage_bench(args):
+    """CI runs on the CPU, where bench.py must REFUSE: non-zero exit and
+    no result line (a CPU timing is never published as a result)."""
     proc = sh([sys.executable, "bench.py"], timeout=600,
-              env={"BENCH_PLATFORM": "cpu", "BENCH_DEADLINE": "400"})
-    try:
-        rec = json.loads(proc.stdout.strip().splitlines()[-1])
-        ok = "value" in rec and rec["value"] > 0
-    except (ValueError, IndexError):
-        ok = False
-    return ok, proc.stdout.strip()[-200:]
+              env={"JAX_PLATFORMS": "cpu"})
+    ok = proc.returncode != 0 and not proc.stdout.strip()
+    return ok, (proc.stderr or proc.stdout).strip()[-200:]
 
 
 STAGES = {"build": stage_build, "sanity": stage_sanity,

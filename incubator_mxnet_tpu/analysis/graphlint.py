@@ -83,6 +83,7 @@ from __future__ import annotations
 import warnings as _warnings
 
 import jax
+from jax.extend import core as _jcore
 import numpy as _onp
 
 from ..base import get_env
@@ -231,7 +232,7 @@ def _iter_subjaxprs(params):
     for name, v in params.items():
         vals = v if isinstance(v, (tuple, list)) else (v,)
         for i, item in enumerate(vals):
-            if isinstance(item, (jax.core.Jaxpr, jax.core.ClosedJaxpr)):
+            if isinstance(item, (_jcore.Jaxpr, _jcore.ClosedJaxpr)):
                 tag = name.replace("_jaxpr", "").replace("jaxpr", "")
                 tag = tag.strip("_") or None
                 idx = f"#{i}" if len(vals) > 1 else ""
@@ -247,7 +248,7 @@ def lint_jaxpr(closed, where="graph", config=None):
     returns deduplicated, sorted Findings."""
     config = config or Config()
     findings: list[Finding] = []
-    if isinstance(closed, jax.core.ClosedJaxpr):
+    if isinstance(closed, _jcore.ClosedJaxpr):
         _walk(closed.jaxpr, tuple(closed.consts), "", where, config,
               findings)
     else:
@@ -349,7 +350,7 @@ def _walk(jaxpr, consts, path, where, config, findings):
         # -- recurse into sub-jaxprs ------------------------------------
         for tag, inner in _iter_subjaxprs(eqn.params):
             sub_path = f"{path}/{prim}{tag}"
-            if isinstance(inner, jax.core.ClosedJaxpr):
+            if isinstance(inner, _jcore.ClosedJaxpr):
                 _walk(inner.jaxpr, tuple(inner.consts), sub_path, where,
                       config, findings)
             else:

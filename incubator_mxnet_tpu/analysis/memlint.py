@@ -58,6 +58,7 @@ import threading
 import warnings as _warnings
 
 import jax
+from jax.extend import core as _jcore
 import numpy as _onp
 
 from ..base import get_env
@@ -216,9 +217,9 @@ def _inner_jaxprs(params):
     for v in params.values():
         vals = v if isinstance(v, (tuple, list)) else (v,)
         for item in vals:
-            if isinstance(item, jax.core.ClosedJaxpr):
+            if isinstance(item, _jcore.ClosedJaxpr):
                 yield item.jaxpr, tuple(item.consts)
-            elif isinstance(item, jax.core.Jaxpr):
+            elif isinstance(item, _jcore.Jaxpr):
                 yield item, ()
 
 

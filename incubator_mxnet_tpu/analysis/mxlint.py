@@ -484,7 +484,7 @@ def _check_donate(fobj: "_File", findings):
                     emit(dec)
 
 
-_SHARD_CALLEES = ("shard_map", "shard_map_compat", "pjit")
+_SHARD_CALLEES = ("shard_map", "pjit")
 _SHARD_RECEIVERS = ("jax", "pjit", "_pjit", "base", "_base",
                     "shard_map")
 _SHARD_KWARGS = ("mesh", "in_specs", "out_specs", "in_shardings",
@@ -492,7 +492,7 @@ _SHARD_KWARGS = ("mesh", "in_specs", "out_specs", "in_shardings",
 
 
 def _is_shard_ref(f):
-    """A reference to ``shard_map``/``shard_map_compat``/``pjit`` as a
+    """A reference to ``shard_map``/``pjit`` as a
     call-site callee.  Attribute receivers are restricted to the
     conventional module names (``jax.shard_map``,
     ``shard_map.shard_map``) so unrelated methods do not
@@ -513,7 +513,7 @@ def _check_shard(fobj: "_File", findings):
     scope rule: tools and benchmarks map throwaway closures).  A
     ``mesh=``/``in_specs=``/``in_shardings=``-family keyword satisfies
     the rule, as do two or more positional arguments (the
-    ``shard_map_compat(fn, mesh, ...)`` positional spelling) — the
+    ``shard_map(fn, mesh, ...)`` positional spelling) — the
     point is that the mesh/sharding decision is VISIBLE at the call
     site, where shardlint (analysis/shardlint.py) can hold the declared
     specs against the propagated ones, not inherited from ambient

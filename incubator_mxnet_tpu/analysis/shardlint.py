@@ -57,6 +57,7 @@ import threading
 import warnings as _warnings
 
 import jax
+from jax.extend import core as _jcore
 
 from ..base import get_env
 from .graphlint import Finding, render, _source_of
@@ -390,7 +391,7 @@ def _iter_subjaxprs_tagged(params):
     for name, v in params.items():
         vals = v if isinstance(v, (tuple, list)) else (v,)
         for i, item in enumerate(vals):
-            if isinstance(item, (jax.core.Jaxpr, jax.core.ClosedJaxpr)):
+            if isinstance(item, (_jcore.Jaxpr, _jcore.ClosedJaxpr)):
                 tag = name.replace("_jaxpr", "").replace("jaxpr", "")
                 tag = tag.strip("_") or ""
                 idx = f"#{i}" if len(vals) > 1 else ""

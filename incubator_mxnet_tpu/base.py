@@ -182,29 +182,3 @@ def resolve_chunk_steps(chunk_steps=None):
     if k < 1:
         raise ValueError(f"chunk_steps must be >= 1, got {k}")
     return k
-
-
-def shard_map_compat(fn, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions: new jax exposes it at the
-    top level (replication check switch ``check_vma=``), 0.4.x under
-    ``jax.experimental.shard_map`` with the same switch named
-    ``check_rep=``."""
-    import jax
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-        except TypeError:
-            pass
-    from jax.experimental.shard_map import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
-
-
-def axis_size_compat(axis_name):
-    """``lax.axis_size`` across jax versions; on older jax the size of
-    a named mapped axis is the trace-time constant ``psum(1, axis)``."""
-    from jax import lax
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)

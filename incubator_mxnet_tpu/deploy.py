@@ -128,6 +128,11 @@ def export_model(model, example_inputs, prefix, params=None,
     example = tuple(
         x.data if isinstance(x, NDArray) else jnp.asarray(x)
         for x in example_inputs)
+    # everything the export lowers and compiles is for the accelerator
+    # (the default backend's first device): a Block's parameters and
+    # NDArray examples live on the host CPU, and committed arguments
+    # would otherwise make the shipped executables CPU programs
+    params, example = jax.device_put((params, example), jax.devices()[0])
 
     # through the unified choke point: the export trace is a compile
     # surface like any other (sentinel site export:<name>, persistent
@@ -376,45 +381,48 @@ def _write_aot_buckets(jitted, params, example, prefix, aot_buckets):
     compiling — XLA never runs in the serving replica.  Executables are
     jax/jaxlib/platform-exact; the loader's compat check falls back to
     recompilation (loudly) rather than crash on a foreign blob.
-    Returns the meta.json ``"aot"`` entry or None when off/unavailable."""
+    AOT buckets that were asked for and cannot be built fail the
+    export.  Returns the meta.json ``"aot"`` entry or None when off."""
     buckets = _parse_aot_buckets(aot_buckets)
     if buckets is None:
         return None
+    if not all(x.ndim >= 1 for x in example):
+        raise ValueError(
+            "AOT buckets need a leading batch axis on every input")
     written = []
+    files = {}
     try:
-        if not all(x.ndim >= 1 for x in example):
-            raise ValueError(
-                "AOT buckets need a leading batch axis on every input")
-        files = {}
         for n in buckets:
             specs = [jax.ShapeDtypeStruct((n,) + tuple(x.shape[1:]),
                                           x.dtype) for x in example]
-            compiled = jitted.lower(params, *specs).compile()
+            # compiled with the persistent cache out of the way: an
+            # executable *served from* the cache re-serializes without
+            # its kernels' object code (XLA:CPU: "Function dot_kernel
+            # not found" when the loaded blob first runs), and with the
+            # cache on by default that would be every second export
+            with _xc.compile_cache_bypassed():
+                compiled = jitted.lower(params, *specs).compile()
             blob = _xc.serialize_executable(compiled)
-            # round-trip self-check BEFORE shipping: an executable
-            # served from a shared compile cache can re-serialize
-            # incompletely (missing kernel symbols) — a blob that does
-            # not load in the exporting environment can never load
-            # anywhere, and must abort the AOT layer here, not crash a
+            # round-trip self-check BEFORE shipping: a blob that does
+            # not load and run in the exporting environment can never
+            # serve anywhere, and must fail the export here, not a
             # serving replica later.  record=False: validation, not
             # cold-start cache traffic
-            _xc.deserialize_executable(blob, record=False)
+            loaded = _xc.deserialize_executable(blob, record=False)
+            jax.block_until_ready(loaded(params, *(
+                jnp.zeros(s.shape, s.dtype) for s in specs)))
             path = f"{prefix}.aot.b{n}"
             with open(path, "wb") as f:
                 f.write(blob)
             written.append(path)
             files[str(n)] = os.path.basename(path)
-        return {"buckets": buckets, "files": files,
-                "compat": _xc.aot_compat()}
-    except Exception as e:  # mxlint: allow-broad-except(AOT executables are an optional artifact layer; failure degrades to compile-at-warmup with a warning)
-        import warnings
+    except BaseException:
         for path in written:   # no partial bucket set: all-or-nothing
             if os.path.exists(path):
                 os.remove(path)
-        warnings.warn(
-            f"AOT bucket export unavailable ({e}); loading processes "
-            "will compile at warmup instead of deserializing")
-        return None
+        raise
+    return {"buckets": buckets, "files": files,
+            "compat": _xc.aot_compat()}
 
 
 def _write_pjrt_sidecar(prefix, params, meta):
@@ -465,11 +473,8 @@ def _write_pjrt_sidecar(prefix, params, meta):
             f.write(f"out {o['dtype']} {len(o['shape'])} {dims}".rstrip()
                     + "\n")
     try:
-        try:
-            from jaxlib import xla_client as _xc
-        except ImportError:  # newer jaxlib moved it under jax._src.lib
-            from jax._src.lib import _jax as _xc
-        blob = _xc.CompileOptions().SerializeAsString()  # before open():
+        from jaxlib import xla_client
+        blob = xla_client.CompileOptions().SerializeAsString()  # before open():
         # a failed serialization must not leave a truncated file behind
     except Exception as e:  # mxlint: allow-broad-except(compile-options blob is an optional artifact; failure warns and the PJRT-direct path recompiles)
         import warnings
@@ -501,9 +506,15 @@ class Predictor:
             self._exported = jax.export.deserialize(f.read())
         from .ndarray import load as nd_load
         loaded = nd_load(prefix + ".params")
-        # rebuild the params pytree from flattened keystr names
-        self._params = _unflatten_keystr(
-            {k: v.data for k, v in loaded.items()})
+        # rebuild the params pytree from flattened keystr names, and
+        # commit it to the accelerator: nd_load lands arrays on the
+        # host CPU (the default context), and committed params decide
+        # where every call runs — left there, a TPU host would serve
+        # from its CPU without a word
+        self.device = jax.devices()[0]
+        self._params = jax.device_put(
+            _unflatten_keystr({k: v.data for k, v in loaded.items()}),
+            self.device)
         # both entry points go through the unified choke point
         # (executor_cache.Executor): jit's executable cache keyed on
         # concrete input shapes is (a) the warm-path dispatch and (b)

@@ -27,14 +27,17 @@ module is the one place all of that lives now:
 Cold-start persistence (ROADMAP item 2 — replica cold-start from
 minutes to seconds) stacks two layers on this choke point:
 
-* **Persistent XLA compilation cache** — ``MXNET_COMPILE_CACHE_DIR``
-  points JAX's compilation cache at a directory
-  (``jax_compilation_cache_dir``); a second process on the same host
-  (a serving replica spawn, an elastic worker join, a rolling reload)
-  skips XLA compilation for every graph the first process built.
-  Enabled at ONE init point (:func:`ensure_compile_cache`), called by
-  every Executor construction, with min-entry-size / min-compile-time
-  thresholds so tiny graphs don't churn the directory.
+* **Persistent XLA compilation cache** — always on: where
+  ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and this
+  code names no directory at all; otherwise the cache lives at the
+  fixed path ``<checkout>/.jax_cache`` (the path is part of the cache
+  key, so it never moves).  A second process on the same host (a
+  serving replica spawn, an elastic worker join, a rolling reload, the
+  next phase of ``chip_smoke.py``) skips XLA compilation for every
+  graph the first process built.  Decided at ONE init point
+  (:func:`ensure_compile_cache`), called by every Executor
+  construction, with min-entry-size / min-compile-time thresholds so
+  tiny graphs don't churn the directory.
 * **AOT-serialized executables** — :func:`serialize_executable` /
   :func:`deserialize_executable` wrap
   ``jax.experimental.serialize_executable`` with a versioned
@@ -49,17 +52,21 @@ the persistent-cache configuration, and AOT load hits/failures.
 """
 from __future__ import annotations
 
+import contextlib
 import json
+import os
 import threading
 import time
 
 import jax
+from jax.experimental.compilation_cache import compilation_cache as _cc
 
 from .base import get_env
 from .locks import named_lock
 
 __all__ = ["Executor", "TraceCache", "run_analyses", "lint_active",
-           "memlint_active", "ensure_compile_cache", "compile_cache_dir",
+           "memlint_active", "ensure_compile_cache",
+           "compile_cache_bypassed",
            "serialize_executable", "deserialize_executable", "aot_compat",
            "AOTCompatError", "record_aot_load", "process_uptime_ms",
            "stats", "reset_stats"]
@@ -89,18 +96,27 @@ class AOTCompatError(RuntimeError):
 # persistent compilation cache — the one shared init point
 # ---------------------------------------------------------------------------
 
-def compile_cache_dir():
-    """The configured persistent-cache directory, or None (off)."""
-    d = get_env("MXNET_COMPILE_CACHE_DIR", "")
-    return d or None
+_DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
 
 
 def ensure_compile_cache():
-    """Switch on JAX's persistent compilation cache if
-    ``MXNET_COMPILE_CACHE_DIR`` is set.  Idempotent and cheap after the
-    first call; every Executor construction routes through here, so any
-    process that compiles anything gets the cache without per-surface
-    wiring.  Returns the cache dir or None.
+    """The one rule for where JAX's persistent compilation cache lives.
+
+    * ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads the variable itself;
+      no directory is set in code (nothing else, this package's own
+      variables included, can override it).
+    * unset: ``<checkout>/.jax_cache`` — derived from this package's
+      path, so every process of one checkout (trainer, server,
+      replicas, ``chip_smoke.py`` children) shares it and a re-run
+      finds what the last run compiled.
+
+    Idempotent and cheap after the first call; every Executor
+    construction routes through here, so any process that compiles
+    anything gets the cache without per-surface wiring.  Returns the
+    directory in effect.  ``JAX_ENABLE_COMPILATION_CACHE=false``
+    switches the cache off (cold-start measurements, the test suite).
 
     Thresholds (both default to "cache everything" because cold start
     is what the cache exists to kill; raise them on hosts where the
@@ -115,39 +131,40 @@ def ensure_compile_cache():
         if _state["cache_init_done"]:
             return _state["cache_dir"]
         _state["cache_init_done"] = True
-        d = compile_cache_dir()
-        if d is None:
-            return None
-        try:
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes",
+                          get_env("MXNET_COMPILE_CACHE_MIN_ENTRY_BYTES",
+                                  0, int))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          get_env("MXNET_COMPILE_CACHE_MIN_COMPILE_SECS",
+                                  0.0, float))
+        d = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+        if not d:
+            d = _DEFAULT_CACHE_DIR
             jax.config.update("jax_compilation_cache_dir", d)
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                              get_env("MXNET_COMPILE_CACHE_MIN_ENTRY_BYTES",
-                                      0, int))
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              get_env("MXNET_COMPILE_CACHE_MIN_COMPILE_SECS",
-                                      0.0, float))
-            # jax's cache module latches its enabled/disabled state at
-            # the first compile; anything compiled before this init
-            # (eager op dispatch during import) would leave it stuck
-            # disabled — drop the latch so the new dir takes effect
-            from jax._src import compilation_cache as _cc
-            _cc.reset_cache()
-        except Exception as e:  # mxlint: allow-broad-except(an unsupported jax config key must degrade to cold compiles, never break model building)
-            import warnings
-            # roll back any config that DID apply before the failure:
-            # the reported state (off) must match reality, not leave a
-            # half-enabled cache behind the "cold compiles" warning
-            try:
-                jax.config.update("jax_compilation_cache_dir", None)
-            except Exception:  # mxlint: allow-broad-except(rollback of a possibly-never-applied key; nothing further to do on failure)
-                pass
-            warnings.warn(
-                f"persistent compilation cache unavailable ({e}); "
-                "compiles will be cold in every process")
-            _state["cache_dir"] = None
-            return None
+        # jax's cache module latches its enabled/disabled state at
+        # the first compile; anything compiled before this init
+        # (eager op dispatch during import) would leave it stuck
+        # disabled — drop the latch so the settings take effect
+        _cc.reset_cache()
         _state["cache_dir"] = d
         return d
+
+
+@contextlib.contextmanager
+def compile_cache_bypassed():
+    """Compile with the persistent cache out of the way (neither read
+    nor written).  For compiles whose product is itself serialized —
+    an executable *served from* the cache can re-serialize
+    incompletely — and for described-topology compiles, whose entries
+    no attached device can read back."""
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    _cc.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        _cc.reset_cache()
 
 
 def _reset_compile_cache_for_tests():
@@ -443,6 +460,10 @@ def serialize_executable(compiled):
     from jax.experimental.serialize_executable import serialize
     payload, in_tree, out_tree = serialize(compiled)
     header = dict(aot_compat())
+    # the devices the program was compiled for: the loader must hand
+    # jax exactly these, or it loads onto every device of the backend
+    header["devices"] = [
+        d.id for d in compiled.runtime_executable().local_devices()]
     blob_header = json.dumps(header, sort_keys=True).encode()
     import pickle
     trees = pickle.dumps((in_tree, out_tree))
@@ -454,7 +475,9 @@ def serialize_executable(compiled):
 
 
 def deserialize_executable(blob, record=True):
-    """Load an AOT blob back into a callable executable.
+    """Load an AOT blob back into a callable executable, onto the
+    devices (by id) it was compiled for — a one-device program loads
+    onto one device whether this process has 1, 4 or 8.
 
     Raises :class:`AOTCompatError` on any mismatch or corruption — the
     caller's contract is to catch it, warn loudly, and recompile.  The
@@ -493,9 +516,17 @@ def deserialize_executable(blob, record=True):
         in_tree, out_tree = pickle.loads(take(tlen))
         plen = int.from_bytes(take(8), "little")
         payload = take(plen)
+        by_id = {d.id: d for d in jax.devices()}
+        ids = header.get("devices", [jax.devices()[0].id])
+        if not all(i in by_id for i in ids):
+            raise AOTCompatError(
+                f"AOT executable was compiled for devices {ids}; this "
+                f"process has {sorted(by_id)}")
         from jax.experimental.serialize_executable import \
             deserialize_and_load
-        loaded = deserialize_and_load(payload, in_tree, out_tree)
+        loaded = deserialize_and_load(
+            payload, in_tree, out_tree,
+            execution_devices=[by_id[i] for i in ids])
         if record:
             record_aot_load(ok=True)
         return loaded

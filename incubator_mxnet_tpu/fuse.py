@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from . import executor_cache as _xc
 from .base import resolve_chunk_steps
 from .ndarray import NDArray
+from .ops.pallas_kernels import gspmd_trace
 
 __all__ = ["FusedTrainStep", "make_fused_train_step", "sgd_init", "adam_init"]
 
@@ -137,18 +138,24 @@ class FusedTrainStep:
         # ChunkedTrainLoop scan K steps per dispatch
         self.chunk_steps = resolve_chunk_steps(chunk_steps)
         self._key = jax.random.PRNGKey(0)
+        # commit the whole train state to where it will run, up front:
+        # jit outputs are committed arrays, so an uncommitted first
+        # call would compile one executable for step 1 and a second —
+        # the real steady-state one — for step 2+ (one program per
+        # batch shape, from the first dispatch); and a Block's
+        # parameters live on the host CPU, which must not decide where
+        # the step runs.  One device without a mesh; replicated over
+        # the mesh with one (data parallelism: GSPMD all-reduces the
+        # gradients of replicated parameters).
         if mesh is None:
-            # commit the whole train state to its device up front: jit
-            # outputs are committed arrays, so an uncommitted first
-            # call would compile one executable for step 1 and a
-            # second — the real steady-state one — for step 2+.  One
-            # program per batch shape, from the first dispatch (the
-            # mesh path leaves placement to the pjit shardings)
-            dev = jax.devices()[0]
-            self.params, self.aux, self.opt_state, self._key = \
-                jax.device_put(
-                    (self.params, self.aux, self.opt_state, self._key),
-                    dev)
+            placement = jax.devices()[0]
+        else:
+            from jax.sharding import NamedSharding, PartitionSpec
+            placement = NamedSharding(mesh, PartitionSpec())
+        self.params, self.aux, self.opt_state, self._key = \
+            jax.device_put(
+                (self.params, self.aux, self.opt_state, self._key),
+                placement)
         self._remat = remat
         # kept for the chunked loop (fuse_loop): the scanned program
         # re-applies the same batch sharding to its (K, batch, ...)
@@ -187,8 +194,13 @@ class FusedTrainStep:
             loss_of = jax.checkpoint(loss_of, policy=policies[self._remat])
 
         def step(params, aux, opt_state, x, y, key):
-            (loss, updates), grads = jax.value_and_grad(
-                loss_of, has_aux=True)(params, aux, x, y, key)
+            # this body runs while tracing, whoever traces it (the jit
+            # call, a lower(), the analyses, the chunked loop's scan):
+            # over a mesh, GSPMD partitions the program, and it cannot
+            # partition a Mosaic kernel
+            with gspmd_trace(mesh is not None):
+                (loss, updates), grads = jax.value_and_grad(
+                    loss_of, has_aux=True)(params, aux, x, y, key)
             if optimizer == "sgd":
                 new_params, new_state = _sgd_update(grads, opt_state, params,
                                                     lr, momentum, wd)

@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..base import shard_map_compat
 
 __all__ = ["GradientCompression", "make_compressed_allreduce"]
 
@@ -131,10 +130,11 @@ def make_compressed_allreduce(mesh, axis_name="dp", threshold=0.5):
         return mean, res
 
     from jax.sharding import PartitionSpec as P
-    mapped = shard_map_compat(
+    mapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis_name), P(axis_name)),
-        out_specs=(P(), P(axis_name)))
+        out_specs=(P(), P(axis_name)),
+        check_vma=False)
     return jax.jit(mapped)  # mxlint: disable=MX-DONATE001(grad/residual trees are caller-held — callers re-run the sync on the same gradients; the donating surface is the compressed dp train step below)
 
 
@@ -181,10 +181,11 @@ def make_compressed_dp_train_step(loss_fn, mesh, lr=0.1, axis_name="dp",
         return new_params, new_res, loss_mean
 
     from jax.sharding import PartitionSpec as P
-    mapped = shard_map_compat(
+    mapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(axis_name), P(axis_name)),
-        out_specs=(P(), P(axis_name), P()))
+        out_specs=(P(), P(axis_name), P()),
+        check_vma=False)
     # params and residuals are pure carry state (`params, residuals,
     # loss = step(params, residuals, batch)`): donate both so the
     # update aliases them in place; the batch (arg 2) is caller-held

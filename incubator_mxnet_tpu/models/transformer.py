@@ -137,9 +137,8 @@ class TransformerLM:
             if mesh is not None:
                 # keep batch/head shards local: run the kernel inside
                 # shard_map so GSPMD doesn't all-gather q/k/v
-                from jax.experimental.shard_map import shard_map
                 spec = P("dp", "tp", None, None)
-                fa = shard_map(
+                fa = jax.shard_map(
                     lambda q, k, v: flash_attention(q, k, v, causal=True),
                     mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
                 return fa(q, k, v).astype(q.dtype)

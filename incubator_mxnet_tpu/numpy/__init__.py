@@ -113,7 +113,7 @@ _DELEGATED = [
     "concatenate", "copysign", "cos", "cosh", "cross", "cumprod", "cumsum",
     "deg2rad", "degrees", "diag", "diag_indices", "diagonal", "diff",
     "divide", "dot", "dsplit", "dstack", "ediff1d", "einsum", "equal", "exp",
-    "expand_dims", "expm1", "fix", "flip", "fliplr", "flipud", "floor",
+    "expand_dims", "expm1", "flip", "fliplr", "flipud", "floor",
     "floor_divide", "fmax", "fmin", "fmod", "greater", "greater_equal",
     "heaviside", "histogram", "hsplit", "hstack", "hypot", "insert",
     "interp", "invert", "isfinite", "isinf", "isnan", "kron", "lcm",
@@ -141,6 +141,7 @@ _g = globals()
 for _name in _DELEGATED:
     if hasattr(_jnp, _name) and _name not in _g:
         _g[_name] = _wrap_fn(getattr(_jnp, _name))
+fix = _wrap_fn(_jnp.trunc)  # numpy.fix rounds toward zero: trunc
 
 
 class _Linalg:

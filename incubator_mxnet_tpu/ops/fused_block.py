@@ -44,7 +44,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_kernels import _round_up, interpret_mode, use_pallas
+from .pallas_kernels import _round_up, dispatch, interpret_mode
 
 __all__ = ["fused_matmul_bn", "bn_consts", "xla_matmul_bn"]
 
@@ -341,17 +341,16 @@ def fused_matmul_bn(x, w, scale=None, bias=None):
     Returns ``(y, s1, s2)`` with ``s1 = sum_M(y)``, ``s2 = sum_M(y^2)``
     in fp32: ``mean = s1/M``, ``var = s2/M - mean^2`` (one-pass BN).
     """
-    prologue = scale is not None
     if scale is None:
-        scale = jnp.ones((x.shape[1],), jnp.float32)
-        bias = jnp.zeros((x.shape[1],), jnp.float32)
-    if not use_pallas("fused_matmul_bn"):
         # same contract as every other kernel gate (e.g. layer_norm):
-        # off-TPU auto mode falls back to the XLA composition; tests
-        # that want interpret-mode Pallas force MXNET_USE_PALLAS=1
-        return xla_matmul_bn(x, w, scale if prologue else None,
-                             bias if prologue else None)
-    return _fmm(x, w, scale, bias, prologue)
+        # see pallas_kernels.dispatch; tests that want interpret-mode
+        # Pallas off-TPU force MXNET_USE_PALLAS=1
+        ones = jnp.ones((x.shape[1],), jnp.float32)
+        return dispatch(
+            lambda x, w: _fmm(x, w, ones, jnp.zeros_like(ones), False),
+            xla_matmul_bn, x, w)
+    return dispatch(lambda x, w, s, b: _fmm(x, w, s, b, True),
+                    xla_matmul_bn, x, w, scale, bias)
 
 
 def _bottleneck_core(x, w1, g1, b1, w2, g2, b2, w3, g3, b3,
@@ -379,9 +378,9 @@ def _bottleneck_core(x, w1, g1, b1, w2, g2, b2, w3, g3, b3,
     # 3x3 stage conv: bn1's normalize+ReLU runs in the conv prologue
     # (the normalized y1 copy never exists in HBM) and bn2's batch
     # stats come from the conv epilogue — the round-5 extension of the
-    # 1x1 pattern to the remaining stage-conv traffic.  Falls back to
-    # the XLA composition (normalize+conv+stats, identical contract)
-    # off-manifest or at over-VMEM widths.
+    # 1x1 pattern to the remaining stage-conv traffic.  Geometry the
+    # blocking plan cannot fit in VMEM rides the XLA composition
+    # (normalize+conv+stats, identical contract), with a warning.
     from .fused_conv import fused_conv3_bn
     y2, a2, c2 = fused_conv3_bn(y1.reshape(n, hs, ws, cm),
                                 jnp.transpose(w2, (1, 2, 3, 0)), sc1, of1)

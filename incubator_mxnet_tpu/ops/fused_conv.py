@@ -34,34 +34,36 @@ stats accumulated in fp32 from the *rounded* output (the one-pass
 E[x^2]-mu^2 convention of ops.nn_ops.batch_norm).
 
 VMEM policy: channel width and block height anti-correlate in ResNet
-(56px@64ch ... 7px@512ch), so whole-image blocks fit comfortably up to
-256 channels with a single output block; wider outputs (512-channel
-stage-4) split the output-channel dimension into N blocks sized by a
-working-set estimate, with dx accumulated in fp32 across N blocks and
-its ReLU/normalize backward applied at the last one.  Geometry the
-plan cannot cover at any width — and any stride/kernel shape this
-kernel does not implement — falls back to the XLA composition.
+(56px@64ch ... 7px@512ch), so whole-image blocks with a single output
+block cover all four ResNet-50 stages inside the 64 MiB the kernels ask
+Mosaic for; wider outputs split the output-channel dimension into N
+blocks sized by a calibrated working-set bound (_Geom._bytes), with dx
+accumulated in fp32 across N blocks and its ReLU/normalize backward
+applied at the last one.  Geometry the plan cannot cover at any width
+(a 112x112 image block, say) runs the XLA composition, and says so.
 """
 from __future__ import annotations
 
 import functools
 import os
+import warnings
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_kernels import (_round_up, interpret_mode, kernel_known_good,
-                             use_pallas)
+from .pallas_kernels import _round_up, dispatch, interpret_mode
 
 __all__ = ["fused_conv3_bn", "xla_conv3_bn"]
 
-# VMEM working-set ceiling for the fused conv kernels (bytes).  The dw
-# kernel is the worst case: the stacked in-register tap gradients PLUS
-# the accumulating output ref (2 * 9*kp*bn*4 fp32) + activation/
-# cotangent tiles — see _Geom._bytes for the exact model.
-_VMEM_BUDGET = int(os.environ.get("MXNET_FUSED_CONV3_VMEM", 10 * 2 ** 20))
+# Scoped-VMEM ceiling for the fused conv kernels (bytes): BOTH the
+# limit handed to Mosaic (`vmem_limit_bytes` — its default, 16 MiB on
+# v5e, refuses the 56x56 stage-1 block) and the ceiling the blocking
+# plan holds its own estimate to (_Geom._bytes).  Half of a v5e core's
+# 128 MiB of VMEM.
+_VMEM_BUDGET = int(os.environ.get("MXNET_FUSED_CONV3_VMEM", 64 * 2 ** 20))
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=_VMEM_BUDGET)
 
 _TAPS = [(dh, dw) for dh in (-1, 0, 1) for dw in (-1, 0, 1)]
 
@@ -75,11 +77,8 @@ def _shift_rows(a, off):
     wrapped/zero-filled row — so the zero-fill (concat) and wrap-around
     (roll) implementations are interchangeable.  `concat` is the
     default; `MXNET_FUSED_CONV3_SHIFT=roll` switches to pltpu.roll as
-    an on-chip escape hatch should Mosaic reject the unaligned
-    sublane-dim concatenation.  When flipping the switch on hardware,
-    rerun `scripts/pallas_smoke.py --kernels fused_conv3_bn` with it
-    set: the smoke validates the roll path against the XLA oracle
-    before any bench trusts it."""
+    the variant for the on-chip A/B (Mosaic compiles the concat form
+    at all four ResNet-50 stage widths — tests/test_tpu_compile.py)."""
     if off == 0:
         return a
     if os.environ.get("MXNET_FUSED_CONV3_SHIFT", "concat") == "roll":
@@ -271,14 +270,14 @@ def _bwd_dw_kernel(x_ref, dy_ref, y_ref, ds1_ref, ds2_ref, sc_ref, bi_ref,
 # ---------------------------------------------------------------------------
 
 class _Geom:
-    """Blocking plan for a (N, H, W, C)->C_out fused conv, or None when
-    the kernel cannot cover the configuration (wrapper falls back).
+    """Blocking plan for a (N, H, W, C)->C_out fused conv; `fits()` is
+    false when the kernel cannot cover the configuration.
 
     The M dimension is blocked into whole images (bm = b*H*W rows).
     The output-channel dimension is blocked too (bn), chosen as the
     widest divisor of the padded width whose worst-case kernel working
-    set fits the VMEM budget — wide stages (512-channel stage-4) run
-    with several N blocks instead of falling back to XLA."""
+    set fits the VMEM budget — outputs too wide for one block run with
+    several N blocks instead of leaving the kernel."""
 
     def __init__(self, x4, cout):
         n, h, w, c = x4.shape
@@ -300,18 +299,17 @@ class _Geom:
         self.bn = self._pick_bn()
 
     def _bytes(self, bn):
-        """Worst working set across the three kernels at width bn."""
-        bm, kp = self.bm, self.kp
-        fwd = bm * kp * 6 + 9 * kp * bn * 2 + bm * bn * 6
-        # nb-dx keeps THREE live (bm, kp) fp32 buffers at once: the
-        # accumulating dx block, the current partial, and xf in the
-        # finish epilogue (review finding) — plus the cotangent tiles
-        dx = (bm * bn * 8 + 9 * kp * bn * 2 + bm * kp * 2
-              + 3 * bm * kp * 4)
-        # dw: the stacked in-register tap gradients live alongside the
-        # accumulating output ref -> 2x the (9*kp, bn) fp32 term
-        dw = bm * kp * 6 + bm * bn * 8 + 2 * 9 * kp * bn * 4
-        return max(fwd, dx, dw)
+        """Upper bound on the scoped VMEM Mosaic wants for the worst of
+        the three kernels at output-block width bn.  Calibrated, not
+        derived: the least `vmem_limit_bytes` each kernel compiles
+        under was bisected for a described v5e (libtpu 0.0.34) at the
+        four ResNet-50 stage shapes in bf16 and f32, stage 4 at bn
+        128/256/512, and a 112x112x64 image.  The nine unrolled taps
+        keep ~50-60 B live per element of a (bm, 128) activation tile
+        (23.8 MiB at 56x56x64, where this bound says 26.7); the weight
+        and dw tiles cost at most 16 B per element, double buffered."""
+        return (32 * self.bm * (self.kp + bn)
+                + 16 * 9 * self.kp * bn)
 
     def _pick_bn(self):
         bn = self.np
@@ -372,6 +370,7 @@ def _fwd_impl(x4, w, scale, bias, prologue):
                          memory_space=pltpu.VMEM),
         ],
         interpret=interpret_mode(),
+        compiler_params=_COMPILER_PARAMS,
     )(g.pad_x(x4), g.pad_w(w), g.pad_vec(scale, g.kp),
       g.pad_vec(bias, g.kp))
     y = y[:g.m, :g.cout].reshape(g.n, g.h, g.w, g.cout)
@@ -411,6 +410,7 @@ def _bwd_impl(x4, w, scale, bias, y4, dy4, ds1, ds2, prologue):
                       row_spec(g.kp), vec_spec(g.kp), vec_spec(g.kp)],
             out_specs=[row_spec(g.kp), vec_spec(g.kp), vec_spec(g.kp)],
             interpret=interpret_mode(),
+            compiler_params=_COMPILER_PARAMS,
         )(dyp, yp, ds1p, ds2p, wp, xp, scp, bip)
     else:
         # wide outputs: accumulate fp32 dx partials across N blocks,
@@ -438,6 +438,7 @@ def _bwd_impl(x4, w, scale, bias, y4, dy4, ds1, ds2, prologue):
                       mrow(g.kp), cvec(g.kp), cvec(g.kp)],
             out_specs=[mrow(g.kp), cvec(g.kp), cvec(g.kp)],
             interpret=interpret_mode(),
+            compiler_params=_COMPILER_PARAMS,
         )(dyp, yp, ds1p, ds2p, wp, xp, scp, bip)
 
     dw_spec = lambda cols, im: pl.BlockSpec(  # noqa: E731
@@ -461,6 +462,7 @@ def _bwd_impl(x4, w, scale, bias, y4, dy4, ds1, ds2, prologue):
         out_specs=pl.BlockSpec((9 * g.kp, g.bn), lambda j, i: (0, j),
                                memory_space=pltpu.VMEM),
         interpret=interpret_mode(),
+        compiler_params=_COMPILER_PARAMS,
     )(xp, dyp, yp, ds1p, ds2p, scp, bip)
 
     dx = dx[:g.m, :g.c].astype(x4.dtype).reshape(x4.shape)
@@ -515,22 +517,6 @@ def xla_conv3_bn(x, w, scale=None, bias=None):
             jnp.sum(jnp.square(yf), axis=(0, 1, 2)))
 
 
-def _conv3_kernel_on():
-    """Kernel dispatch gate.  Unlike the generic use_pallas contract,
-    an explicit MXNET_USE_PALLAS=1 still honors a negative manifest
-    verdict here: the bench forces '1' for the fused-bottleneck config,
-    and a Mosaic-broken conv kernel must downgrade to the XLA
-    composition (the 1x1 kernels keep running) rather than sink the
-    whole attempt.  MXNET_FUSED_CONV3 ∈ {auto,0,1} overrides."""
-    flag = os.environ.get("MXNET_FUSED_CONV3", "auto").lower()
-    if flag in ("0", "false", "off"):
-        return False
-    if flag in ("1", "true", "on"):
-        return True
-    return use_pallas("fused_conv3_bn") and kernel_known_good(
-        "fused_conv3_bn")
-
-
 def fused_conv3_bn(x, w, scale=None, bias=None):
     """3x3/s1/p1 NHWC conv with BN stats epilogue and optional
     normalize+ReLU prologue.
@@ -545,22 +531,30 @@ def fused_conv3_bn(x, w, scale=None, bias=None):
     ``s1 = sum(y)``, ``s2 = sum(y^2)`` over N*H*W (one-pass BN stats:
     mean = s1/M, var = s2/M - mean^2).
     """
-    prologue = scale is not None
     if w.ndim != 4 or w.shape[0] != 3 or w.shape[1] != 3:
         raise ValueError(f"fused_conv3_bn needs a 3x3 HWIO kernel, "
                          f"got {w.shape}")
-    if scale is None:
-        scale = jnp.ones((x.shape[-1],), jnp.float32)
-        bias = jnp.zeros((x.shape[-1],), jnp.float32)
+    args = (x, w) if scale is None else (x, w, scale, bias)
     # per-width tuning knob: after the on-chip fc3 A/B
     # (scripts/perf_probe.py fc3), restrict the kernel to the input
     # widths where it wins, e.g. MXNET_FUSED_CONV3_WIDTHS=64,128 —
     # losing widths ride the XLA composition with no code change
     widths = os.environ.get("MXNET_FUSED_CONV3_WIDTHS")
-    width_ok = (widths is None
-                or x.shape[-1] in {int(v) for v in widths.split(",") if v})
-    if not (width_ok and _conv3_kernel_on()
-            and _Geom(x, w.shape[-1]).fits()):
-        return xla_conv3_bn(x, w, scale if prologue else None,
-                            bias if prologue else None)
-    return _fc3(x, w, scale, bias, prologue)
+    if widths is not None and x.shape[-1] not in {
+            int(v) for v in widths.split(",") if v}:
+        return xla_conv3_bn(*args)
+
+    def kernel(x, w, scale=None, bias=None):
+        if not _Geom(x, w.shape[-1]).fits():
+            warnings.warn(
+                f"fused_conv3_bn: no whole-image blocking of "
+                f"x{tuple(x.shape)} -> {w.shape[-1]} channels fits the "
+                f"{_VMEM_BUDGET >> 20} MiB VMEM budget; this conv runs "
+                "the XLA composition")
+            return xla_conv3_bn(x, w, scale, bias)
+        if scale is None:
+            ones = jnp.ones((x.shape[-1],), jnp.float32)
+            return _fc3(x, w, ones, jnp.zeros_like(ones), False)
+        return _fc3(x, w, scale, bias, True)
+
+    return dispatch(kernel, xla_conv3_bn, *args)

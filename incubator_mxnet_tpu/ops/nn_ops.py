@@ -262,18 +262,22 @@ def layer_norm(x, gamma, beta, axis=-1, eps=1e-5):
     """LayerNorm (reference src/operator/nn/layer_norm.cc) — a single fused
     XLA subgraph, or the hand-fused Pallas kernel for the common
     trailing-axis case on TPU (ops/pallas_kernels.fused_layer_norm)."""
+    def xla(x, gamma, beta):
+        xf = x.astype(jnp.float32) if x.dtype != jnp.float32 else x
+        mean = jnp.mean(xf, axis=axis, keepdims=True)
+        var = jnp.var(xf, axis=axis, keepdims=True)
+        x_hat = (xf - mean) * lax.rsqrt(var + eps)
+        shape = [1] * x.ndim
+        shape[axis % x.ndim] = x.shape[axis % x.ndim]
+        out = x_hat * gamma.reshape(shape) + beta.reshape(shape)
+        return out.astype(x.dtype)
+
     if isinstance(axis, int) and axis in (-1, x.ndim - 1) and gamma.ndim == 1:
         from . import pallas_kernels as pk
-        if pk.use_pallas("fused_layer_norm"):
-            return pk.fused_layer_norm(x, gamma, beta, float(eps))
-    xf = x.astype(jnp.float32) if x.dtype != jnp.float32 else x
-    mean = jnp.mean(xf, axis=axis, keepdims=True)
-    var = jnp.var(xf, axis=axis, keepdims=True)
-    x_hat = (xf - mean) * lax.rsqrt(var + eps)
-    shape = [1] * x.ndim
-    shape[axis % x.ndim] = x.shape[axis % x.ndim]
-    out = x_hat * gamma.reshape(shape) + beta.reshape(shape)
-    return out.astype(x.dtype)
+        return pk.dispatch(
+            lambda x, g, b: pk.fused_layer_norm(x, g, b, float(eps)),
+            xla, x, gamma, beta)
+    return xla(x, gamma, beta)
 
 
 @register("GroupNorm", aliases=("group_norm",))
@@ -318,12 +322,19 @@ def rms_norm(x, gamma, axis=-1, eps=1e-6):
     """TPU-era addition (not in the reference): used by the transformer
     stack.  Trailing-axis case runs the fused Pallas kernel on TPU
     (pallas_kernels.fused_rms_norm), like LayerNorm/softmax."""
-    from . import pallas_kernels as pk
-    if axis in (-1, x.ndim - 1) and pk.use_pallas("fused_rms_norm"):
-        return pk.fused_rms_norm(x, gamma, eps)
-    ms = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=axis, keepdims=True)
-    y = (x.astype(jnp.float32) * lax.rsqrt(ms + eps)).astype(x.dtype)
-    return y * gamma
+    def xla(x, gamma):
+        # same contract as the kernel: fp32 statistics and scale, the
+        # result in x's dtype
+        xf = x.astype(jnp.float32)
+        ms = jnp.mean(jnp.square(xf), axis=axis, keepdims=True)
+        return (xf * lax.rsqrt(ms + eps)
+                * gamma.astype(jnp.float32)).astype(x.dtype)
+
+    if axis in (-1, x.ndim - 1):
+        from . import pallas_kernels as pk
+        return pk.dispatch(lambda x, g: pk.fused_rms_norm(x, g, eps),
+                           xla, x, gamma)
+    return xla(x, gamma)
 
 
 # ---------------------------------------------------------------------------
@@ -343,9 +354,10 @@ def softmax(x, axis=-1, temperature=None, length=None):
         idx = jnp.arange(x.shape[ax]).reshape(shape)
         mask = idx < jnp.expand_dims(length, ax)
         x = jnp.where(mask, x, -jnp.inf)
-    from . import pallas_kernels as pk
-    if isinstance(axis, int) and pk.use_pallas("fused_softmax"):
-        return pk.fused_softmax(x, axis)
+    if isinstance(axis, int):
+        from . import pallas_kernels as pk
+        return pk.dispatch(lambda x: pk.fused_softmax(x, axis),
+                           lambda x: jnn.softmax(x, axis=axis), x)
     return jnn.softmax(x, axis=axis)
 
 
@@ -754,15 +766,16 @@ def softmax_xent(logits, labels):
     blockwise).  Output dtype follows logits like the log_softmax+pick
     formulation."""
     from . import pallas_kernels as pk
-    lbl = labels.astype(jnp.int32)
-    if pk.use_pallas("fused_softmax_xent"):
-        out = pk.fused_softmax_xent(logits, lbl)
-    else:
+
+    def xla(logits, lbl):
         lp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
         # pick(mode='clip') semantics, same as the Pallas kernel: padding
         # labels like -1 clamp to a valid row instead of wrapping
         safe = jnp.clip(lbl, 0, logits.shape[-1] - 1)
-        out = -jnp.take_along_axis(lp, safe[:, None], axis=-1)[:, 0]
+        return -jnp.take_along_axis(lp, safe[:, None], axis=-1)[:, 0]
+
+    out = pk.dispatch(pk.fused_softmax_xent, xla, logits,
+                      labels.astype(jnp.int32))
     return out.astype(logits.dtype)
 
 

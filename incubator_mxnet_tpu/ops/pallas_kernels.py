@@ -16,13 +16,20 @@ reaches for Pallas only where a manual schedule beats it:
 
 Kernels run in interpret mode off-TPU so CPU tests exercise identical
 code paths; wrappers pad to TPU tile boundaries ((8,128) f32) and mask.
-``MXNET_USE_PALLAS`` ∈ {"0","1","auto"} gates dispatch from the op layer
-(auto = only on TPU backends).
+Whether an op routes to its kernel is decided by the platform and the
+shape alone (:func:`dispatch`): ``MXNET_USE_PALLAS`` ∈ {"0","1","auto"}
+gates it from the op layer (auto = where the computation lowers for a
+TPU), and rows wider than ``_MAX_COLS`` ride the XLA formulation.  That every kernel compiles
+under Mosaic at real widths is pinned by tests/test_tpu_compile.py
+(described-topology compiles) and proven on the chip by
+``chip_smoke.py``'s ``kernels`` phase.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import os
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -30,78 +37,60 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["fused_softmax", "fused_layer_norm", "flash_attention",
-           "use_pallas", "interpret_mode", "fused_softmax_xent",
-           "fused_rms_norm"]
+           "dispatch", "interpret_mode", "gspmd_trace",
+           "fused_softmax_xent", "fused_rms_norm"]
 
 _NEG_INF = -1e30
 
 
 def interpret_mode() -> bool:
-    """Pallas interpret mode: on unless running on a real TPU backend."""
+    """Pallas interpret mode: on unless running on a real TPU backend.
+    A backend that fails to initialize raises here — it is never read
+    as "no TPU"."""
+    return jax.default_backend() != "tpu"
+
+
+_trace = threading.local()
+
+
+@contextlib.contextmanager
+def gspmd_trace(over_mesh=True):
+    """Mark what is traced inside as a program GSPMD partitions over a
+    mesh (``jit`` with mesh shardings).  A Mosaic kernel cannot be
+    partitioned automatically — jax refuses the program and asks for a
+    ``shard_map`` around the call — so in here :func:`dispatch` routes
+    every op to its XLA composition, which GSPMD partitions natively."""
+    was = getattr(_trace, "gspmd", False)
+    _trace.gspmd = was or bool(over_mesh)
     try:
-        return jax.default_backend() != "tpu"
-    except Exception:  # mxlint: allow-broad-except(backend init failure of any kind means interpret mode is the safe answer)
-        return True
+        yield
+    finally:
+        _trace.gspmd = was
 
 
-_MANIFEST_CACHE: list = []  # [parsed-or-None], lazily filled
+def dispatch(kernel, xla, *args):
+    """Route one op call to ``kernel(*args)`` (a Pallas wrapper) or to
+    ``xla(*args)`` (its XLA composition, same contract), from what can
+    be observed:
 
-
-def manifest_path() -> str:
-    return os.environ.get(
-        "MXNET_PALLAS_MANIFEST",
-        os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                     "pallas_manifest.json"))
-
-
-def _manifest():
-    """Known-good kernel manifest written by scripts/pallas_smoke.py on
-    real hardware (VERDICT r3 Next #2; reference analog: NVRTC fused-op
-    verification, fused_op.cu:174-186).  Only a manifest recorded on the
-    CURRENT backend platform applies."""
-    if not _MANIFEST_CACHE:
-        parsed = None
-        try:
-            import json
-            with open(manifest_path()) as f:
-                parsed = json.load(f)
-        except (OSError, ValueError):
-            parsed = None
-        _MANIFEST_CACHE.append(parsed)
-    m = _MANIFEST_CACHE[0]
-    if m and m.get("platform") == jax.default_backend():
-        return m
-    return None
-
-
-def reload_manifest():
-    _MANIFEST_CACHE.clear()
-
-
-def kernel_known_good(name: str) -> bool:
-    """False only when a manifest for this platform explicitly marks the
-    kernel failed; no manifest (or an unknown name) stays permissive —
-    the smoke harness always writes every kernel, so unknown names only
-    occur mid-development."""
-    m = _manifest()
-    if m is None:
-        return True
-    return bool(m.get("kernels", {}).get(name, {}).get("ok", True))
-
-
-def use_pallas(kernel: str | None = None) -> bool:
-    """MXNET_USE_PALLAS: '0' forces off, '1' forces ON (manifest
-    ignored — the explicit override contract; the smoke harness itself
-    relies on it), 'auto' (default) = TPU backend AND the kernel not
-    marked bad in the platform's smoke manifest."""
+    * ``MXNET_USE_PALLAS`` '0' forces the composition, '1' the kernel
+      (interpreted off-TPU — how the CPU tests reach the kernels);
+    * 'auto' (default): a process without a TPU backend, and a program
+      GSPMD partitions over a mesh (:func:`gspmd_trace`), get the
+      composition; a process with one gets BOTH, under
+      ``lax.platform_dependent``, and the lowering picks — the kernel
+      where the computation is placed on a TPU, the composition where
+      it is placed on the host's CPU (``mx.cpu()`` arrays on a TPU
+      machine: Mosaic cannot lower there).
+    """
     flag = os.environ.get("MXNET_USE_PALLAS", "auto").lower()
     if flag in ("0", "false", "off"):
-        return False
+        return xla(*args)
     if flag in ("1", "true", "on"):
-        return True
-    if jax.default_backend() != "tpu":
-        return False
-    return kernel is None or kernel_known_good(kernel)
+        return kernel(*args)
+    if jax.default_backend() != "tpu" or getattr(_trace, "gspmd", False):
+        return xla(*args)
+    return jax.lax.platform_dependent(*args, tpu=kernel, default=xla)
 
 
 def _round_up(x: int, m: int) -> int:
@@ -141,7 +130,13 @@ def _softmax_bwd_kernel(y_ref, g_ref, o_ref):
     o_ref[:] = (y * (g - inner)).astype(o_ref.dtype)
 
 
-_VMEM_BUDGET = 8 * 1024 * 1024  # bytes; ~half the ~16 MB/core VMEM
+# Bytes one copy of a row-wise kernel's blocks may take, counted as
+# f32.  Mosaic double-buffers every pipelined block and the kernels keep
+# two or three block-sized f32 temporaries, so what the compiler is
+# asked for is four times this — 32 MiB of a v5e core's 128; under its
+# 16 MiB default the _MAX_COLS-wide rows do not compile.
+_VMEM_BUDGET = 8 * 1024 * 1024
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=4 * _VMEM_BUDGET)
 
 
 def _rowwise_block(rows_p, cols_p, n_buffers):
@@ -166,7 +161,26 @@ def _rowwise_call(kernel, out_dtype, n_inputs, x2d_list):
         in_specs=[spec] * n_inputs,
         out_specs=spec,
         interpret=interpret_mode(),
+        compiler_params=_COMPILER_PARAMS,
     )(*x2d_list)
+
+
+def _col_partial(a):
+    """Column sums of a (block_r, cols) tile kept as EIGHT sublane rows:
+    Mosaic wants the last two block dims divisible by (8, 128), so a
+    per-row-block partial is an (8, cols) tile the caller sums, never a
+    (1, cols) one.  Folding rows onto sublanes is plain vector adds —
+    no cross-sublane reduce in the kernel."""
+    return a.reshape(a.shape[0] // 8, 8, a.shape[1]).sum(axis=0)
+
+
+def _row_valid(block_r, n_rows):
+    """(block_r, 1) mask of the rows of this grid step that exist in
+    the unpadded input: a ragged last block reads whatever lies past
+    the array, and column sums must not see it."""
+    row = pl.program_id(0) * block_r + jax.lax.broadcasted_iota(
+        jnp.int32, (block_r, 1), 0)
+    return row < n_rows
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
@@ -235,14 +249,14 @@ def _ln_fwd_kernel(x_ref, gamma_ref, beta_ref, o_ref, mean_ref, rstd_ref,
 
 
 def _ln_bwd_kernel(x_ref, g_ref, gamma_ref, mean_ref, rstd_ref,
-                   dx_ref, dgamma_ref, dbeta_ref, *, n_cols):
+                   dx_ref, dgamma_ref, dbeta_ref, *, n_rows, n_cols):
     x = x_ref[:].astype(jnp.float32)
     g = g_ref[:].astype(jnp.float32)
     gamma = gamma_ref[:].astype(jnp.float32)
     mean = mean_ref[:]
     rstd = rstd_ref[:]
     col = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
-    valid = col < n_cols
+    valid = (col < n_cols) & _row_valid(x.shape[0], n_rows)
     xhat = jnp.where(valid, (x - mean) * rstd, 0.0)
     gv = jnp.where(valid, g, 0.0)
     # dx = rstd * (gγ − mean(gγ) − xhat·mean(gγ·xhat))
@@ -252,8 +266,8 @@ def _ln_bwd_kernel(x_ref, g_ref, gamma_ref, mean_ref, rstd_ref,
     dx = (ggam - m1 - xhat * m2) * rstd
     dx_ref[:] = jnp.where(valid, dx, 0.0).astype(dx_ref.dtype)
     # per-row-block partials, reduced across blocks by the caller
-    dgamma_ref[:] = jnp.sum(gv * xhat, axis=0, keepdims=True)
-    dbeta_ref[:] = jnp.sum(gv, axis=0, keepdims=True)
+    dgamma_ref[:] = _col_partial(gv * xhat)
+    dbeta_ref[:] = _col_partial(gv)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -289,6 +303,7 @@ def _ln_fwd(x, gamma, beta, eps):
         in_specs=[row_spec, vec_spec, vec_spec],
         out_specs=(row_spec, stat_spec, stat_spec),
         interpret=interpret_mode(),
+        compiler_params=_COMPILER_PARAMS,
     )(x2d_p, gamma_p.reshape(1, -1), beta_p.reshape(1, -1))
     return y[:rows, :cols].reshape(*lead, cols), mean, rstd
 
@@ -316,17 +331,20 @@ def _fused_ln_bwd(eps, res, g):
                             memory_space=pltpu.VMEM)
     stat_spec = pl.BlockSpec((block_r, 1), lambda i: (i, 0),
                              memory_space=pltpu.VMEM)
-    part_spec = pl.BlockSpec((1, cols_p), lambda i: (i, 0),
+    part_spec = pl.BlockSpec((8, cols_p), lambda i: (i, 0),
                              memory_space=pltpu.VMEM)
     dx, dgamma_part, dbeta_part = pl.pallas_call(
-        functools.partial(_ln_bwd_kernel, n_cols=cols),
+        functools.partial(_ln_bwd_kernel, n_rows=rows, n_cols=cols),
         out_shape=(jax.ShapeDtypeStruct((rows_p, cols_p), x.dtype),
-                   jax.ShapeDtypeStruct((n_blocks, cols_p), jnp.float32),
-                   jax.ShapeDtypeStruct((n_blocks, cols_p), jnp.float32)),
+                   jax.ShapeDtypeStruct((8 * n_blocks, cols_p),
+                                        jnp.float32),
+                   jax.ShapeDtypeStruct((8 * n_blocks, cols_p),
+                                        jnp.float32)),
         grid=(n_blocks,),
         in_specs=[row_spec, row_spec, vec_spec, stat_spec, stat_spec],
         out_specs=(row_spec, part_spec, part_spec),
         interpret=interpret_mode(),
+        compiler_params=_COMPILER_PARAMS,
     )(x2d_p, g2d_p, gamma_p.reshape(1, -1), mean, rstd)
     dx = dx[:rows, :cols].reshape(*lead, cols)
     dgamma = dgamma_part.sum(axis=0)[:cols].astype(gamma.dtype)
@@ -514,17 +532,17 @@ def flash_attention(q, k, v, sm_scale=None, causal=False):
     caps sequence length by device memory, SURVEY.md §5.7); pairs with
     parallel/ring_attention.py for the sequence-parallel path.
 
-    If the smoke manifest marks this kernel bad on the current hardware,
-    falls back to the O(T²) XLA formulation instead of risking a Mosaic
-    failure mid-run.
+    In a process without a TPU the kernel always runs (interpret mode),
+    so CPU tests cover it; with one, :func:`dispatch` decides between
+    the kernel and the O(T²) XLA formulation.
     """
     scale = float(sm_scale) if sm_scale is not None else q.shape[-1] ** -0.5
-    # on real hardware honor both the MXNET_USE_PALLAS flag (bench's
-    # degraded retry sets 0) and the smoke manifest; interpret mode (CPU
-    # tests) always runs the kernel path
-    if not interpret_mode() and not use_pallas("flash_attention"):
-        return _xla_attention(q, k, v, scale, bool(causal))
-    return _flash_core(q, k, v, scale, bool(causal))
+    causal = bool(causal)
+    if interpret_mode():
+        return _flash_core(q, k, v, scale, causal)
+    return dispatch(lambda q, k, v: _flash_core(q, k, v, scale, causal),
+                    lambda q, k, v: _xla_attention(q, k, v, scale, causal),
+                    q, k, v)
 
 
 def _xla_attention(q, k, v, scale, causal):
@@ -586,6 +604,7 @@ def _xent_call(kernel, out_shape, x2d, lbl2d, *extra):
         in_specs=in_specs,
         out_specs=out_spec,
         interpret=interpret_mode(),
+        compiler_params=_COMPILER_PARAMS,
     )(x2d, lbl2d, *extra)
 
 
@@ -667,13 +686,13 @@ def _rms_fwd_kernel(x_ref, gamma_ref, o_ref, rrms_ref, *, n_cols, eps):
 
 
 def _rms_bwd_kernel(x_ref, g_ref, gamma_ref, rrms_ref, dx_ref, dgamma_ref,
-                    *, n_cols):
+                    *, n_rows, n_cols):
     x = x_ref[:].astype(jnp.float32)
     g = g_ref[:].astype(jnp.float32)
     gamma = gamma_ref[:].astype(jnp.float32)
     rrms = rrms_ref[:]
     col = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
-    valid = col < n_cols
+    valid = (col < n_cols) & _row_valid(x.shape[0], n_rows)
     xv = jnp.where(valid, x, 0.0)
     gv = jnp.where(valid, g, 0.0)
     ggam = gv * gamma
@@ -681,7 +700,8 @@ def _rms_bwd_kernel(x_ref, g_ref, gamma_ref, rrms_ref, dx_ref, dgamma_ref,
     s = jnp.sum(ggam * xv, axis=-1, keepdims=True)
     dx = rrms * (ggam - xv * (rrms * rrms) * s / n_cols)
     dx_ref[:] = jnp.where(valid, dx, 0.0).astype(dx_ref.dtype)
-    dgamma_ref[:] = jnp.sum(gv * xv * rrms, axis=0, keepdims=True)
+    # select, not multiply: rrms of a row past the array is garbage
+    dgamma_ref[:] = _col_partial(jnp.where(valid, gv * xv * rrms, 0.0))
 
 
 def fused_rms_norm(x, gamma, eps=1e-6):
@@ -726,8 +746,9 @@ def _rms_fwd(x, gamma, eps):
         in_specs=[row_spec, vec_spec],
         out_specs=(row_spec, stat_spec),
         interpret=interpret_mode(),
+        compiler_params=_COMPILER_PARAMS,
     )(x2d_p, gamma_p.reshape(1, -1))
-    return y[:rows, :cols].reshape(*lead, cols), (x, gamma, rrms, rows)
+    return y[:rows, :cols].reshape(*lead, cols), (x, gamma, rrms)
 
 
 def _rms_vjp_fwd(x, gamma, eps):
@@ -735,10 +756,10 @@ def _rms_vjp_fwd(x, gamma, eps):
 
 
 def _rms_vjp_bwd(eps, res, g):
-    x, gamma, rrms, rows = res
+    x, gamma, rrms = res
     lead = x.shape[:-1]
     cols = x.shape[-1]
-    x2d_p, _, _ = _pad_rows_cols(x.reshape(-1, cols), 8, 128)
+    x2d_p, rows, _ = _pad_rows_cols(x.reshape(-1, cols), 8, 128)
     g2d_p, _, _ = _pad_rows_cols(
         g.reshape(-1, cols).astype(x.dtype), 8, 128)
     rows_p, cols_p = x2d_p.shape
@@ -751,16 +772,18 @@ def _rms_vjp_bwd(eps, res, g):
                             memory_space=pltpu.VMEM)
     stat_spec = pl.BlockSpec((block_r, 1), lambda i: (i, 0),
                              memory_space=pltpu.VMEM)
-    part_spec = pl.BlockSpec((1, cols_p), lambda i: (i, 0),
+    part_spec = pl.BlockSpec((8, cols_p), lambda i: (i, 0),
                              memory_space=pltpu.VMEM)
     dx, dgamma_parts = pl.pallas_call(
-        functools.partial(_rms_bwd_kernel, n_cols=cols),
+        functools.partial(_rms_bwd_kernel, n_rows=rows, n_cols=cols),
         out_shape=(jax.ShapeDtypeStruct((rows_p, cols_p), x.dtype),
-                   jax.ShapeDtypeStruct((n_blocks, cols_p), jnp.float32)),
+                   jax.ShapeDtypeStruct((8 * n_blocks, cols_p),
+                                        jnp.float32)),
         grid=(n_blocks,),
         in_specs=[row_spec, row_spec, vec_spec, stat_spec],
         out_specs=(row_spec, part_spec),
         interpret=interpret_mode(),
+        compiler_params=_COMPILER_PARAMS,
     )(x2d_p, g2d_p, gamma_p.reshape(1, -1), rrms)
     dgamma = dgamma_parts.sum(axis=0)[:cols].astype(gamma.dtype)
     return dx[:rows, :cols].reshape(*lead, cols), dgamma

@@ -95,8 +95,8 @@ class Op:
             # per-op jits ride the unified choke point too (sentinel
             # site op:{name} via Executor's instrument, persistent
             # compile cache init): eager dispatch is usually the FIRST
-            # thing a process compiles, and it must hit
-            # MXNET_COMPILE_CACHE_DIR like every other surface.  This
+            # thing a process compiles, and it must hit the
+            # persistent cache like every other surface.  This
             # path runs once per (op, kwarg-name set), never per call.
             # Eager-path inputs are live NDArray chunk values the
             # caller reads after the op, so nothing is donated

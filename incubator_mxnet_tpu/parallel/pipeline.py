@@ -16,7 +16,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..base import axis_size_compat, shard_map_compat
 
 __all__ = ["pipeline_forward"]
 
@@ -29,7 +28,7 @@ def _pipeline_sharded(stage_params, microbatches, stage_fn, axis_name,
     microbatches: (n_micro, mb_size, ...) — replicated input; rank 0
     feeds the pipeline, the last rank's outputs are collected.
     """
-    npp = axis_size_compat(axis_name)
+    npp = lax.axis_size(axis_name)
     rank = lax.axis_index(axis_name)
     if strip_stage_axis:
         # one layer per stage: drop the local (size-1) slice axis so
@@ -101,9 +100,10 @@ def pipeline_forward(stacked_params, x, stage_fn, mesh: Mesh, n_micro=4,
                            axis_name=axis_name,
                            strip_stage_axis=(stack == npp))
     param_specs = jax.tree_util.tree_map(lambda _: param_spec, stacked_params)
-    mapped = shard_map_compat(
+    mapped = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(param_specs, P()),
-        out_specs=P())
+        out_specs=P(),
+        check_vma=False)
     out = mapped(stacked_params, micro)
     return out.reshape(B, *out.shape[2:])

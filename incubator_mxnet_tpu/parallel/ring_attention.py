@@ -17,7 +17,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..base import axis_size_compat, shard_map_compat
 
 __all__ = ["ring_attention", "_ring_attention_sharded"]
 
@@ -43,7 +42,7 @@ def _local_block(q, k, v, m_prev, l_prev, o_prev, scale, mask=None):
 
 def _ring_attention_sharded(q, k, v, axis_name, causal=False):
     """Body run inside shard_map: q,k,v are (B, H, T_local, D) shards."""
-    nsp = axis_size_compat(axis_name)
+    nsp = lax.axis_size(axis_name)
     my_idx = lax.axis_index(axis_name)
     scale = 1.0 / (q.shape[-1] ** 0.5)
     B, H, T, D = q.shape
@@ -82,7 +81,8 @@ def ring_attention(q, k, v, mesh: Mesh, axis_name="sp", causal=False,
     """
     fn = functools.partial(_ring_attention_sharded, axis_name=axis_name,
                            causal=causal)
-    mapped = shard_map_compat(
+    mapped = jax.shard_map(
         fn, mesh=mesh, in_specs=(qkv_spec, qkv_spec, qkv_spec),
-        out_specs=qkv_spec)
+        out_specs=qkv_spec,
+        check_vma=False)
     return mapped(q, k, v)

@@ -16,14 +16,13 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..base import axis_size_compat, shard_map_compat
 
 __all__ = ["ulysses_attention"]
 
 
 def _ulysses_sharded(q, k, v, axis_name, causal):
     """q,k,v: (B, H, T_local, D) with H full, T sharded."""
-    nsp = axis_size_compat(axis_name)
+    nsp = lax.axis_size(axis_name)
     B, H, T, D = q.shape
     assert H % nsp == 0, "heads must divide sp degree for Ulysses"
 
@@ -62,7 +61,8 @@ def ulysses_attention(q, k, v, mesh: Mesh, axis_name="sp", causal=False,
                       qkv_spec=P("dp", None, "sp", None)):
     fn = functools.partial(_ulysses_sharded, axis_name=axis_name,
                            causal=causal)
-    mapped = shard_map_compat(
+    mapped = jax.shard_map(
         fn, mesh=mesh, in_specs=(qkv_spec, qkv_spec, qkv_spec),
-        out_specs=qkv_spec)
+        out_specs=qkv_spec,
+        check_vma=False)
     return mapped(q, k, v)

@@ -7,8 +7,6 @@ device counts.
 """
 from __future__ import annotations
 
-import importlib.util
-
 import jax
 
 
@@ -25,22 +23,18 @@ class Features(dict):
     def __init__(self):
         feats = {}
         platforms = {d.platform for d in jax.devices()}
-        feats["TPU"] = any(p not in ("cpu", "gpu") for p in platforms) or \
-            "tpu" in platforms
+        feats["TPU"] = "tpu" in platforms
         feats["CPU"] = True
         feats["GPU"] = "gpu" in platforms
         feats["CUDA"] = False
         feats["CUDNN"] = False
         feats["MKLDNN"] = False
         feats["XLA"] = True
-        feats["PALLAS"] = _has_pallas()
+        feats["PALLAS"] = True
         feats["BF16"] = True
         feats["INT8"] = True
         feats["DIST_KVSTORE"] = True
-        feats["SHARD_MAP"] = (
-            hasattr(jax, "shard_map")
-            or importlib.util.find_spec("jax.experimental.shard_map")
-            is not None)
+        feats["SHARD_MAP"] = True
         feats["OPENCV"] = _has_cv2()
         feats["SIGNAL_HANDLER"] = True
         feats["PROFILER"] = True
@@ -48,14 +42,6 @@ class Features(dict):
 
     def is_enabled(self, name):
         return self[name.upper()].enabled
-
-
-def _has_pallas():
-    try:
-        from jax.experimental import pallas  # noqa: F401
-        return True
-    except ImportError:
-        return False
 
 
 def _has_cv2():

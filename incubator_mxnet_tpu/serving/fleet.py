@@ -59,6 +59,7 @@ import time
 import numpy as onp
 
 from ..base import get_env
+from ..context import child_tpu_chips
 from .. import fault, flightrec, trace
 from ..error import ReplicaUnavailableError
 from ..locks import named_lock
@@ -79,6 +80,7 @@ class _ReplicaBase:
     """Shared lifecycle + health bookkeeping for both backends."""
 
     backend = "?"
+    chip = None     # the TPU chip a process replica owns (fleet-assigned)
 
     def __init__(self, rid, models, probe_fails=None):
         self.rid = rid
@@ -429,9 +431,10 @@ class ProcessReplica(_ReplicaBase):
 
     def __init__(self, rid, models, warmup=None, probe_fails=None,
                  startup_timeout_s=300.0, session_models=None,
-                 session_dir=None):
+                 session_dir=None, chip=None):
         super().__init__(rid, models, probe_fails=probe_fails)
         self._warmup = warmup
+        self.chip = chip   # None where subprocesses run on no TPU
         self._session_models = dict(session_models or {})
         for name, spec in self._session_models.items():
             if not isinstance(spec, str):
@@ -465,8 +468,16 @@ class ProcessReplica(_ReplicaBase):
             cmd.append("--no-warmup")
         repo_root = os.path.dirname(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))))
+        # the replica inherits this process's environment — platform
+        # (JAX_PLATFORMS), compile-cache directory and all: it runs
+        # where the operator pointed the fleet, never on a default
         env = dict(os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        if self.chip is not None:
+            # one process per chip: without these a subprocess claims
+            # every chip of the host and the next replica finds none
+            env.update(TPU_VISIBLE_CHIPS=str(self.chip),
+                       TPU_CHIPS_PER_PROCESS_BOUNDS="1,1,1",
+                       TPU_PROCESS_BOUNDS="1,1,1")
         env["PYTHONPATH"] = repo_root + os.pathsep + env.get(
             "PYTHONPATH", "")
         self._proc = subprocess.Popen(
@@ -798,6 +809,9 @@ class ProcessReplica(_ReplicaBase):
                 self._proc.wait(10.0)
 
 
+_UNPROBED = object()
+
+
 class ReplicaFleet:
     """Spawn/adopt N replicas; own their lifecycle, health and rolls.
 
@@ -834,6 +848,9 @@ class ReplicaFleet:
         self._probe_fails = probe_fails
         self._replicas: list = []
         self._next_rid = 0
+        # process backend on a TPU host: chips a subprocess finds
+        # (None: its backend is no TPU), probed once at first spawn
+        self._tpu_chips = _UNPROBED
         self._meta_cache: dict = {}       # name -> input specs
         self._lock = named_lock("fleet.state")
         self._stop = threading.Event()
@@ -867,7 +884,29 @@ class ReplicaFleet:
 
     # -- lifecycle ----------------------------------------------------
 
-    def _new_replica(self, models=None):
+    def _free_chip(self, pending=()):
+        """The chip a new process replica will own, or None where
+        replica subprocesses run on no TPU.  One process per chip: a
+        process replica beyond the host's chips is an error here, at
+        spawn — several replicas share a chip through the thread
+        backend."""
+        if self._tpu_chips is _UNPROBED:
+            self._tpu_chips = child_tpu_chips()
+        if self._tpu_chips is None:
+            return None
+        with self._lock:
+            busy = {r.chip for r in self._replicas if r.state != DEAD}
+        busy.update(r.chip for r in pending)
+        free = [c for c in range(self._tpu_chips) if c not in busy]
+        if not free:
+            raise ValueError(
+                f"--backend process needs one TPU chip per replica and "
+                f"all {self._tpu_chips} of this host are taken "
+                f"({len(busy)} live process replica(s)); use --backend "
+                f"thread to let several replicas share a chip")
+        return free[0]
+
+    def _new_replica(self, models=None, pending=()):
         with self._lock:
             rid = f"r{self._next_rid}"
             self._next_rid += 1
@@ -876,7 +915,8 @@ class ReplicaFleet:
             return ProcessReplica(rid, models, warmup=self._warmup,
                                   probe_fails=self._probe_fails,
                                   session_models=self.session_models,
-                                  session_dir=self.session_dir)
+                                  session_dir=self.session_dir,
+                                  chip=self._free_chip(pending))
         return ThreadReplica(rid, models, buckets=self._buckets,
                              warmup=self._warmup,
                              probe_fails=self._probe_fails,
@@ -886,7 +926,9 @@ class ReplicaFleet:
     def spawn(self):
         """Bring up all N replicas concurrently; raises if any failed
         to reach ``ready``.  Starts the prober.  Returns ``self``."""
-        fresh = [self._new_replica() for _ in range(self.n)]
+        fresh = []
+        for _ in range(self.n):
+            fresh.append(self._new_replica(pending=fresh))
         with self._lock:
             self._replicas.extend(fresh)
         errors = []

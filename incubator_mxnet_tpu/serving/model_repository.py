@@ -60,6 +60,8 @@ class ModelEntry:
             "cold_start_ms": self.cold_start_ms,
             "aot_buckets": self.predictor.aot_buckets,
             "aot_load_failures": self.predictor.aot_load_failures,
+            "device": {"platform": self.predictor.device.platform,
+                       "kind": self.predictor.device.device_kind},
             "compile_count": self.predictor.compile_count,
             "queue_depth": self.batcher.depth,
             "inputs": self.predictor.meta["inputs"],

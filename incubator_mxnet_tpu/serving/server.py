@@ -56,7 +56,10 @@ def health_body(repository, t_start=None, sessions=None):
     * ``draining`` — admission stopped; in-flight work finishing.
 
     Queue depth rides along per model (and summed at the top level) so
-    schedulers can shed load before the 429 bound bites.  Shared by
+    schedulers can shed load before the 429 bound bites.  Per-model
+    ``device`` says where the model's parameters live — platform and
+    kind as JAX reports them — so a replica serving from the wrong
+    platform is visible to whoever probes it.  Shared by
     the HTTP handler and the in-process fleet replicas, so the two
     probe paths can never disagree on shape."""
     draining = repository.admission.draining
@@ -75,12 +78,15 @@ def health_body(repository, t_start=None, sessions=None):
             # numbers an autoscaler sizes spawn lead time from
             "cold_start_ms": d["cold_start_ms"],
             "aot_buckets": d["aot_buckets"],
+            "aot_load_failures": d["aot_load_failures"],
+            "device": d["device"],
         }
     for name in repository.loading_names():
         if name not in models:
             models[name] = {"state": "loading", "version": None,
                             "queue_depth": 0, "compile_count": None,
-                            "cold_start_ms": None, "aot_buckets": []}
+                            "cold_start_ms": None, "aot_buckets": [],
+                            "aot_load_failures": 0, "device": None}
     body = {
         "status": "draining" if draining else "ok",
         "uptime_s": (round(time.monotonic() - t_start, 3)

@@ -529,7 +529,7 @@ def test_donate001_method_named_jit_not_flagged(tmp_path):
 
 def test_shard001_flags_bare_shard_map(tmp_path):
     fs = _lint_pkg_src(tmp_path, """
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         f = shard_map(lambda x: x)
         g = jax.pjit(lambda x: x, donate_argnums=(0,))
     """)
@@ -539,7 +539,7 @@ def test_shard001_flags_bare_shard_map(tmp_path):
 def test_shard001_explicit_sharding_passes(tmp_path):
     # keyword spelling, positional spelling, and in_shardings all count
     assert "MX-SHARD001" not in _rules(_lint_pkg_src(tmp_path, """
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         f = shard_map(body, mesh=mesh, in_specs=specs, out_specs=out)
         g = shard_map(body, mesh, specs, out)
         h = jax.pjit(fn, in_shardings=s, out_shardings=s,

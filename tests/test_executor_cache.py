@@ -5,9 +5,10 @@ Four batteries:
 * **TraceCache + Executor keying** — shape/dtype/static/donation
   changes miss (a fresh executable), re-entry hits (no retrace), and
   the compile_count probe tracks exactly that.
-* **Persistent compilation cache** — ``MXNET_COMPILE_CACHE_DIR`` is
-  honored at the shared init point: compiling through any Executor
-  populates the directory.
+* **Persistent compilation cache** — one rule at the shared init
+  point: ``JAX_COMPILATION_CACHE_DIR`` set means no directory is set
+  in code, unset means ``<checkout>/.jax_cache``; compiling through
+  any Executor populates the directory in effect.
 * **AOT executables** — envelope round-trip is bitwise-identical to
   the traced path; a version/platform mismatch or corrupted blob is a
   typed :class:`AOTCompatError` and the Predictor falls back to
@@ -20,6 +21,8 @@ Four batteries:
 """
 import json
 import os
+import subprocess
+import sys
 import warnings
 
 import numpy as onp
@@ -32,6 +35,8 @@ import incubator_mxnet_tpu as mx
 from incubator_mxnet_tpu import deploy, error, executor_cache as xc, profiler
 from incubator_mxnet_tpu.analysis import graphlint as gl
 from incubator_mxnet_tpu.gluon import nn
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture()
@@ -114,36 +119,69 @@ class TestTraceCache:
 # ---------------------------------------------------------------------------
 
 class TestPersistentCache:
-    def test_cache_dir_honored_at_shared_init(self, tmp_path,
-                                              monkeypatch):
-        d = str(tmp_path / "xla_cache")
-        os.makedirs(d)
-        monkeypatch.setenv("MXNET_COMPILE_CACHE_DIR", d)
-        xc._reset_compile_cache_for_tests()
-        try:
-            assert xc.ensure_compile_cache() == d
-            # any Executor compile now populates the directory
-            ex = xc.Executor(lambda a: jnp.tanh(a @ a) * 3,
-                             "test:persist")
-            ex(jnp.ones((64, 64)))
-            assert len(os.listdir(d)) > 0
-            # idempotent: second call is a cached read, same answer
-            assert xc.ensure_compile_cache() == d
-        finally:
-            jax.config.update("jax_compilation_cache_dir", None)
-            # drop the in-memory cache object too: a stale initialized
-            # cache with the config off makes later identical compiles
-            # return shared executables whose re-serialization is
-            # incomplete (AOT blobs that fail to load)
-            from jax._src import compilation_cache as _cc
-            _cc.reset_cache()
-            xc._reset_compile_cache_for_tests()
+    """The one rule of ``ensure_compile_cache``: JAX's own variable
+    wins and then no directory is set in code; otherwise the fixed
+    ``<checkout>/.jax_cache``."""
 
-    def test_off_by_default(self, monkeypatch):
-        monkeypatch.delenv("MXNET_COMPILE_CACHE_DIR", raising=False)
-        monkeypatch.delenv("MXTPU_COMPILE_CACHE_DIR", raising=False)
+    @pytest.fixture
+    def fresh_rule(self):
+        was = jax.config.jax_compilation_cache_dir
         xc._reset_compile_cache_for_tests()
-        assert xc.ensure_compile_cache() is None
+        yield
+        jax.config.update("jax_compilation_cache_dir", was)
+        # drop the in-memory cache object too: a stale initialized
+        # cache with the config off makes later identical compiles
+        # return shared executables whose re-serialization is
+        # incomplete (AOT blobs that fail to load)
+        from jax._src import compilation_cache as _cc
+        _cc.reset_cache()
+        xc._reset_compile_cache_for_tests()
+
+    def test_jax_variable_set_no_directory_set_in_code(
+            self, tmp_path, monkeypatch, fresh_rule):
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "j"))
+        # the package's old variable must not override JAX's
+        monkeypatch.setenv("MXNET_COMPILE_CACHE_DIR", str(tmp_path / "m"))
+        dirs_set = []
+        real_update = jax.config.update
+        monkeypatch.setattr(
+            jax.config, "update",
+            lambda k, v: (dirs_set.append(v)
+                          if k == "jax_compilation_cache_dir" else None,
+                          real_update(k, v))[1])
+        assert xc.ensure_compile_cache() == str(tmp_path / "j")
+        assert dirs_set == []
+        assert xc.ensure_compile_cache() == str(tmp_path / "j")
+
+    def test_unset_goes_to_fixed_checkout_path(self, tmp_path,
+                                               monkeypatch, fresh_rule):
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        monkeypatch.setenv("MXNET_COMPILE_CACHE_DIR", str(tmp_path / "m"))
+        want = os.path.join(REPO, ".jax_cache")
+        assert xc.ensure_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+        # idempotent: second call is a cached read, same answer
+        assert xc.ensure_compile_cache() == want
+
+    def test_executor_compile_lands_in_jax_variable_dir(self, tmp_path):
+        """End to end in a fresh process: with JAX's variable set, an
+        Executor compile populates THAT directory."""
+        d = tmp_path / "xla_cache"
+        env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(d),
+                   JAX_ENABLE_COMPILATION_CACHE="true",
+                   MXNET_COMPILE_CACHE_DIR=str(tmp_path / "m"),
+                   PYTHONPATH=REPO)
+        code = ("import jax.numpy as jnp\n"
+                "from incubator_mxnet_tpu import executor_cache as xc\n"
+                "ex = xc.Executor(lambda a: jnp.tanh(a @ a) * 3, 't:p')\n"
+                "ex(jnp.ones((64, 64))).block_until_ready()\n"
+                "print(xc.stats()['persistent_cache_dir'])\n")
+        proc = subprocess.run([sys.executable, "-c", code], env=env,
+                              capture_output=True, text=True, timeout=120)
+        assert proc.returncode == 0, proc.stderr[-800:]
+        assert proc.stdout.strip().splitlines()[-1] == str(d)
+        assert len(os.listdir(d)) > 0
+        assert not (tmp_path / "m").exists()
 
     def test_cold_start_provider_registered(self):
         xc.Executor(lambda a: a + 1, "test:provider")
@@ -170,6 +208,42 @@ class TestAOT:
         loaded = xc.deserialize_executable(blob)
         onp.testing.assert_array_equal(onp.asarray(loaded(a, b)),
                                        onp.asarray(jitted(a, b)))
+
+    @pytest.mark.parametrize("n_dev", [1, 4])
+    def test_roundtrip_loads_onto_the_compiled_devices(self, n_dev):
+        """A program compiled for 1 (or 4) of the harness's 8 devices
+        loads onto exactly those: without ``execution_devices=`` jax
+        loads it onto all 8 and the first call dies with "Expected args
+        to execute_sharded_on_local_devices to have 8 shards"."""
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+        assert len(jax.devices()) == 8
+        mesh = Mesh(onp.array(jax.devices()[:n_dev]), ("dp",))
+        w = jax.device_put(jnp.ones((16, 4)), NamedSharding(mesh, P()))
+        x = jax.device_put(jnp.ones((8, 16)),
+                           NamedSharding(mesh, P("dp")))
+        jitted = jax.jit(lambda w, x: jnp.tanh(x @ w))  # mxlint: disable=MX-DONATE001(test fixture: both buffers are reused for the parity call)
+        compiled = jitted.lower(w, x).compile()
+        loaded = xc.deserialize_executable(
+            xc.serialize_executable(compiled))
+        assert (loaded.runtime_executable().local_devices()
+                == list(jax.devices()[:n_dev]))
+        out = loaded(w, x)
+        assert len(out.sharding.device_set) == n_dev
+        onp.testing.assert_array_equal(onp.asarray(out),
+                                       onp.asarray(jitted(w, x)))
+
+    def test_devices_absent_here_is_typed(self):
+        compiled = jax.jit(lambda a: a + 1).lower(jnp.ones(3)).compile()  # mxlint: disable=MX-DONATE001(test fixture: one-shot compile for envelope surgery)
+        blob = xc.serialize_executable(compiled)
+        hlen = int.from_bytes(blob[8:16], "little")
+        header = json.loads(blob[16:16 + hlen].decode())
+        assert header["devices"] == [0]
+        header["devices"] = [0, 99]
+        new_header = json.dumps(header, sort_keys=True).encode()
+        tampered = (blob[:8] + len(new_header).to_bytes(8, "little")
+                    + new_header + blob[16 + hlen:])
+        with pytest.raises(xc.AOTCompatError, match=r"devices \[0, 99\]"):
+            xc.deserialize_executable(tampered)
 
     def test_version_mismatch_is_typed_and_named(self):
         compiled = jax.jit(lambda a: a + 1).lower(jnp.ones(3)).compile()  # mxlint: disable=MX-DONATE001(test fixture: one-shot compile for envelope surgery)

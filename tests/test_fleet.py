@@ -662,3 +662,84 @@ def test_process_fleet_kill_and_roll_end_to_end(artifact, predictor):
         assert [e["version"] for e in report["replicas"]] == [2]
     finally:
         router.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# process replicas run where the operator pointed the fleet (ISSUE 23)
+# ---------------------------------------------------------------------------
+
+class _NeverStarts:
+    """subprocess.Popen stand-in: records the launch, exits at once."""
+
+    launches = []
+
+    def __init__(self, cmd, env=None, **kw):
+        type(self).launches.append(dict(env))
+        self.stdout = iter(())
+        self.returncode = 1
+
+    def poll(self):
+        return self.returncode
+
+    def kill(self):
+        pass
+
+    wait = poll
+
+
+@pytest.fixture
+def launches(monkeypatch):
+    from incubator_mxnet_tpu.serving import fleet as fleet_mod
+    monkeypatch.setattr(fleet_mod.subprocess, "Popen", _NeverStarts)
+    _NeverStarts.launches = []
+    return _NeverStarts.launches
+
+
+@pytest.mark.parametrize("platforms", ["cpu", "tpu", None])
+def test_process_replica_inherits_jax_platforms(monkeypatch, launches,
+                                                platforms):
+    """A process replica gets this process's JAX_PLATFORMS — set or
+    unset — and never a default of its own ("cpu" used to be one: every
+    replica of the router CLI ran on the host unless the variable was
+    exported)."""
+    from incubator_mxnet_tpu.error import ReplicaUnavailableError
+    from incubator_mxnet_tpu.serving.fleet import ProcessReplica
+    if platforms is None:
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    else:
+        monkeypatch.setenv("JAX_PLATFORMS", platforms)
+    with pytest.raises(ReplicaUnavailableError):
+        ProcessReplica("r0", {"m": "/nowhere"}, startup_timeout_s=5).start()
+    (env,) = launches
+    assert env.get("JAX_PLATFORMS") == platforms
+    assert "TPU_VISIBLE_CHIPS" not in env
+
+
+def test_process_fleet_one_replica_per_tpu_chip(monkeypatch, launches):
+    """On a TPU host every process replica owns one chip: each is pinned
+    to its own, and one more than the host has is refused at spawn with
+    a sentence (several replicas share a chip through the thread
+    backend)."""
+    from incubator_mxnet_tpu.error import ReplicaUnavailableError
+    from incubator_mxnet_tpu.serving import fleet as fleet_mod
+    probes = []
+    monkeypatch.setattr(fleet_mod, "child_tpu_chips",
+                        lambda: probes.append(1) or 2)
+    fleet = ReplicaFleet({"m": "/nowhere"}, n=3, backend="process")
+    with pytest.raises(ValueError, match="one TPU chip per replica"):
+        fleet.spawn()
+    assert launches == []               # refused before anything started
+    fleet = ReplicaFleet({"m": "/nowhere"}, n=2, backend="process")
+    with pytest.raises(ReplicaUnavailableError):
+        fleet.spawn()                   # _NeverStarts: no replica comes up
+    assert sorted(e["TPU_VISIBLE_CHIPS"] for e in launches) == ["0", "1"]
+    assert all(e["TPU_PROCESS_BOUNDS"] == "1,1,1" for e in launches)
+    assert len(probes) == 2             # asked once per fleet, not per replica
+
+
+def test_child_tpu_chips_answers_cpu_without_a_child(monkeypatch):
+    from incubator_mxnet_tpu import context
+    monkeypatch.setattr(context.subprocess, "run", lambda *a, **k: pytest.fail(
+        "JAX_PLATFORMS=cpu must not cost a probe subprocess"))
+    assert context.child_tpu_chips({"JAX_PLATFORMS": "cpu"}) is None
+    assert context.child_tpu_chips({"JAX_PLATFORMS": "CPU,tpu"}) is None

@@ -5,7 +5,7 @@ checked through fwd outputs, stats, and full VJP — including the
 stats-cotangent path (ds1/ds2 feed the producing matmul via the BN
 constants of the *next* layer, exactly how the bottleneck chain uses
 it).  Kernels run in interpret mode on CPU (same numerics as Mosaic up
-to dot rounding); the on-chip proof lives in scripts/pallas_smoke.py.
+to dot rounding); the on-chip proof is chip_smoke.py's kernels phase.
 """
 import numpy as onp
 import jax

@@ -4,8 +4,8 @@ Oracle: the pure-XLA composition ``xla_conv3_bn`` (identical contract),
 checked through fwd outputs, stats, and full VJP — including the
 stats-cotangent path (ds1/ds2 feed the producing conv via the BN
 constants of the *next* layer, the bottleneck-chain dataflow).  Kernels
-run in interpret mode on CPU; the on-chip proof is
-scripts/pallas_smoke.py (kernel name: fused_conv3_bn).
+run in interpret mode on CPU; that they compile for the chip is
+tests/test_tpu_compile.py, the on-chip proof chip_smoke.py's kernels phase.
 """
 import numpy as onp
 import jax

@@ -209,7 +209,7 @@ class TestTileRule:
 
 class TestF64Rule:
     def test_f64_flags_under_x64(self):
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             def f(x):
                 return (x.astype(jnp.float64) * 2.0).sum()
 

@@ -204,3 +204,22 @@ def test_array_function_protocol():
     assert c.asnumpy().tolist() == [1, 2, 3, 4, 5, 6]
     s = onp.stack([a, b])
     assert isinstance(s, nd.NDArray) and s.shape == (2, 3)
+
+
+@pytest.mark.parametrize("kind", ["tpu", "gpu", "cpu"])
+def test_context_device_id_out_of_range_raises(kind):
+    """A device_id beyond the devices present is an error, never a wrap
+    onto device ``id % n`` (``mx.tpu(3)`` on a one-chip host used to land
+    on chip 0); in range, tpu()/gpu() resolve to the default backend."""
+    import jax
+    n = len(jax.devices())          # the harness's 8 virtual CPU devices
+    assert mx.Context(kind, n - 1).jax_device == jax.devices()[n - 1]
+    with pytest.raises(ValueError, match="out of range"):
+        mx.Context(kind, n + 1).jax_device
+    with pytest.raises(ValueError, match="out of range"):
+        nd.ones((2,), ctx=mx.Context(kind, 9))
+
+
+def test_num_tpus_counts_tpu_devices_only():
+    assert mx.context.num_tpus() == 0     # CPU harness: not "any non-CPU"
+    assert not mx.tpu_context_available()

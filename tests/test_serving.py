@@ -16,6 +16,7 @@ import urllib.request
 import numpy as onp
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 import incubator_mxnet_tpu as mx
@@ -478,7 +479,9 @@ def test_healthz_structured_state_json_shape(artifact):
                                               "queue_depth",
                                               "compile_count",
                                               "cold_start_ms",
-                                              "aot_buckets"}
+                                              "aot_buckets",
+                                              "aot_load_failures",
+                                              "device"}
         assert body["status"] == "ok"
         assert body["queue_depth"] == 0
         m = dict(body["models"]["mlp"])
@@ -487,7 +490,12 @@ def test_healthz_structured_state_json_shape(artifact):
         assert m == {
             "state": "ready", "version": 1, "queue_depth": 0,
             "compile_count": repo.compile_counts()["mlp"],
-            "aot_buckets": []}
+            "aot_buckets": [], "aot_load_failures": 0,
+            # where the model's parameters live, as JAX reports it
+            # (chip_smoke.py reads this to prove the server is on the
+            # chip)
+            "device": {"platform": "cpu",
+                       "kind": jax.devices()[0].device_kind}}
         # a model mid-build reports `loading` (not absent, not ready)
         with repo._loading_state("incoming"):
             assert repo.loading_names() == ["incoming"]
@@ -495,7 +503,8 @@ def test_healthz_structured_state_json_shape(artifact):
             assert b2["models"]["incoming"] == {
                 "state": "loading", "version": None,
                 "queue_depth": 0, "compile_count": None,
-                "cold_start_ms": None, "aot_buckets": []}
+                "cold_start_ms": None, "aot_buckets": [],
+                "aot_load_failures": 0, "device": None}
         _, b3 = health_body(repo, time.monotonic())
         assert "incoming" not in b3["models"]
         # draining flips status, the code, and every model's state

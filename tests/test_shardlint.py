@@ -216,8 +216,8 @@ def test_sl_shard_peak001_budget(mesh):
 # ---------------------------------------------------------------------------
 
 def _shard_mapped(body, mesh, in_specs, out_specs):
-    from incubator_mxnet_tpu.base import shard_map_compat
-    return shard_map_compat(body, mesh, in_specs, out_specs)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def test_collective_costs_psum_and_gather():

@@ -1,0 +1,201 @@
+"""The main path's Pallas kernels compile for the chip, at real widths.
+
+This sandbox has no TPU, but the TPU's compiler is installed and compiles
+for a chip that is *described* and not attached (the `on-chip-measurement`
+guide, section 2, third rehearsal).  Interpret mode cannot see what these
+tests see: a block shape Mosaic's tiling refuses (the LayerNorm/RMSNorm
+backward's (1, cols) partials), more scoped VMEM than a kernel may use (the
+fused conv at ResNet-50 stage 1, every row-wise kernel at its widest rows),
+a kernel GSPMD is asked to partition.  Each test asserts a
+``tpu_custom_call`` in the compiled program.  Nothing runs, so nothing here
+says a result is right or fast — ``chip_smoke.py``'s ``kernels`` phase does
+that on the chip.
+
+The topology is described inside a module-scoped fixture, never while a
+module is imported: only one process at a time may load the TPU's library,
+every xdist worker imports every test file, and only the worker that is
+given this file may load it.  All such tests stay in this one file.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as onp
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from incubator_mxnet_tpu import executor_cache as xc
+from incubator_mxnet_tpu.ops import fused_block, fused_conv
+from incubator_mxnet_tpu.ops import pallas_kernels as pk
+
+BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # whatever keeps the compiler from loading here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def for_the_chip(monkeypatch):
+    """Steer the kernels' own platform test: `interpret_mode()` and
+    `dispatch()` ask `jax.default_backend()`, which is the CPU here."""
+    monkeypatch.delenv("MXNET_USE_PALLAS", raising=False)
+    monkeypatch.setattr(pk.jax, "default_backend", lambda: "tpu")
+
+
+def _compile(fn, *specs):
+    # a described-topology compile cannot be read back from the persistent
+    # cache without a chip; keep it out of the way
+    with xc.compile_cache_bypassed():
+        return jax.jit(fn).lower(*specs).compile().as_text()
+
+
+def _fwd_bwd(fn, n_diff):
+    """fn's forward and backward as one program (unit cotangents)."""
+    def run(*a):
+        out, vjp = jax.vjp(lambda *d: fn(*d, *a[n_diff:]), *a[:n_diff])
+        return out, vjp(jax.tree_util.tree_map(jnp.ones_like, out))
+    return run
+
+
+def _spec(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+# (rows, cols, dtype): BERT-base activations, a 1024-wide f32 stack, and
+# the widest row the kernels take (_MAX_COLS) — each more than one row block
+ROWWISE = [(16384, 768, BF16), (8192, 1024, F32), (4096, 16384, F32)]
+
+
+@pytest.mark.parametrize("rows,cols,dtype", ROWWISE)
+def test_layer_norm_fwd_bwd(one_chip, for_the_chip, rows, cols, dtype):
+    s = functools.partial(_spec, one_chip)
+    text = _compile(_fwd_bwd(pk.fused_layer_norm, 3),
+                    s((rows, cols), dtype), s((cols,), F32), s((cols,), F32))
+    assert text.count("tpu_custom_call") == 2
+
+
+@pytest.mark.parametrize("rows,cols,dtype", ROWWISE)
+def test_rms_norm_fwd_bwd(one_chip, for_the_chip, rows, cols, dtype):
+    s = functools.partial(_spec, one_chip)
+    text = _compile(_fwd_bwd(pk.fused_rms_norm, 2),
+                    s((rows, cols), dtype), s((cols,), F32))
+    assert text.count("tpu_custom_call") == 2
+
+
+@pytest.mark.parametrize("shape,dtype", [
+    ((8, 12, 512, 512), BF16),      # BERT-base attention probabilities
+    ((4096, 16384), F32)])
+def test_softmax_fwd_bwd(one_chip, for_the_chip, shape, dtype):
+    text = _compile(_fwd_bwd(pk.fused_softmax, 1),
+                    _spec(one_chip, shape, dtype))
+    assert text.count("tpu_custom_call") == 2
+
+
+@pytest.mark.parametrize("rows,cols,dtype", [
+    (256, 1000, F32),               # ResNet-50 logits at batch 256
+    (4096, 16384, BF16)])
+def test_softmax_xent_fwd_bwd(one_chip, for_the_chip, rows, cols, dtype):
+    s = functools.partial(_spec, one_chip)
+    text = _compile(_fwd_bwd(pk.fused_softmax_xent, 1),
+                    s((rows, cols), dtype), s((rows,), I32))
+    assert text.count("tpu_custom_call") == 2
+
+
+def test_flash_attention_fwd(one_chip, for_the_chip):
+    q = _spec(one_chip, (8, 16, 2048, 64), BF16)
+    text = _compile(lambda q, k, v: pk.flash_attention(q, k, v, causal=True),
+                    q, q, q)
+    assert text.count("tpu_custom_call") == 1
+
+
+# ResNet-50's four stages at batch 256: (pixels a side, mid width, out width)
+STAGES = [(56, 64, 256), (28, 128, 512), (14, 256, 1024), (7, 512, 2048)]
+
+
+@pytest.mark.parametrize("hw,cm,co", [STAGES[0], STAGES[3]])
+def test_fused_matmul_bn_fwd_bwd(one_chip, for_the_chip, hw, cm, co):
+    s = functools.partial(_spec, one_chip)
+    m = 256 * hw * hw
+    text = _compile(_fwd_bwd(fused_block.fused_matmul_bn, 4),
+                    s((m, cm), BF16), s((cm, co), BF16),
+                    s((cm,), F32), s((cm,), F32))
+    assert text.count("tpu_custom_call") == 3       # fwd, dx, dw
+
+
+@pytest.mark.parametrize("hw,cm,co", STAGES)
+def test_fused_conv3_bn_fwd_bwd(one_chip, for_the_chip, hw, cm, co):
+    x = jax.ShapeDtypeStruct((256, hw, hw, cm), BF16)
+    plan = fused_conv._Geom(x, cm)
+    assert plan.fits() and plan.n_blocks == 1
+    s = functools.partial(_spec, one_chip)
+    text = _compile(_fwd_bwd(fused_conv.fused_conv3_bn, 4),
+                    s(x.shape, BF16), s((3, 3, cm, cm), BF16),
+                    s((cm,), F32), s((cm,), F32))
+    assert text.count("tpu_custom_call") == 3       # fwd, dx, dw
+
+
+def test_fused_conv3_bn_several_output_blocks(one_chip, for_the_chip,
+                                              monkeypatch):
+    """The multi-N-block plan (outputs too wide for one block) at stage-4
+    width: a budget below the one-block estimate forces two blocks."""
+    monkeypatch.setattr(fused_conv, "_VMEM_BUDGET", 40 * 2 ** 20)
+    x = jax.ShapeDtypeStruct((256, 7, 7, 512), BF16)
+    assert fused_conv._Geom(x, 512).n_blocks == 2
+    s = functools.partial(_spec, one_chip)
+    text = _compile(_fwd_bwd(fused_conv.fused_conv3_bn, 4),
+                    s(x.shape, BF16), s((3, 3, 512, 512), BF16),
+                    s((512,), F32), s((512,), F32))
+    assert text.count("tpu_custom_call") == 3
+
+
+def test_fused_conv3_bn_image_block_beyond_vmem_rides_xla(one_chip,
+                                                          for_the_chip):
+    """A 112x112x64 image block needs ~82 MiB of scoped VMEM (bisected):
+    the plan must say it does not fit, and the op says so and runs the XLA
+    composition instead of handing Mosaic a kernel it refuses."""
+    x = jax.ShapeDtypeStruct((8, 112, 112, 64), BF16)
+    assert not fused_conv._Geom(x, 64).fits()
+    s = functools.partial(_spec, one_chip)
+    with pytest.warns(UserWarning, match="runs the XLA composition"):
+        text = _compile(fused_conv.fused_conv3_bn,
+                        s(x.shape, BF16), s((3, 3, 64, 64), BF16),
+                        s((64,), F32), s((64,), F32))
+    assert "tpu_custom_call" not in text
+
+
+def test_mosaic_kernel_under_a_mesh_needs_gspmd_trace(topo, for_the_chip):
+    """Why `fuse.FusedTrainStep` traces its step under `gspmd_trace` when it
+    is given a mesh: GSPMD cannot partition a Mosaic kernel, so a dp program
+    that reaches one does not compile; inside `gspmd_trace` the op routes
+    to its XLA composition and the program partitions."""
+    from incubator_mxnet_tpu.ops import nn_ops
+    mesh = Mesh(onp.array(topo.devices).reshape(4), ("dp",))
+    logits = _spec(NamedSharding(mesh, P("dp")), (256, 1000), F32)
+    labels = _spec(NamedSharding(mesh, P("dp")), (256,), I32)
+
+    def mean_loss(x, y):
+        return jnp.mean(nn_ops.softmax_xent.fn(x, y))
+
+    with pytest.raises(NotImplementedError, match="automatically partitioned"):
+        _compile(jax.grad(mean_loss), logits, labels)
+
+    def mean_loss_over_mesh(x, y):
+        with pk.gspmd_trace():
+            return mean_loss(x, y)
+
+    text = _compile(jax.value_and_grad(mean_loss_over_mesh), logits, labels)
+    assert "tpu_custom_call" not in text
+    assert "all-reduce" in text         # the mean over the sharded batch

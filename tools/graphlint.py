@@ -83,7 +83,7 @@ def selftest():
         else:
             print(f"[selftest] {tag}: {sorted(rules)} OK")
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         expect("f64-upcast", ["GL-DTYPE001"],
                lambda x: (x.astype(jnp.float64) * 2.0).sum(),
                jnp.ones((4,), jnp.float32))

@@ -28,6 +28,7 @@ import signal
 import socket
 import subprocess
 import sys
+import time
 
 
 def _free_port():
@@ -38,17 +39,57 @@ def _free_port():
     return port
 
 
+def _await_servers(addrs, timeout=120.0):
+    """Block until every parameter server accepts connections.  A worker
+    connects the moment it starts, and a server that is still importing
+    refuses it (the workers' own retry budget is under a second)."""
+    deadline = time.monotonic() + timeout
+    for addr in addrs:
+        host, _, port = addr.rpartition(":")
+        while True:
+            try:
+                socket.create_connection((host, int(port)), 1.0).close()
+                break
+            except OSError:
+                if time.monotonic() > deadline:
+                    raise SystemExit(
+                        f"launch.py: parameter server {addr} did not "
+                        f"listen within {timeout:.0f}s")
+                time.sleep(0.1)
+
+
 def _server_code(port, kv_mode, num_workers):
     """Bootstrap string for one PS server process.  Servers are CPU
-    processes (reference: server role never owns a GPU); the cpu
-    backend is forced BEFORE anything imports jax — the server-side
-    optimizer path uses jnp and must not touch the accelerator plugin."""
+    processes by role (reference: the server role never owns a GPU);
+    the cpu backend is forced BEFORE anything imports jax — the
+    server-side optimizer path uses jnp and must not take a chip from
+    the workers."""
     repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     return (f"import sys; sys.path.insert(0, {repo_root!r}); "
             f"import jax; jax.config.update('jax_platforms', 'cpu'); "
             f"from incubator_mxnet_tpu.kvstore.ps_server import "
             f"serve_forever; "
             f"serve_forever({port}, {kv_mode!r}, {num_workers})")
+
+
+def _refuse_workers_sharing_tpu_host(env, num_workers):
+    """Several local workers on a TPU host would each claim every chip
+    (this launcher gives a worker no chip of its own): the second one
+    fails or hangs at its first JAX call.  Say so instead."""
+    if num_workers < 2:
+        return
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from incubator_mxnet_tpu.context import child_tpu_chips
+    chips = child_tpu_chips(env)
+    if chips is not None:
+        raise SystemExit(
+            f"launch.py --launcher local: {num_workers} workers on one "
+            f"TPU host ({chips} chip(s)) would each claim every chip, and "
+            "a chip belongs to one process at a time.  Run one worker per "
+            "host (--launcher ssh), drive all local chips from one process "
+            "(make_fused_train_step(..., mesh=...)), or set "
+            "JAX_PLATFORMS=cpu for a CPU rehearsal.")
 
 
 def launch_local(args, extra_env=None):
@@ -70,10 +111,15 @@ def launch_local(args, extra_env=None):
 
     coordinator = f"127.0.0.1:{_free_port()}"
     server_addrs = [f"127.0.0.1:{p}" for p in server_ports]
-    workers = []
+    worker_envs = []
     for i in range(args.num_workers):
         env = dict(env_base)
         env.update(_worker_env(args, i, coordinator, server_addrs))
+        worker_envs.append(env)
+    _refuse_workers_sharing_tpu_host(worker_envs[0], args.num_workers)
+    _await_servers(server_addrs)
+    workers = []
+    for env in worker_envs:
         p = subprocess.Popen(args.command, env=env)
         workers.append(p)
         procs.append(("worker", p))
@@ -180,6 +226,7 @@ def launch_ssh(args, extra_env=None):
         remote = f"cd {_sh_quote(workdir)} && {{ {server_sh}; }}"
         procs.append(("server", subprocess.Popen(
             ssh_cmd + [head, remote], stdin=subprocess.PIPE)))
+    _await_servers(server_addrs)
 
     workers = []
     for i, host in enumerate(assignment):
@@ -265,6 +312,7 @@ def launch_sge(args, extra_env=None):
         env["JAX_PLATFORMS"] = "cpu"
         code = _server_code(sport, args.kv_mode, args.num_workers)
         procs.append(subprocess.Popen([sys.executable, "-c", code], env=env))
+    _await_servers(server_addrs)
 
     # The jax.distributed coordinator is HOSTED BY WORKER 0 on whatever
     # exec node SGE places task 1 — unknowable at submit time.  Task 1
@@ -385,6 +433,7 @@ def launch_yarn(args, extra_env=None):
         env["JAX_PLATFORMS"] = "cpu"
         code = _server_code(sport, args.kv_mode, args.num_workers)
         procs.append(subprocess.Popen([sys.executable, "-c", code], env=env))
+    _await_servers(server_addrs)
 
     srv, rport = _rendezvous_server()
     env = _worker_env(args, 0, coordinator="__YARN__",
