@@ -47,11 +47,20 @@ minutes to seconds) stacks two layers on this choke point:
   different toolchain instead of crashing inside an unpickler.
 
 Observability: a ``cold_start`` profiler stats provider reports time
-from process start to first executable build, per-site build counts,
-the persistent-cache configuration, and AOT load hits/failures.
+from this module's import to first executable build, per-site build
+counts, the persistent-cache configuration, AOT load hits/failures,
+and -- under ``"jit"`` -- what JAX itself reports of every compile
+(``jax.monitoring``): seconds tracing, lowering, in backend compile
+and retrieving from the persistent cache, and counts of compiles and
+of cache hits and misses, per jitted function (the calling
+:class:`Executor`'s site where one is calling, else the function's
+name).  The listeners run only when something compiles.
+``Executor.__call__`` times the call as the ``executor.call`` span of
+:mod:`.trace`.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import json
 import os
@@ -59,8 +68,10 @@ import threading
 import time
 
 import jax
+from jax import monitoring as _monitoring
 from jax.experimental.compilation_cache import compilation_cache as _cc
 
+from . import trace as _trace
 from .base import get_env
 from .locks import named_lock
 
@@ -68,10 +79,10 @@ __all__ = ["Executor", "TraceCache", "run_analyses", "lint_active",
            "memlint_active", "ensure_compile_cache",
            "compile_cache_bypassed",
            "serialize_executable", "deserialize_executable", "aot_compat",
-           "AOTCompatError", "record_aot_load", "process_uptime_ms",
-           "stats", "reset_stats"]
+           "AOTCompatError", "record_aot_load", "since_import_ms",
+           "compile_log", "stats", "reset_stats"]
 
-_PROCESS_T0 = time.monotonic()
+_IMPORT_T0 = time.monotonic()   # this module's import, not process start
 
 _lock = named_lock("executor.state")
 _state = {
@@ -84,6 +95,21 @@ _state = {
 }
 _sites: dict[str, dict] = {}       # site -> {"executors": n, "built_ms": t}
 _provider_registered = False
+
+# what jax.monitoring reports of each compile, folded per jitted function
+_JIT_FIELDS = ("compiles", "trace_s", "lower_s", "backend_compile_s",
+               "cache_retrieval_s", "cache_hits", "cache_misses")
+_jit: dict[str, dict] = {}         # site or function name -> the fields
+_jit_log = collections.deque(maxlen=4096)   # one record per compile
+
+
+class _Compiling(threading.local):
+    """What this thread is compiling: the calling Executor's site, the
+    last traced function ``(name, seconds)``, the open compile record."""
+    site = trace = record = None
+
+
+_compiling = _Compiling()
 
 
 class AOTCompatError(RuntimeError):
@@ -137,6 +163,13 @@ def ensure_compile_cache():
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           get_env("MXNET_COMPILE_CACHE_MIN_COMPILE_SECS",
                                   0.0, float))
+        # the key holds the instructions' metadata too: by default jax
+        # strips it, and a program that differs only in its
+        # ``jax.named_scope`` names would be served an executable whose
+        # instructions carry the older names -- a profile of it would
+        # attribute device time to scopes that are gone
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          True)
         d = os.environ.get("JAX_COMPILATION_CACHE_DIR")
         if not d:
             d = _DEFAULT_CACHE_DIR
@@ -185,6 +218,64 @@ def _ensure_provider():
     _provider_registered = True
     from . import profiler
     profiler.register_stats_provider("cold_start", stats)
+    _monitoring.register_event_duration_secs_listener(_on_jit_duration)
+    _monitoring.register_event_listener(_on_jit_event)
+
+
+def _compile_record(module_name, **fields):
+    """A compile's record, every counter 0 but ``fields``, for the module
+    ``jit(step)`` (what jax names it) under its function's name ``step``."""
+    fun = module_name.partition("(")[2].rstrip(")") or module_name
+    return dict(dict.fromkeys(_JIT_FIELDS, 0), fun=fun, **fields)
+
+
+def _on_jit_duration(event, seconds, fun_name=None, **_):
+    """``jax.monitoring`` duration listener.  A compile reports, on the
+    thread that makes it and in this order: the tracing of every jitted
+    function nested in the program and then of the program itself (the
+    last before lowering), its lowering, the persistent cache's events,
+    and the backend compile that encloses them.  One record a compile is
+    opened at the lowering and folded at the backend compile; nested
+    traces are inside the program's own and are not added to it."""
+    if event == "/jax/core/compile/jaxpr_trace_duration":
+        _compiling.trace = (fun_name, seconds)
+    elif event == "/jax/core/compile/jaxpr_to_mlir_module_duration":
+        traced, trace_s = _compiling.trace or (None, 0.0)
+        _compiling.trace = None
+        record = _compiling.record = _compile_record(fun_name,
+                                                     lower_s=seconds)
+        if traced == record["fun"]:
+            record["trace_s"] = trace_s
+    elif event == "/jax/compilation_cache/cache_retrieval_time_sec":
+        record = _compiling.record
+        if record is not None:
+            record["cache_retrieval_s"] += seconds
+    elif event == "/jax/core/compile/backend_compile_duration":
+        fresh = _compile_record(fun_name)
+        record = _compiling.record
+        if record is None or record["fun"] != fresh["fun"]:
+            record = fresh                           # lowered elsewhere
+        _compiling.record = None
+        record.update(compiles=1, backend_compile_s=seconds,
+                      at=time.perf_counter(),
+                      site=_compiling.site)
+        with _lock:
+            into = _jit.setdefault(record["site"] or record["fun"],
+                                   dict.fromkeys(_JIT_FIELDS, 0))
+            for field in _JIT_FIELDS:
+                into[field] += record[field]
+            _jit_log.append(record)
+
+
+def _on_jit_event(event, **_):
+    """``jax.monitoring`` event listener: the persistent cache's hit or
+    miss of the compile this thread is making."""
+    record = _compiling.record
+    if record is not None:
+        if event == "/jax/compilation_cache/cache_hits":
+            record["cache_hits"] += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            record["cache_misses"] += 1
 
 
 class Executor:
@@ -229,7 +320,6 @@ class Executor:
         # span is timing the call; this event names the site) AND on
         # the always-on flight ring, where a postmortem can see a
         # compile burst precede an incident even with tracing off
-        from . import trace as _trace
         _trace.add_event("executor.created", site=site)
         from . import flightrec as _flightrec
         _flightrec.record(_flightrec.COMPILE, "executor.created",
@@ -238,14 +328,22 @@ class Executor:
         with _lock:
             if _state["first_build_ms"] is None:
                 _state["first_build_ms"] = round(
-                    (self._built_at - _PROCESS_T0) * 1000.0, 3)
+                    (self._built_at - _IMPORT_T0) * 1000.0, 3)
             st = _sites.setdefault(site, {"executors": 0})
             st["executors"] += 1
-            st["built_ms_after_start"] = round(
-                (self._built_at - _PROCESS_T0) * 1000.0, 3)
+            st["built_ms_after_import"] = round(
+                (self._built_at - _IMPORT_T0) * 1000.0, 3)
 
     def __call__(self, *args, **kwargs):
-        return self.jfn(*args, **kwargs)
+        """The jitted call, timed as the ``executor.call`` span; what it
+        compiles is counted under this executor's site."""
+        was = _compiling.site
+        _compiling.site = self.site
+        try:
+            with _trace.span("executor.call", site=self.site):
+                return self.jfn(*args, **kwargs)
+        finally:
+            _compiling.site = was
 
     def lower(self, *args, **kwargs):
         return self.jfn.lower(*args, **kwargs)
@@ -254,11 +352,9 @@ class Executor:
     def compile_count(self):
         """Distinct executables this entry point compiled (jit cache
         probe; AOT-loaded executables never appear here — that is the
-        point)."""
-        try:
-            return int(self.jfn._cache_size())
-        except Exception:  # mxlint: allow-broad-except(best-effort probe of a private jax internal; a degraded count beats failing a metrics scrape)
-            return 0
+        point).  A probe that cannot be read raises: 0 would say
+        "compiled nothing"."""
+        return int(self.jfn._cache_size())
 
     def analyze(self, args, graphlint=None, memlint=None,
                 shardlint=None):
@@ -398,7 +494,6 @@ class TraceCache:
         as an ``executor.build`` span — the difference between "paid a
         compile" and "replayed an executable" for exactly the request
         that paid it (docs/observability.md)."""
-        from . import trace as _trace
         with self._lock:
             entry = self._d.get(key)
             if entry is not None:
@@ -554,8 +649,20 @@ def record_aot_load(ok=True):
 # reporting
 # ---------------------------------------------------------------------------
 
-def process_uptime_ms():
-    return round((time.monotonic() - _PROCESS_T0) * 1000.0, 3)
+def since_import_ms():
+    """Milliseconds since this module was imported (with the package, so
+    a little after process start)."""
+    return round((time.monotonic() - _IMPORT_T0) * 1000.0, 3)
+
+
+def compile_log():
+    """One record per compile since the counters were registered (the
+    newest 4096): ``fun``, ``site`` (the calling Executor's, or None),
+    ``at`` (``time.perf_counter()`` when the backend compile ended) and
+    the fields of ``stats()["jit"]`` -- for a reader that has to cut the
+    sums at a point in time."""
+    with _lock:
+        return [dict(r) for r in _jit_log]
 
 
 def stats():
@@ -565,9 +672,12 @@ def stats():
         # but keep the detail table to the structural surfaces
         per_site = {k: dict(v) for k, v in _sites.items()
                     if not k.startswith("op:")}
+        jit = {field: sum(f[field] for f in _jit.values())
+               for field in _JIT_FIELDS}
+        jit["per_function"] = {k: dict(v) for k, v in _jit.items()}
         out = {
-            "process_uptime_ms": process_uptime_ms(),
-            "first_executor_build_ms": _state["first_build_ms"],
+            "since_import_ms": since_import_ms(),
+            "first_executor_build_ms_after_import": _state["first_build_ms"],
             "persistent_cache_dir": _state["cache_dir"],
             "aot_loads": _state["aot_loads"],
             "aot_load_failures": _state["aot_load_failures"],
@@ -575,6 +685,7 @@ def stats():
             "sites": len(_sites),
             "op_sites": sum(1 for k in _sites if k.startswith("op:")),
             "per_site": per_site,
+            "jit": jit,
         }
     return out
 
@@ -585,6 +696,8 @@ def reset_stats():
     supported operation (use _reset_compile_cache_for_tests)."""
     with _lock:
         _sites.clear()
+        _jit.clear()
+        _jit_log.clear()
         _state["first_build_ms"] = None
         _state["aot_loads"] = 0
         _state["aot_load_failures"] = 0

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from . import executor_cache as _xc
+from . import trace
 from .base import resolve_chunk_steps
 from .ndarray import NDArray
 from .ops.pallas_kernels import gspmd_trace
@@ -174,13 +175,21 @@ class FusedTrainStep:
         lr, momentum, wd = self.lr, self.momentum, self.wd
         optimizer = self.optimizer
 
+        # the phases carry names into every instruction's op_name
+        # (docs/observability.md): under value_and_grad the forward
+        # pass reads jvp(forward)/..., the backward pass
+        # transpose(jvp(forward))/... with no scope of its own, the
+        # update optimizer/...; child blocks add their registration
+        # keys below the phase (gluon/block.py)
         def loss_of(params, aux, x, y, key):
-            out, updates = apply({**params, **aux}, x, training=True,
-                                 key=key, with_updates=True)
-            if isinstance(out, tuple):
-                out = out[0]
-            loss = loss_block(NDArray(out), NDArray(y))
-            return jnp.mean(loss.data), updates
+            with jax.named_scope("forward"):
+                out, updates = apply({**params, **aux}, x, training=True,
+                                     key=key, with_updates=True)
+                if isinstance(out, tuple):
+                    out = out[0]
+                with jax.named_scope("loss"):
+                    loss = loss_block(NDArray(out), NDArray(y))
+                return jnp.mean(loss.data), updates
 
         if self._remat:
             # rematerialization (SURVEY §"HBM bandwidth"): trade recompute
@@ -201,19 +210,21 @@ class FusedTrainStep:
             with gspmd_trace(mesh is not None):
                 (loss, updates), grads = jax.value_and_grad(
                     loss_of, has_aux=True)(params, aux, x, y, key)
-            if optimizer == "sgd":
-                new_params, new_state = _sgd_update(grads, opt_state, params,
-                                                    lr, momentum, wd)
-            elif optimizer == "nag":
-                new_params, new_state = _nag_update(grads, opt_state, params,
-                                                    lr, momentum, wd)
-            elif optimizer == "adamw":
-                new_params, new_state = _adamw_update(
-                    grads, opt_state, params, lr, 0.9, 0.999, 1e-8, wd)
-            else:
-                new_params, new_state = _adam_update(
-                    grads, opt_state, params, lr, 0.9, 0.999, 1e-8, wd)
-            new_aux = {**aux, **{k: v for k, v in updates.items() if k in aux}}
+            with jax.named_scope("optimizer"):
+                if optimizer == "sgd":
+                    new_params, new_state = _sgd_update(
+                        grads, opt_state, params, lr, momentum, wd)
+                elif optimizer == "nag":
+                    new_params, new_state = _nag_update(
+                        grads, opt_state, params, lr, momentum, wd)
+                elif optimizer == "adamw":
+                    new_params, new_state = _adamw_update(
+                        grads, opt_state, params, lr, 0.9, 0.999, 1e-8, wd)
+                else:
+                    new_params, new_state = _adam_update(
+                        grads, opt_state, params, lr, 0.9, 0.999, 1e-8, wd)
+                new_aux = {**aux, **{k: v for k, v in updates.items()
+                                     if k in aux}}
             return new_params, new_aux, new_state, loss
 
         donate_argnums = (0, 1, 2) if donate else ()
@@ -232,12 +243,30 @@ class FusedTrainStep:
         self._executor = _xc.Executor(
             step, f"fused_step:{type(self.block).__name__}",
             donate_argnums=donate_argnums, in_shardings=in_shardings)
-        return self._executor.jfn
+        # called through the Executor, not its bare jfn: the one choke
+        # point times every jitted entry point (``executor.call``)
+        return self._executor
 
     def __call__(self, x, y):
-        xv = x.data if isinstance(x, NDArray) else x
-        yv = y.data if isinstance(y, NDArray) else y
-        self._key, sub = jax.random.split(self._key)
+        # the host's side of a step, span by span (trace.py; written
+        # into the profiler's trace when a session is on): the key
+        # split is a jitted program of its own, the analyses' latches
+        # are checked while one is open, the rest is the jitted call
+        with trace.span("fused_step.call"):
+            xv = x.data if isinstance(x, NDArray) else x
+            yv = y.data if isinstance(y, NDArray) else y
+            with trace.span("fused_step.key_split"):
+                self._key, sub = jax.random.split(self._key)
+            if not (self._lint_done and self._memlint_done
+                    and self._shardlint_done):
+                with trace.span("fused_step.analyses"):
+                    self._analyze(xv, yv, sub)
+            self.params, self.aux, self.opt_state, loss = self._step_fn(
+                self.params, self.aux, self.opt_state, xv, yv, sub)
+        self._last = loss
+        return loss
+
+    def _analyze(self, xv, yv, sub):
         if not (self._lint_done and self._memlint_done):
             # build-time analyses of the whole train step through the
             # unified choke point (MXNET_GRAPH_LINT/MXNET_GRAPH_MEMLINT).
@@ -266,10 +295,6 @@ class FusedTrainStep:
                     in_specs=(None, None, None, bspec, bspec, None),
                     allow_replicated=(0, 1, 2, 5)))
             self._shardlint_done = True
-        self.params, self.aux, self.opt_state, loss = self._step_fn(
-            self.params, self.aux, self.opt_state, xv, yv, sub)
-        self._last = loss
-        return loss
 
     @property
     def step_fn(self):

@@ -166,8 +166,8 @@ class ChunkedTrainLoop:
         with trace.span("train.chunk", steps=self.chunk_steps,
                         chunk=self.chunks_run):
             s.params, s.aux, s.opt_state, s._key, loss = \
-                self._executor.jfn(s.params, s.aux, s.opt_state,
-                                   s._key, xs, ys)
+                self._executor(s.params, s.aux, s.opt_state,
+                               s._key, xs, ys)
         s._last = loss
         self.chunks_run += 1
         return loss

@@ -23,6 +23,7 @@ into one XLA executable via ``jax.jit``:
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 
 import jax
@@ -79,6 +80,7 @@ class Block:
     def __setattr__(self, name, value):
         if isinstance(value, Block):
             self.__dict__.setdefault("_children", {})[name] = value
+            value.__dict__["_scope_key"] = name
         elif isinstance(value, Parameter):
             shared = self.__dict__.get("_shared_params")
             if shared is not None:
@@ -103,8 +105,21 @@ class Block:
         super().__setattr__(name, value)
 
     def register_child(self, block, name=None):
-        self._children[name or str(len(self._children))] = block
+        name = name or str(len(self._children))
+        self._children[name] = block
+        block.__dict__["_scope_key"] = name
         return block
+
+    def _scope(self):
+        """The ``jax.named_scope`` this block is traced under: the key it
+        is registered under in its parent (``features``, ``0``, ``conv1``
+        ...), so an instruction's ``op_name`` reads
+        ``forward/features/4/0/body/conv1/...``.  Not ``self.name``: the
+        prefix counter differs between two nets built in one process.  A
+        root block has no key and adds no segment."""
+        key = self.__dict__.get("_scope_key")
+        return contextlib.nullcontext() if key is None \
+            else jax.named_scope(key)
 
     @property
     def prefix(self):
@@ -221,12 +236,13 @@ class Block:
         for hook in self._forward_pre_hooks:
             hook(self, args)
         policy = getattr(self, "_amp_policy", None)
-        if policy is not None:
-            from ..amp import amp as _amp
-            with _amp.policy_scope(policy):
+        with self._scope():
+            if policy is not None:
+                from ..amp import amp as _amp
+                with _amp.policy_scope(policy):
+                    out = self.forward(*args, **kwargs)
+            else:
                 out = self.forward(*args, **kwargs)
-        else:
-            out = self.forward(*args, **kwargs)
         for hook in self._forward_hooks:
             hook(self, args, out)
         return out
@@ -502,8 +518,7 @@ class HybridBlock(Block):
                 from ..amp import amp as _amp
                 pol_ctx = _amp.policy_scope(policy)
             else:
-                import contextlib as _cl
-                pol_ctx = _cl.nullcontext()
+                pol_ctx = contextlib.nullcontext()
             with _TraceParams(mapping), _random.key_scope(key), \
                     autograd._scope(None, training), \
                     _CollectStateUpdates() as su, pol_ctx:

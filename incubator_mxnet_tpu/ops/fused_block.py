@@ -143,6 +143,7 @@ def _fwd_impl(x, w, scale, bias, prologue, bm=None, bn=None):
                          memory_space=pltpu.VMEM),
         ],
         interpret=interpret_mode(),
+        name="matmul_bn_fwd",
     )(xp, wp, scp, bip)
     return y[:m, :n], s1[0, :n], s2[0, :n]
 
@@ -249,6 +250,7 @@ def _bwd_impl(x, w, scale, bias, y, dy, ds1, ds2, prologue):
                          memory_space=pltpu.VMEM),
         ],
         interpret=interpret_mode(),
+        name="matmul_bn_bwd_dx",
     )(dyp, yp, ds1p, ds2p, wp, xp, scp, bip)
 
     # --- dw --- (same M tiling as dx: the padded dy/y/x are reused)
@@ -280,6 +282,7 @@ def _bwd_impl(x, w, scale, bias, y, dy, ds1, ds2, prologue):
         out_specs=pl.BlockSpec((bk2, bn2), lambda kj, nj, i: (kj, nj),
                                memory_space=pltpu.VMEM),
         interpret=interpret_mode(),
+        name="matmul_bn_bwd_dw",
     )(xp, dyp, yp, ds1p, ds2p, scp, bip)
 
     dx = dx[:m, :k]
@@ -341,16 +344,18 @@ def fused_matmul_bn(x, w, scale=None, bias=None):
     Returns ``(y, s1, s2)`` with ``s1 = sum_M(y)``, ``s2 = sum_M(y^2)``
     in fp32: ``mean = s1/M``, ``var = s2/M - mean^2`` (one-pass BN).
     """
-    if scale is None:
-        # same contract as every other kernel gate (e.g. layer_norm):
-        # see pallas_kernels.dispatch; tests that want interpret-mode
-        # Pallas off-TPU force MXNET_USE_PALLAS=1
-        ones = jnp.ones((x.shape[1],), jnp.float32)
-        return dispatch(
-            lambda x, w: _fmm(x, w, ones, jnp.zeros_like(ones), False),
-            xla_matmul_bn, x, w)
-    return dispatch(lambda x, w, s, b: _fmm(x, w, s, b, True),
-                    xla_matmul_bn, x, w, scale, bias)
+    # same contract as every other kernel gate (e.g. layer_norm): see
+    # pallas_kernels.dispatch, which also names the trace scope after
+    # this wrapper; tests that want interpret-mode Pallas off-TPU force
+    # MXNET_USE_PALLAS=1
+    def matmul_bn(x, w, scale=None, bias=None):
+        if scale is None:
+            ones = jnp.ones((x.shape[1],), jnp.float32)
+            return _fmm(x, w, ones, jnp.zeros_like(ones), False)
+        return _fmm(x, w, scale, bias, True)
+
+    args = (x, w) if scale is None else (x, w, scale, bias)
+    return dispatch(matmul_bn, xla_matmul_bn, *args)
 
 
 def _bottleneck_core(x, w1, g1, b1, w2, g2, b2, w3, g3, b3,

@@ -371,6 +371,7 @@ def _fwd_impl(x4, w, scale, bias, prologue):
         ],
         interpret=interpret_mode(),
         compiler_params=_COMPILER_PARAMS,
+        name="conv3_bn_fwd",
     )(g.pad_x(x4), g.pad_w(w), g.pad_vec(scale, g.kp),
       g.pad_vec(bias, g.kp))
     y = y[:g.m, :g.cout].reshape(g.n, g.h, g.w, g.cout)
@@ -411,6 +412,7 @@ def _bwd_impl(x4, w, scale, bias, y4, dy4, ds1, ds2, prologue):
             out_specs=[row_spec(g.kp), vec_spec(g.kp), vec_spec(g.kp)],
             interpret=interpret_mode(),
             compiler_params=_COMPILER_PARAMS,
+            name="conv3_bn_bwd_dx",
         )(dyp, yp, ds1p, ds2p, wp, xp, scp, bip)
     else:
         # wide outputs: accumulate fp32 dx partials across N blocks,
@@ -439,6 +441,7 @@ def _bwd_impl(x4, w, scale, bias, y4, dy4, ds1, ds2, prologue):
             out_specs=[mrow(g.kp), cvec(g.kp), cvec(g.kp)],
             interpret=interpret_mode(),
             compiler_params=_COMPILER_PARAMS,
+            name="conv3_bn_bwd_dx_blocked",
         )(dyp, yp, ds1p, ds2p, wp, xp, scp, bip)
 
     dw_spec = lambda cols, im: pl.BlockSpec(  # noqa: E731
@@ -463,6 +466,7 @@ def _bwd_impl(x4, w, scale, bias, y4, dy4, ds1, ds2, prologue):
                                memory_space=pltpu.VMEM),
         interpret=interpret_mode(),
         compiler_params=_COMPILER_PARAMS,
+        name="conv3_bn_bwd_dw",
     )(xp, dyp, yp, ds1p, ds2p, scp, bip)
 
     dx = dx[:g.m, :g.c].astype(x4.dtype).reshape(x4.shape)
@@ -544,7 +548,7 @@ def fused_conv3_bn(x, w, scale=None, bias=None):
             int(v) for v in widths.split(",") if v}:
         return xla_conv3_bn(*args)
 
-    def kernel(x, w, scale=None, bias=None):
+    def conv3_bn(x, w, scale=None, bias=None):
         if not _Geom(x, w.shape[-1]).fits():
             warnings.warn(
                 f"fused_conv3_bn: no whole-image blocking of "
@@ -557,4 +561,4 @@ def fused_conv3_bn(x, w, scale=None, bias=None):
             return _fc3(x, w, ones, jnp.zeros_like(ones), False)
         return _fc3(x, w, scale, bias, True)
 
-    return dispatch(kernel, xla_conv3_bn, *args)
+    return dispatch(conv3_bn, xla_conv3_bn, *args)

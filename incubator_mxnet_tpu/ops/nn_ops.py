@@ -275,7 +275,7 @@ def layer_norm(x, gamma, beta, axis=-1, eps=1e-5):
     if isinstance(axis, int) and axis in (-1, x.ndim - 1) and gamma.ndim == 1:
         from . import pallas_kernels as pk
         return pk.dispatch(
-            lambda x, g, b: pk.fused_layer_norm(x, g, b, float(eps)),
+            functools.partial(pk.fused_layer_norm, eps=float(eps)),
             xla, x, gamma, beta)
     return xla(x, gamma, beta)
 
@@ -332,7 +332,7 @@ def rms_norm(x, gamma, axis=-1, eps=1e-6):
 
     if axis in (-1, x.ndim - 1):
         from . import pallas_kernels as pk
-        return pk.dispatch(lambda x, g: pk.fused_rms_norm(x, g, eps),
+        return pk.dispatch(functools.partial(pk.fused_rms_norm, eps=eps),
                            xla, x, gamma)
     return xla(x, gamma)
 
@@ -356,8 +356,8 @@ def softmax(x, axis=-1, temperature=None, length=None):
         x = jnp.where(mask, x, -jnp.inf)
     if isinstance(axis, int):
         from . import pallas_kernels as pk
-        return pk.dispatch(lambda x: pk.fused_softmax(x, axis),
-                           lambda x: jnn.softmax(x, axis=axis), x)
+        return pk.dispatch(functools.partial(pk.fused_softmax, axis=axis),
+                           functools.partial(jnn.softmax, axis=axis), x)
     return jnn.softmax(x, axis=axis)
 
 

@@ -37,7 +37,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["fused_softmax", "fused_layer_norm", "flash_attention",
-           "dispatch", "interpret_mode", "gspmd_trace",
+           "dispatch", "kernel_name", "interpret_mode", "gspmd_trace",
            "fused_softmax_xent", "fused_rms_norm"]
 
 _NEG_INF = -1e30
@@ -84,13 +84,28 @@ def dispatch(kernel, xla, *args):
       machine: Mosaic cannot lower there).
     """
     flag = os.environ.get("MXNET_USE_PALLAS", "auto").lower()
-    if flag in ("0", "false", "off"):
-        return xla(*args)
-    if flag in ("1", "true", "on"):
-        return kernel(*args)
-    if jax.default_backend() != "tpu" or getattr(_trace, "gspmd", False):
-        return xla(*args)
-    return jax.lax.platform_dependent(*args, tpu=kernel, default=xla)
+    with jax.named_scope(kernel_name(kernel)):
+        if flag in ("0", "false", "off"):
+            return xla(*args)
+        if flag in ("1", "true", "on"):
+            return kernel(*args)
+        if jax.default_backend() != "tpu" or getattr(_trace, "gspmd", False):
+            return xla(*args)
+        return jax.lax.platform_dependent(*args, tpu=kernel, default=xla)
+
+
+def kernel_name(kernel):
+    """The name an op's kernel is known by in a trace: its wrapper's own
+    (``functools.partial`` unwrapped) less a leading ``_`` and ``fused_``
+    -- ``fused_layer_norm`` -> ``layer_norm``.  :func:`dispatch` runs
+    whichever side it picks under this ``jax.named_scope``, so the XLA
+    composition is found under the same name as the kernel it stands in
+    for, and each ``pl.pallas_call`` below is named ``<it>_fwd`` /
+    ``<it>_bwd``."""
+    while isinstance(kernel, functools.partial):
+        kernel = kernel.func
+    name = getattr(kernel, "__name__", "kernel").strip("<>_")
+    return name.removeprefix("fused_")
 
 
 def _round_up(x: int, m: int) -> int:
@@ -149,7 +164,7 @@ def _rowwise_block(rows_p, cols_p, n_buffers):
     return min(block_r, _round_up(rows_p, 8))
 
 
-def _rowwise_call(kernel, out_dtype, n_inputs, x2d_list):
+def _rowwise_call(kernel, name, out_dtype, n_inputs, x2d_list):
     rows_p, cols_p = x2d_list[0].shape
     block_r = _rowwise_block(rows_p, cols_p, n_inputs + 1)
     spec = pl.BlockSpec((block_r, cols_p), lambda i: (i, 0),
@@ -162,6 +177,7 @@ def _rowwise_call(kernel, out_dtype, n_inputs, x2d_list):
         out_specs=spec,
         interpret=interpret_mode(),
         compiler_params=_COMPILER_PARAMS,
+        name=name,
     )(*x2d_list)
 
 
@@ -199,7 +215,7 @@ def _fused_softmax_impl(x, axis):
     x2d_p, rows, cols = _pad_rows_cols(x2d, 8, 128)
     out = _rowwise_call(
         functools.partial(_softmax_fwd_kernel, n_cols=cols),
-        x.dtype, 1, [x2d_p])
+        "softmax_fwd", x.dtype, 1, [x2d_p])
     out = out[:rows, :cols].reshape(*lead, cols)
     return jnp.moveaxis(out, -1, axis)
 
@@ -218,7 +234,8 @@ def _fused_softmax_bwd(axis, y, g):
     lead = ym.shape[:-1]
     y2d, rows, cols = _pad_rows_cols(ym.reshape(-1, ym.shape[-1]), 8, 128)
     g2d, _, _ = _pad_rows_cols(gm.reshape(-1, gm.shape[-1]), 8, 128)
-    dx = _rowwise_call(_softmax_bwd_kernel, y.dtype, 2, [y2d, g2d])
+    dx = _rowwise_call(_softmax_bwd_kernel, "softmax_bwd", y.dtype, 2,
+                       [y2d, g2d])
     dx = dx[:rows, :cols].reshape(*lead, cols)
     return (jnp.moveaxis(dx, -1, axis),)
 
@@ -304,6 +321,7 @@ def _ln_fwd(x, gamma, beta, eps):
         out_specs=(row_spec, stat_spec, stat_spec),
         interpret=interpret_mode(),
         compiler_params=_COMPILER_PARAMS,
+        name="layer_norm_fwd",
     )(x2d_p, gamma_p.reshape(1, -1), beta_p.reshape(1, -1))
     return y[:rows, :cols].reshape(*lead, cols), mean, rstd
 
@@ -345,6 +363,7 @@ def _fused_ln_bwd(eps, res, g):
         out_specs=(row_spec, part_spec, part_spec),
         interpret=interpret_mode(),
         compiler_params=_COMPILER_PARAMS,
+        name="layer_norm_bwd",
     )(x2d_p, g2d_p, gamma_p.reshape(1, -1), mean, rstd)
     dx = dx[:rows, :cols].reshape(*lead, cols)
     dgamma = dgamma_part.sum(axis=0)[:cols].astype(gamma.dtype)
@@ -430,6 +449,7 @@ def _flash_fwd_impl(q, k, v, sm_scale, causal):
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=q_spec,
         interpret=interpret_mode(),
+        name="flash_attention_fwd",
     )(qp, kp, vp)
     return out.reshape(b, h, tqp, dp)[:, :, :tq, :d]
 
@@ -509,7 +529,7 @@ def _block_probs(qf, ks, kb, m, l, sm_scale, causal, tk, row):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _flash_core(q, k, v, sm_scale, causal):
+def _flash_attention(q, k, v, sm_scale, causal):
     return _flash_fwd_impl(q, k, v, sm_scale, causal)
 
 
@@ -522,7 +542,7 @@ def _flash_vjp_bwd(sm_scale, causal, res, g):
     return _attn_bwd_reference(q, k, v, sm_scale, causal, g)
 
 
-_flash_core.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
+_flash_attention.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
 def flash_attention(q, k, v, sm_scale=None, causal=False):
@@ -538,11 +558,14 @@ def flash_attention(q, k, v, sm_scale=None, causal=False):
     """
     scale = float(sm_scale) if sm_scale is not None else q.shape[-1] ** -0.5
     causal = bool(causal)
+    kernel = functools.partial(_flash_attention, sm_scale=scale,
+                               causal=causal)
     if interpret_mode():
-        return _flash_core(q, k, v, scale, causal)
-    return dispatch(lambda q, k, v: _flash_core(q, k, v, scale, causal),
-                    lambda q, k, v: _xla_attention(q, k, v, scale, causal),
-                    q, k, v)
+        with jax.named_scope(kernel_name(kernel)):
+            return kernel(q, k, v)
+    return dispatch(kernel,
+                    functools.partial(_xla_attention, scale=scale,
+                                      causal=causal), q, k, v)
 
 
 def _xla_attention(q, k, v, scale, causal):
@@ -588,7 +611,7 @@ def _xent_bwd_kernel(x_ref, lbl_ref, g_ref, dx_ref, *, n_cols):
     dx_ref[...] = jnp.where(valid, dx, 0.0).astype(dx_ref.dtype)
 
 
-def _xent_call(kernel, out_shape, x2d, lbl2d, *extra):
+def _xent_call(kernel, name, out_shape, x2d, lbl2d, *extra):
     rows_p, cols_p = x2d.shape
     block_r = _rowwise_block(rows_p, cols_p, 3)
     xspec = pl.BlockSpec((block_r, cols_p), lambda i: (i, 0),
@@ -605,6 +628,7 @@ def _xent_call(kernel, out_shape, x2d, lbl2d, *extra):
         out_specs=out_spec,
         interpret=interpret_mode(),
         compiler_params=_COMPILER_PARAMS,
+        name=name,
     )(x2d, lbl2d, *extra)
 
 
@@ -636,7 +660,7 @@ def _xent_fwd(logits, labels):
                                  8, 1)
     loss = _xent_call(
         functools.partial(_xent_fwd_kernel, n_cols=cols),
-        (x2d.shape[0], 1), x2d, lbl2d)
+        "softmax_xent_fwd", (x2d.shape[0], 1), x2d, lbl2d)
     return loss[:rows, 0], (logits, labels)
 
 
@@ -661,7 +685,7 @@ def _xent_vjp_bwd(res, g):
         g.reshape(-1, 1).astype(jnp.float32), 8, 1)
     dx = _xent_call(
         functools.partial(_xent_bwd_kernel, n_cols=cols),
-        x2d.shape, x2d, lbl2d, g2d)
+        "softmax_xent_bwd", x2d.shape, x2d, lbl2d, g2d)
     return dx[:rows, :cols].astype(logits.dtype), None
 
 
@@ -747,6 +771,7 @@ def _rms_fwd(x, gamma, eps):
         out_specs=(row_spec, stat_spec),
         interpret=interpret_mode(),
         compiler_params=_COMPILER_PARAMS,
+        name="rms_norm_fwd",
     )(x2d_p, gamma_p.reshape(1, -1))
     return y[:rows, :cols].reshape(*lead, cols), (x, gamma, rrms)
 
@@ -784,6 +809,7 @@ def _rms_vjp_bwd(eps, res, g):
         out_specs=(row_spec, part_spec),
         interpret=interpret_mode(),
         compiler_params=_COMPILER_PARAMS,
+        name="rms_norm_bwd",
     )(x2d_p, g2d_p, gamma_p.reshape(1, -1), rrms)
     dgamma = dgamma_parts.sum(axis=0)[:cols].astype(gamma.dtype)
     return dx[:rows, :cols].reshape(*lead, cols), dgamma

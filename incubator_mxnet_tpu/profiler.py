@@ -310,10 +310,6 @@ def resume(profile_process="worker"):
     _alloc_tracking = bool(_state.get("alloc_session"))
 
 
-def is_running():
-    return _state["running"]
-
-
 def _emit(name, category, start_us, dur_us, args=None):
     with _events_lock:
         _events.append({

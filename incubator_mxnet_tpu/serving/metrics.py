@@ -267,7 +267,7 @@ class ServingMetrics:
             self._cold_start[model] = {
                 # gauges: the LIVE version's load cost
                 "cold_start_ms": round(float(cold_start_ms), 3),
-                "time_to_ready_ms": _xc.process_uptime_ms(),
+                "time_to_ready_ms": _xc.since_import_ms(),
                 "compile_count_at_ready": int(compile_count),
                 # counters: monotonic across reloads — a v2 exported
                 # without AOT must not make the Prometheus series drop

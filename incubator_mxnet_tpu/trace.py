@@ -4,9 +4,10 @@ batcher → device (docs/observability.md).
 The stack has deep *aggregate* observability — Prometheus counters,
 latency histograms, a dozen profiler stats providers — but none of it
 answers "where did THIS slow request spend its time?".  This module is
-the request-scoped layer: a pure-stdlib, monotonic-clock span recorder
-with context propagation, near-zero off cost, and Chrome trace-event
-export, threaded through every stage a request crosses:
+the request-scoped layer, and the package's one span source: a
+monotonic-clock span recorder with context propagation, near-zero off
+cost, and Chrome trace-event export, threaded through every stage a
+request crosses:
 
 * **Birth / adoption** — a trace is born at a front end (router or
   server) by a head-sampling decision (``MXNET_TRACE_SAMPLE``, default
@@ -29,6 +30,12 @@ export, threaded through every stage a request crosses:
   dumps into one timeline by trace id.  Span timestamps are monotonic
   (mxlint MX-TIME001); export places them on a shared timeline via
   ONE wall-clock anchor captured per process.
+* **The profiler's clock** — the body of every :class:`span`, sampled
+  or not, also runs under a ``jax.profiler.TraceAnnotation`` of its
+  name: nothing without a profiler session, and with one
+  (``profiler.set_config(xprof_dir=...)``) the span is written into the
+  session's ``.xplane.pb``, on its host plane, beside the device's
+  operations (``chipbench/scope_reduce.py`` reads both).
 
 Span vocabulary (what the instrumented call sites record):
 ``router.request`` / ``server.request`` roots; ``router.hop`` /
@@ -39,7 +46,12 @@ padding bucket); ``session.queue`` / ``session.decode_step``
 (continuous batching); ``executor.build`` vs ``trace_cache.hit``
 (compile-vs-cache on the Executor choke point); ``model.load``;
 ``train.epoch`` / ``train.chunk`` / ``prefetch.fill`` /
-``prefetch.drain`` on the training side.  ``fault.py`` injections add
+``prefetch.drain`` on the training side; ``fused_step.call`` around
+one ``FusedTrainStep.__call__`` with its children
+``fused_step.key_split`` (the PRNG split, a jitted program of its
+own), ``fused_step.analyses`` (while a lint latch is open) and
+``executor.call`` (``Executor.__call__``: the jitted call itself, with
+its ``site``).  ``fault.py`` injections add
 a ``fault.<point>`` event to the active span, so a chaos-run artifact
 shows the injected fault and the recovery path in one timeline.  The
 HA router tier adds ``router.forwarded`` events (mis-hashed session
@@ -57,12 +69,14 @@ import threading
 import time
 from collections import deque
 
+from jax.profiler import TraceAnnotation as _TraceAnnotation
+
 from .base import get_env
 from .locks import named_lock
 
 __all__ = [
     "HEADER", "Span", "enabled", "active", "sample_rate", "configure",
-    "reset", "start_trace", "start_child", "record_span", "from_header",
+    "reset", "start_trace", "record_span", "from_header",
     "parse_header", "header_value", "current_span", "current_trace_id",
     "activate", "span", "add_event", "export", "spans", "stats",
     "health_block", "slow_k",
@@ -142,10 +156,6 @@ class Span:
     @property
     def done(self):
         return self._done
-
-    def duration_ms(self):
-        end = self.t1 if self.t1 is not None else time.monotonic()
-        return (end - self.t0) * 1000.0
 
 
 # ---------------------------------------------------------------------------
@@ -291,15 +301,6 @@ def start_trace(name, **args):
     return Span(name, _new_id(16), **args)
 
 
-def start_child(name, parent=None, **args):
-    """Child span of ``parent`` (default: the context's current span);
-    ``None`` parent ⇒ ``None`` (unsampled requests stay free)."""
-    p = parent if parent is not None else _current.get()
-    if p is None:
-        return None
-    return p.child(name, **args)
-
-
 def record_span(name, parent, t0, t1, **args):
     """Create AND finish a child span with explicit monotonic
     timestamps — for recorders that learn about a region after the
@@ -345,17 +346,26 @@ class span:
     """``with trace.span("router.hop", replica=rid):`` — child of the
     current span, activated for the body, finished on exit with
     ``outcome`` = the escaping exception's class name (or "ok").
-    No current span ⇒ complete no-op."""
+    No current span ⇒ nothing reaches the ring.
 
-    __slots__ = ("_name", "_args", "_span", "_token")
+    Sampled or not, the body also runs under a
+    ``jax.profiler.TraceAnnotation`` of the same name: JAX's own no-op
+    without a profiler session; with one (``profiler.set_config(
+    xprof_dir=...)``, ``jax.profiler.start_trace``) the span is written
+    into the session's ``.xplane.pb``, on the clock of its host plane,
+    beside the device's operations."""
+
+    __slots__ = ("_name", "_args", "_span", "_token", "_annotation")
 
     def __init__(self, name, **args):
         self._name = name
         self._args = args
         self._span = None
         self._token = None
+        self._annotation = _TraceAnnotation(name)
 
     def __enter__(self):
+        self._annotation.__enter__()
         parent = _current.get()
         if parent is not None:
             self._span = parent.child(self._name, **self._args)
@@ -368,6 +378,7 @@ class span:
         if self._span is not None:
             self._span.finish(
                 outcome=etype.__name__ if etype is not None else None)
+        self._annotation.__exit__(etype, evalue, tb)
         return False
 
 

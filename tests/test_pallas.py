@@ -363,7 +363,7 @@ def test_flash_attention_xla_route_when_pallas_off_on_tpu(monkeypatch):
     monkeypatch.setenv("MXNET_USE_PALLAS", "0")
     monkeypatch.setattr(pk, "interpret_mode", lambda: False)
     monkeypatch.setattr(
-        pk, "_flash_core",
+        pk, "_flash_attention",
         lambda *a: pytest.fail("kernel ran with MXNET_USE_PALLAS=0"))
     out = onp.asarray(pk.flash_attention(q, k, v, causal=True))
     onp.testing.assert_allclose(out, ref, rtol=1e-6)

@@ -1,0 +1,200 @@
+"""The names the fused train step gives its instructions (CPU, no compile).
+
+A profile of the step attributes device time by each instruction's
+``op_name``: ``jit(step)/<phase>/<block keys>/jit(<op>)/<kernel>/<primitive>``
+(docs/observability.md).  Held to here, on the toy ResNet and toy BERT of
+tests/chipbench/toy/, with and without a ``dp`` mesh: nearly every equation
+carries a phase, every convolution and matmul a block path made of the keys
+the blocks are registered under, two nets built in one process give the same
+paths, the kernels' scopes and every ``pl.pallas_call`` are named.  The
+grammar is parsed by the benchmark's own reader, ``chipbench/scope_reduce``.
+"""
+import ast
+import collections
+import functools
+import os
+import re
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as onp
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+import incubator_mxnet_tpu as mx  # noqa: E402
+from chipbench import run as harness, scope_reduce as sr  # noqa: E402
+from incubator_mxnet_tpu import amp  # noqa: E402
+from incubator_mxnet_tpu.fuse import make_fused_train_step  # noqa: E402
+from incubator_mxnet_tpu.ops import (  # noqa: E402
+    fused_block, fused_conv, pallas_kernels as pk)
+
+TOY = os.path.join(REPO, "tests", "chipbench", "toy", "cells", "configs")
+CALLS = ("jaxpr", "call_jaxpr", "fun_jaxpr")    # an equation's inner program
+
+
+def _leaves(jaxpr, prefix=()):
+    """``(primitive, name-stack segments)`` of every equation that is no
+    call, through the jitted ops and custom-vjp calls of the step; a call's
+    equations stand below its own stack and its ``jit(<name>)``."""
+    for eqn in jaxpr.eqns:
+        stack = prefix + tuple(
+            s for s in str(eqn.source_info.name_stack).split("/") if s)
+        inner = next((eqn.params[k] for k in CALLS if k in eqn.params), None)
+        if inner is None or eqn.primitive.name == "pallas_call":
+            yield eqn.primitive.name, stack
+            continue
+        if eqn.primitive.name in ("jit", "pjit"):
+            stack += (f"jit({eqn.params['name']})",)
+        yield from _leaves(getattr(inner, "jaxpr", inner), stack)
+
+
+def _step(name, mesh, batch=4, seq_len=16):
+    config = harness.load_json(os.path.join(TOY, name + ".json"))
+    model = harness.load_module(os.path.join(TOY, name + ".py"))
+    mx.random.seed(7)
+    built = model.build(7, config)
+    amp.convert_block(built["net"], config["dtype"])
+    kwargs = {}
+    if mesh:
+        from jax.sharding import Mesh
+        kwargs["mesh"] = Mesh(onp.array(jax.devices()[:4]), ("dp",))
+    step = make_fused_train_step(built["net"], built["loss"],
+                                 built["optimizer"],
+                                 dict(built["optimizer_params"]), **kwargs)
+    x, y = model.make_batch(7, 0, batch, config, {"seq_len": seq_len})
+    jaxpr = jax.make_jaxpr(step.step_fn)(
+        step.params, step.aux, step.opt_state, jnp.asarray(x),
+        jnp.asarray(y), step._key)
+    return built["net"], [(prim, "/".join(("jit(step)",) + stack + (prim,)))
+                          for prim, stack in _leaves(jaxpr.jaxpr)]
+
+
+def _key_paths(block, prefix=()):
+    """Every path of registration keys from the root block down."""
+    yield prefix
+    for key, child in block._children.items():
+        yield from _key_paths(child, prefix + (key,))
+
+
+@functools.lru_cache(maxsize=None)
+def _traced(name, mesh):
+    return _step(name, mesh)
+
+
+CASES = [("toy_resnet", False), ("toy_resnet", True),
+         ("toy_bert", False), ("toy_bert", True)]
+
+
+@pytest.mark.parametrize("name,mesh", CASES)
+def test_nearly_every_equation_carries_a_phase(name, mesh):
+    _, eqns = _traced(name, mesh)
+    phases = collections.Counter(sr.parse(op).phase for _, op in eqns)
+    assert len(eqns) > 300
+    assert phases[None] <= 0.01 * len(eqns), phases
+    # the three phases are told apart with no scope for the backward pass
+    assert min(phases["forward"], phases["backward"],
+               phases["optimizer"]) > 20, phases
+
+
+@pytest.mark.parametrize("name,mesh", CASES)
+def test_every_conv_and_dot_carries_a_path_of_registration_keys(name, mesh):
+    net, eqns = _traced(name, mesh)
+    known = set(_key_paths(net))
+    heavy = [op for prim, op in eqns
+             if prim in ("conv_general_dilated", "dot_general")]
+    assert len(heavy) >= 9
+    for op in heavy:
+        parsed = sr.parse(op)
+        assert parsed.phase in ("forward", "backward"), op
+        assert parsed.blocks and parsed.blocks in known, op
+    # the root contributes no segment, and no path holds a counter-made name
+    assert not [op for _, op in eqns if re.search(r"resnetv1|bertmodel", op)]
+
+
+@pytest.mark.parametrize("name", ["toy_resnet", "toy_bert"])
+def test_two_nets_built_in_one_process_give_identical_paths(name):
+    first = _traced(name, False)
+    second = _step(name, False)
+    assert first[0].name != second[0].name or first[0].prefix == ""
+    assert sorted(first[1]) == sorted(second[1])
+
+
+def test_the_loss_has_one_kernel_name_on_both_sides_of_the_dispatch():
+    """Under a mesh the XLA composition stands in for the kernel
+    (``gspmd_trace``): it is found under the kernel's name all the same."""
+    for mesh in (False, True):
+        _, eqns = _traced("toy_resnet", mesh)
+        loss = collections.Counter(
+            (p.phase, p.kernel) for p in (sr.parse(op) for _, op in eqns)
+            if p.blocks[:1] == ("loss",) and p.kernel)
+        assert loss[("forward", "softmax_xent")] > 3
+        assert loss[("backward", "softmax_xent")] > 3
+
+
+def test_kernel_scopes_and_pallas_names_in_a_traced_step(monkeypatch):
+    monkeypatch.setenv("MXNET_USE_PALLAS", "1")     # interpreted kernels
+    # shapes of its own: an op's jit keeps the route it was first traced with
+    _, eqns = _step("toy_bert", False, batch=3, seq_len=8)
+    calls = collections.Counter()
+    for prim, op in eqns:
+        if prim == "pallas_call":
+            parsed = sr.parse(op)
+            calls[(parsed.phase, parsed.kernel)] += 1
+            assert parsed.call == parsed.kernel + (
+                "_fwd" if parsed.phase == "forward" else "_bwd"), op
+            assert parsed.blocks[-1] in ("embed_ln", "ln1", "ln2"), op
+    # the 5 LayerNorms, forward and backward: attention is one XLA op
+    # (dot_product_attention), the loss over (batch, tokens, vocabulary)
+    # logits is log_softmax + pick
+    assert calls == {("forward", "layer_norm"): 5,
+                     ("backward", "layer_norm"): 5}
+
+
+def test_kernel_name_comes_from_the_wrapper():
+    assert pk.kernel_name(pk.fused_layer_norm) == "layer_norm"
+    assert pk.kernel_name(functools.partial(
+        pk.fused_softmax, axis=-1)) == "softmax"
+    assert pk.kernel_name(pk.fused_softmax_xent) == "softmax_xent"
+    assert pk.kernel_name(lambda x: x) == "lambda"
+    # the reader's vocabulary is the program's
+    made = {pk.kernel_name(f) for f in (
+        pk.fused_layer_norm, pk.fused_rms_norm, pk.fused_softmax,
+        pk.fused_softmax_xent, pk._flash_attention)}
+    assert made | {"matmul_bn", "conv3_bn"} == set(sr.KERNELS)
+
+
+def test_every_pallas_call_site_has_a_name_of_its_own():
+    sites, names = 0, []
+    for module in (pk, fused_block, fused_conv):
+        with open(module.__file__) as f:
+            text = f.read()
+        for node in ast.walk(ast.parse(text)):
+            if (isinstance(node, ast.Call)
+                    and ast.unparse(node.func) == "pl.pallas_call"):
+                sites += 1
+                assert "name" in {k.arg for k in node.keywords}, \
+                    (module.__name__, node.lineno)
+        names += re.findall(r'"((?:%s)_(?:fwd|bwd)\w*)"'
+                            % "|".join(sr.KERNELS), text)
+    assert sites == 14
+    # forward and backward apart, also where two share one call site
+    assert len(names) == len(set(names)) == 16, sorted(names)
+    assert {"layer_norm_fwd", "layer_norm_bwd", "softmax_xent_fwd",
+            "flash_attention_fwd", "matmul_bn_bwd_dw",
+            "conv3_bn_bwd_dx_blocked"} <= set(names)
+
+
+def test_pallas_names_reach_the_traced_program():
+    x = jnp.ones((16, 256), jnp.float32)
+    g = jnp.ones((256,), jnp.float32)
+
+    def loss(x, g, b):
+        return pk.fused_layer_norm(x, g, b).sum()
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(x, g, g)
+    found = [eqn.params["name"] for eqn in jaxpr.jaxpr.eqns
+             if eqn.primitive.name == "pallas_call"]
+    assert found == ["layer_norm_fwd", "layer_norm_bwd"]

@@ -609,6 +609,168 @@ def test_chunked_loop_epoch_trace_and_chunk_spans():
 
 
 # ---------------------------------------------------------------------------
+# the fused step: spans on the profiler's clock, counters at the choke point
+# ---------------------------------------------------------------------------
+
+def _dense_step():
+    import incubator_mxnet_tpu as mx
+    from incubator_mxnet_tpu import gluon, nd
+    from incubator_mxnet_tpu.fuse import make_fused_train_step
+    from incubator_mxnet_tpu.gluon import nn
+
+    mx.random.seed(0)
+    net = nn.HybridSequential()
+    net.add(nn.Dense(8, in_units=4), nn.Dense(4))
+    net.initialize()
+    net(nd.random.uniform(shape=(1, 4)))
+    step = make_fused_train_step(net, gluon.loss.L2Loss(), "sgd",
+                                 {"learning_rate": 0.1})
+    rng = onp.random.RandomState(1)
+    return step, jnp.asarray(rng.rand(2, 4).astype("f")), \
+        jnp.asarray(rng.rand(2, 4).astype("f"))
+
+
+def _host_spans(trace_dir):
+    """``(line, name, start, end)`` of the host plane's events in the one
+    ``.xplane.pb`` under ``trace_dir``."""
+    import glob
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    return [(line.name, e.name, e.start_ns, e.start_ns + e.duration_ns)
+            for plane in ProfileData.from_file(path).planes
+            if plane.name == "/host:CPU"
+            for line in plane.lines for e in line.events]
+
+
+def test_step_spans_reach_the_profilers_trace_with_sampling_off(tmp_path):
+    import jax
+    step, x, y = _dense_step()
+    step(x, y).block_until_ready()          # compiled before the session
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        for _ in range(3):
+            step(x, y).block_until_ready()
+        with trace.span("user.region", note="any span, not only ours"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    spans = _host_spans(str(tmp_path))
+    by_name = {}
+    for line, name, start, end in spans:
+        by_name.setdefault(name, []).append((line, start, end))
+    for name in ("fused_step.call", "fused_step.key_split",
+                 "fused_step.analyses", "executor.call"):
+        assert len(by_name[name]) == 3, (name, sorted(by_name))
+    assert len(by_name["user.region"]) == 1
+    # on one thread's line, each call encloses its key split, its latches'
+    # check and the jitted call, in that order
+    for call, split, latch, jitted in zip(
+            *(sorted(by_name[n]) for n in (
+                "fused_step.call", "fused_step.key_split",
+                "fused_step.analyses", "executor.call"))):
+        assert call[0] == split[0] == latch[0] == jitted[0]
+        assert call[1] <= split[1] <= split[2] <= latch[1] <= latch[2] \
+            <= jitted[1] <= jitted[2] <= call[2]
+    # head sampling stayed off: nothing reached the ring
+    assert not trace.enabled() and trace.spans() == []
+    assert trace.stats()["spans_recorded"] == 0
+
+
+def test_step_spans_reach_the_ring_under_a_sampled_parent():
+    step, x, y = _dense_step()
+    trace.configure(sample=1.0, ring=64)
+    root = trace.start_trace("train.epoch")
+    with trace.activate(root):
+        step(x, y)
+    root.finish()
+    spans = {s.name: s for s in trace.spans(root.trace_id)}
+    call = spans["fused_step.call"]
+    assert call.parent_id == root.span_id
+    assert spans["fused_step.key_split"].parent_id == call.span_id
+    assert spans["executor.call"].parent_id == call.span_id
+    assert spans["executor.call"].args["site"] == \
+        "fused_step:HybridSequential"
+
+
+def test_compile_counters_move_at_the_first_call_and_then_never():
+    from incubator_mxnet_tpu import executor_cache as xc
+    step, x, y = _dense_step()
+    site = "fused_step:HybridSequential"
+    before = dict(xc.stats()["jit"]["per_function"].get(
+        site, dict.fromkeys(xc._JIT_FIELDS, 0)))
+    n_log = len(xc.compile_log())
+    step(x, y).block_until_ready()
+    first = xc.stats()["jit"]
+    mine = first["per_function"][site]
+    assert mine["compiles"] == before["compiles"] + 1
+    assert mine["trace_s"] > before["trace_s"]
+    assert mine["lower_s"] > before["lower_s"]
+    assert mine["backend_compile_s"] > before["backend_compile_s"]
+    # the key split compiled too, under its function's own name
+    assert first["compiles"] >= mine["compiles"] + 1
+    assert first["compiles"] == sum(
+        f["compiles"] for f in first["per_function"].values())
+    record = [r for r in xc.compile_log()[n_log:] if r["site"] == site][-1]
+    assert record["fun"] == "step" and record["at"] <= time.perf_counter()
+    assert record["trace_s"] > 0 and record["backend_compile_s"] > 0
+    # ten more steps: one executable, and the listeners are never reached
+    n_first = len(xc.compile_log())
+    for _ in range(10):
+        step(x, y)
+    step(x, y).block_until_ready()
+    assert step._executor.compile_count == 1
+    assert xc.stats()["jit"] == first
+    assert len(xc.compile_log()) == n_first
+    assert "jit" in profiler.provider_stats()["cold_start"]
+
+
+def test_scope_names_key_the_persistent_cache(tmp_path, monkeypatch):
+    """An executable served from the persistent cache carries the names
+    it was compiled with: two programs that differ only in their
+    ``jax.named_scope`` must not share an entry, or a profile attributes
+    device time to scopes that are gone."""
+    import jax
+    from jax._src import compilation_cache as _jcc
+    from incubator_mxnet_tpu import executor_cache as xc
+
+    def program(scope):
+        def f(a):
+            with jax.named_scope(scope):
+                return (a * 2.0).sum()
+        return f
+
+    saved = {k: getattr(jax.config, k) for k in (
+        "jax_compilation_cache_dir", "jax_enable_compilation_cache",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes",
+        "jax_compilation_cache_include_metadata_in_key")}
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    jax.config.update("jax_enable_compilation_cache", True)
+    xc._reset_compile_cache_for_tests()
+    try:
+        assert xc.ensure_compile_cache() == str(tmp_path)
+        x = jnp.arange(8.0)
+        records = []
+        for scope in ("forward", "forward", "optimizer"):
+            ex = xc.Executor(program(scope), "test:scope_key")
+            ex(x).block_until_ready()
+            records.append(xc.compile_log()[-1])
+        assert [r["site"] for r in records] == ["test:scope_key"] * 3
+        assert [(r["cache_hits"], r["cache_misses"]) for r in records] == \
+            [(0, 1), (1, 0), (0, 1)]
+        assert records[1]["cache_retrieval_s"] > 0
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
+        xc._reset_compile_cache_for_tests()
+        _jcc.reset_cache()
+
+
+# ---------------------------------------------------------------------------
 # end-to-end: process-replica fleet, merged timeline, coverage gate
 # ---------------------------------------------------------------------------
 
