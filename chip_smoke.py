@@ -255,14 +255,14 @@ def child_train(args):
 # ---------------------------------------------------------------- kernels
 
 def _kernel_cases(rehearse):
-    """(name, fn, arg specs, has_kernel[, reference fn]) — fn goes through the op layer's own
+    """(name, fn, arg specs, has_kernel) — fn goes through the op layer's own
     dispatch (a registered op's ``.fn`` is its body: calling the Op itself
     would replay the first trace from its per-op jit cache, whatever
     MXNET_USE_PALLAS says by then); specs are (shape, dtype, kind): normal = N(0,1) activations,
     weight = N(0, 1/fan_in) so a matmul keeps them O(1), ones = 1 + 0.1 N(0,1)
     scales, label = class ids.  Differentiable arguments come first."""
     import jax.numpy as jnp
-    from incubator_mxnet_tpu.ops import nn_ops, pallas_kernels as pk
+    from incubator_mxnet_tpu.ops import nn_ops
     from incubator_mxnet_tpu.ops.fused_block import fused_matmul_bn
     from incubator_mxnet_tpu.ops.fused_conv import fused_conv3_bn
     bf, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
@@ -282,13 +282,16 @@ def _kernel_cases(rehearse):
         ("softmax_xent", lambda x, l: nn_ops.softmax_xent.fn(x, l),
          [((16, 10) if t else (256, 1000), f32, "normal"),
           ((16,) if t else (256,), i32, "label")], True),
-        # off-TPU flash_attention always runs its (interpreted) kernel, so its
-        # XLA composition is named here and not reached through the flag
+        # BERT-base's attention in the benchmark's cell (32 x 12 heads x 512
+        # x 64, dense), and a long causal sequence: several blocks each way
         ("flash_attention",
-         lambda q, k, v: pk.flash_attention(q, k, v, causal=True),
-         [((1, 2, 256, 32) if t else (8, 16, 2048, 64), bf, "normal")] * 3,
-         True, lambda q, k, v: pk._xla_attention(
-             q, k, v, q.shape[-1] ** -0.5, True)),
+         lambda q, k, v: nn_ops.dot_product_attention.fn(q, k, v),
+         [((1, 2, 256, 32) if t else (32, 12, 512, 64), bf, "normal")] * 3,
+         True),
+        ("flash_attention_causal",
+         lambda q, k, v: nn_ops.dot_product_attention.fn(q, k, v, causal=True),
+         [((1, 2, 640, 32) if t else (8, 16, 2048, 64), bf, "normal")] * 3,
+         True),
     ]
     if not t:
         # a 32k-vocabulary LM loss: rows wider than pallas_kernels._MAX_COLS
@@ -362,7 +365,7 @@ def child_kernels(args):
             del os.environ["MXNET_USE_PALLAS"]
 
     key = jax.random.PRNGKey(args.seed)
-    for n, (name, fn, specs, has_kernel, *ref_fn) in enumerate(_kernel_cases(
+    for n, (name, fn, specs, has_kernel) in enumerate(_kernel_cases(
             args.rehearse)):
         # everything the case needs, and its verdict, are one program each:
         # every eager op on the chip is a small compile of its own
@@ -378,8 +381,7 @@ def child_kernels(args):
         run = fwd_bwd(fn, n_diff)
         t0 = time.perf_counter()
         kernel = compiled_under("auto" if on_tpu else "1", run, cts, *a)
-        ref = compiled_under(
-            "0", fwd_bwd(ref_fn[0], n_diff) if ref_fn else run, cts, *a)
+        ref = compiled_under("0", run, cts, *a)
         compile_s = time.perf_counter() - t0
         calls = kernel.as_text().count("tpu_custom_call")
         if on_tpu:

@@ -1,8 +1,9 @@
 """BERT encoder (BASELINE config 3; gluon-nlp BERT lineage).
 
-Gluon blocks over the fused attention op — covers the reference's
-contrib BERT-era ops (src/operator/contrib/transformer.cc: interleaved
-matmul self-attention) with one XLA-fused dot_product_attention.
+Gluon blocks over the attention op — covers the reference's contrib
+BERT-era ops (src/operator/contrib/transformer.cc: interleaved matmul
+self-attention) with dot_product_attention: on a TPU the blockwise
+Pallas kernel pair, with a key-padding mask the XLA composition.
 """
 from __future__ import annotations
 

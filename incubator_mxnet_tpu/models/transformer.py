@@ -24,10 +24,14 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..ops.nn_ops import dot_product_attention
+from ..ops.pallas_kernels import gspmd_trace
 from ..parallel.ring_attention import ring_attention
 from ..parallel.moe import init_moe_params, moe_forward
 
 __all__ = ["TransformerConfig", "TransformerLM"]
+
+_attend = functools.partial(dot_product_attention.fn, causal=True)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -41,7 +45,7 @@ class TransformerConfig:
     dtype: str = "bfloat16"
     use_moe: bool = False
     n_experts: int = 8
-    attention: str = "gspmd"  # 'gspmd' | 'ring' | 'flash' (pallas kernel)
+    attention: str = "gspmd"  # under a mesh: 'gspmd' | 'ring' | 'flash' (shard_map)
 
     @property
     def head_dim(self):
@@ -123,34 +127,27 @@ class TransformerLM:
         return (x.astype(jnp.float32) * lax.rsqrt(ms + 1e-6)).astype(x.dtype) * g
 
     def _attention(self, q, k, v, mesh):
+        """Causal attention through the one entry point,
+        ``dot_product_attention`` (its ``dispatch`` picks the blockwise
+        kernel or the XLA composition); ``cfg.attention`` only says how
+        it meets a mesh."""
         cfg = self.cfg
-        if cfg.attention == "ring" and mesh is not None:
+        if mesh is None:
+            return _attend(q, k, v)
+        if cfg.attention == "ring" or (
+                cfg.attention == "flash" and mesh.shape.get("sp", 1) > 1):
+            # the kernel is per-(b,h); sequence sharding needs the ring
+            # schedule instead of an all-gather of K/V
             return ring_attention(q, k, v, mesh, axis_name="sp", causal=True)
         if cfg.attention == "flash":
-            from ..ops.pallas_kernels import flash_attention
-            if mesh is not None and "sp" in mesh.axis_names \
-                    and mesh.shape["sp"] > 1:
-                # flash kernel is per-(b,h); sequence sharding needs the
-                # ring schedule instead of an all-gather of K/V
-                return ring_attention(q, k, v, mesh, axis_name="sp",
-                                      causal=True)
-            if mesh is not None:
-                # keep batch/head shards local: run the kernel inside
-                # shard_map so GSPMD doesn't all-gather q/k/v
-                spec = P("dp", "tp", None, None)
-                fa = jax.shard_map(
-                    lambda q, k, v: flash_attention(q, k, v, causal=True),
-                    mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
-                return fa(q, k, v).astype(q.dtype)
-            return flash_attention(q, k, v, causal=True).astype(q.dtype)
-        logits = jnp.einsum("bhtd,bhsd->bhts", q, k,
-                            preferred_element_type=jnp.float32)
-        logits = logits / (cfg.head_dim ** 0.5)
-        T, S = logits.shape[-2:]
-        mask = jnp.tril(jnp.ones((T, S), bool))
-        logits = jnp.where(mask, logits, -1e30)
-        probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-        return jnp.einsum("bhts,bhsd->bhtd", probs, v)
+            # keep batch/head shards local: inside shard_map a Mosaic
+            # kernel is legal and GSPMD doesn't all-gather q/k/v
+            spec = P("dp", "tp", None, None)
+            return jax.shard_map(_attend, mesh=mesh,
+                                 in_specs=(spec, spec, spec),
+                                 out_specs=spec)(q, k, v)
+        with gspmd_trace():     # GSPMD partitions the composition
+            return _attend(q, k, v)
 
     def _layer(self, lp, x, mesh):
         cfg = self.cfg

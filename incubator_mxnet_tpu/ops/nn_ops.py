@@ -572,19 +572,39 @@ def lrn(x, alpha=1e-4, beta=0.75, knorm=2.0, nsize=5):
 
 @register("dot_product_attention")
 def dot_product_attention(q, k, v, mask=None, scale=None, causal=False):
-    """(B, H, T, D) scaled dot-product attention as one fused XLA region."""
-    d = q.shape[-1]
-    scale = scale if scale is not None else 1.0 / (d ** 0.5)
-    logits = jnp.einsum("bhtd,bhsd->bhts", q, k,
-                        preferred_element_type=jnp.float32) * scale
-    if causal:
-        t, s = logits.shape[-2:]
-        cm = jnp.tril(jnp.ones((t, s), bool))
-        logits = jnp.where(cm, logits, -jnp.inf)
+    """(B, H, T, D) scaled dot-product attention, the one entry point:
+    the blockwise Pallas kernel pair (``pallas_kernels.flash_attention``,
+    no (T, S) tensor in HBM, forward or backward) where
+    :func:`pallas_kernels.dispatch` routes it, else this XLA composition
+    with float32 scores.  The kernel takes dense and causal attention
+    over bfloat16 / float32 operands; an explicit ``mask`` (a
+    key-padding mask included) and float16 (Mosaic loads no float16
+    vector on a v5e) take the composition."""
+    from . import pallas_kernels as pk
+    scale = float(scale) if scale is not None else q.shape[-1] ** -0.5
+
+    def xla(q, k, v):
+        logits = jnp.einsum("bhtd,bhsd->bhts", q, k,
+                            preferred_element_type=jnp.float32) * scale
+        if causal:
+            t, s = logits.shape[-2:]
+            cm = jnp.tril(jnp.ones((t, s), bool))
+            logits = jnp.where(cm, logits, -jnp.inf)
+        if mask is not None:
+            logits = jnp.where(mask.astype(bool), logits, -jnp.inf)
+        probs = jnn.softmax(logits, axis=-1).astype(q.dtype)
+        return jnp.einsum("bhts,bhsd->bhtd", probs, v)
+
     if mask is not None:
-        logits = jnp.where(mask.astype(bool), logits, -jnp.inf)
-    probs = jnn.softmax(logits, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhts,bhsd->bhtd", probs, v)
+        unless = "mask"
+    elif not (q.dtype == k.dtype == v.dtype
+              and q.dtype in (jnp.bfloat16, jnp.float32)):
+        unless = "dtype"
+    else:
+        unless = None
+    return pk.dispatch(
+        functools.partial(pk.flash_attention, sm_scale=scale, causal=causal),
+        xla, q, k, v, unless=unless)
 
 
 # ---------------------------------------------------------------------------

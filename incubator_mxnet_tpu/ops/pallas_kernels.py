@@ -10,9 +10,10 @@ reaches for Pallas only where a manual schedule beats it:
   max/exp/sum, custom fused backward.
 * ``fused_layer_norm``— single pass mean/rstd + affine, backward kernel
   emitting dx and per-block dgamma/dbeta partials.
-* ``flash_attention`` — blockwise online-softmax attention, O(T) memory,
-  q-block grid with an inner lax.fori_loop over KV blocks; backward is a
-  memory-efficient KV-block scan (recompute, no T×T materialization).
+* ``flash_attention`` — blockwise attention, O(T) memory: a forward kernel
+  (one pass with a plain softmax where a head's keys fit one block, the
+  online rescale beyond) and a backward kernel that recomputes the
+  probabilities from the saved row log-sum-exp; no T×T tensor in HBM.
 
 Kernels run in interpret mode off-TPU so CPU tests exercise identical
 code paths; wrappers pad to TPU tile boundaries ((8,128) f32) and mask.
@@ -26,6 +27,7 @@ under Mosaic at real widths is pinned by tests/test_tpu_compile.py
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import functools
 import os
@@ -36,9 +38,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import profiler as _profiler
+from ..locks import named_lock
+
 __all__ = ["fused_softmax", "fused_layer_norm", "flash_attention",
-           "dispatch", "kernel_name", "interpret_mode", "gspmd_trace",
-           "fused_softmax_xent", "fused_rms_norm"]
+           "dispatch", "kernel_name", "kernel_routes", "interpret_mode",
+           "gspmd_trace", "fused_softmax_xent", "fused_rms_norm"]
 
 _NEG_INF = -1e30
 
@@ -68,11 +73,13 @@ def gspmd_trace(over_mesh=True):
         _trace.gspmd = was
 
 
-def dispatch(kernel, xla, *args):
+def dispatch(kernel, xla, *args, unless=None):
     """Route one op call to ``kernel(*args)`` (a Pallas wrapper) or to
     ``xla(*args)`` (its XLA composition, same contract), from what can
     be observed:
 
+    * ``unless``: what the op itself saw in this call that the kernel
+      does not take (``"mask"``, ``"dtype"`` ...) — the composition;
     * ``MXNET_USE_PALLAS`` '0' forces the composition, '1' the kernel
       (interpreted off-TPU — how the CPU tests reach the kernels);
     * 'auto' (default): a process without a TPU backend, and a program
@@ -82,16 +89,54 @@ def dispatch(kernel, xla, *args):
       where the computation is placed on a TPU, the composition where
       it is placed on the host's CPU (``mx.cpu()`` arrays on a TPU
       machine: Mosaic cannot lower there).
+
+    Every decision is counted by kernel name and reason
+    (:func:`kernel_routes`), when the call is traced.
     """
     flag = os.environ.get("MXNET_USE_PALLAS", "auto").lower()
-    with jax.named_scope(kernel_name(kernel)):
-        if flag in ("0", "false", "off"):
+    forced = flag in ("1", "true", "on")
+    name = kernel_name(kernel)
+    if unless is not None:
+        route = "xla:" + unless
+    elif flag in ("0", "false", "off"):
+        route = "xla:flag"
+    elif forced:
+        route = "kernel"
+    elif jax.default_backend() != "tpu":
+        route = "xla:no_tpu"
+    elif getattr(_trace, "gspmd", False):
+        route = "xla:gspmd"
+    else:
+        route = "kernel"
+    with _routes_lock:
+        _routes[name][route] += 1
+    with jax.named_scope(name):
+        if route != "kernel":
             return xla(*args)
-        if flag in ("1", "true", "on"):
+        if forced:
             return kernel(*args)
-        if jax.default_backend() != "tpu" or getattr(_trace, "gspmd", False):
-            return xla(*args)
         return jax.lax.platform_dependent(*args, tpu=kernel, default=xla)
+
+
+_routes = collections.defaultdict(collections.Counter)
+_routes_lock = named_lock("ops.kernel_routes")
+
+
+def kernel_routes(reset=False):
+    """``{kernel name: {route: calls}}`` of every :func:`dispatch` decision
+    so far: ``kernel``, or ``xla:<why>`` (``flag``, ``no_tpu``, ``gspmd``,
+    or what the op gave as ``unless``).  Counted where an op's body is
+    traced, so a jitted op counts once for each distinct signature it is
+    traced with, not once a run.  The ``kernel_routes`` provider of
+    ``profiler.dumps()``."""
+    with _routes_lock:
+        out = {name: dict(routes) for name, routes in sorted(_routes.items())}
+        if reset:
+            _routes.clear()
+    return out
+
+
+_profiler.register_stats_provider("kernel_routes", kernel_routes)
 
 
 def kernel_name(kernel):
@@ -375,208 +420,362 @@ fused_layer_norm.defvjp(_fused_ln_fwd, _fused_ln_bwd)
 
 
 # ======================================================================
-# flash attention (blockwise online softmax)
+# flash attention: softmax(QKᵀ·scale)·V block by block, forward and
+# backward, no (T, S) score tensor in HBM
 # ======================================================================
 
-_BQ = 128
-_BK = 128
+# Query rows and keys of one block, and the scores one grid step works
+# on: a step takes as many heads together as that allows (a step costs
+# ~0.35 us whatever it holds, and short sequences would pay it for
+# little work).  Measured on a v5e at (32, 12, 512, 64) bfloat16, forward
+# + backward of one layer with its head transposes (my chip runs, PR 28):
+# 512 x 512 blocks with 2 / 4 heads a step 1.27 / 1.23 ms, the XLA
+# composition 4.75; with (T, D) heads, 1 / 2 / 4 heads 2.06 / 1.93 / 1.87,
+# 256 x 512 blocks 2.19, 512 x 256 2.29, 256 x 256 2.79 (two key blocks:
+# the online rescale).  No length was found below which the kernels
+# lose: at (32, 12, 128, 64) both sides sit on the ~0.25 ms floor of one
+# dispatch, at (64, 12, 256, 64) 1.75 against 2.59.
+_ATTN_BQ = 512
+_ATTN_BK = 512
+_ATTN_SCORES = 4 * 512 * 512
+
+_NN = (((1,), (0,)), ((), ()))      # a · b
+_NT = (((1,), (1,)), ((), ()))      # a · bᵀ
+_TN = (((0,), (0,)), ((), ()))      # aᵀ · b
 
 
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *, sm_scale, causal,
-                      t_kv, block_k):
-    """One q block vs the whole (padded) KV sequence, online softmax."""
-    q = q_ref[0].astype(jnp.float32) * sm_scale  # (BQ, D)
-    bq, d = q.shape
-    n_kv = k_ref.shape[1] // block_k
-    qi = pl.program_id(1)
+def _attn_block(t, cap):
+    """``(block, padded length)`` along a sequence: lengths pad to the
+    128-lane tile, and the block is the largest multiple of 128 up to
+    ``cap`` that divides the padded length."""
+    tp = _round_up(t, 128)
+    return max(b for b in range(128, min(cap, tp) + 1, 128)
+               if tp % b == 0), tp
 
-    def body(kb, carry):
-        acc, m_prev, l_prev = carry
-        k = k_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        col = kb * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (bq, block_k), 1)
-        mask = col < t_kv
-        if causal:
-            row = qi * bq + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, block_k), 0)
-            mask = jnp.logical_and(mask, col <= row)
-        s = jnp.where(mask, s, _NEG_INF)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return acc, m_new, l_new
 
-    init = (jnp.zeros((bq, d), jnp.float32),
-            jnp.full((bq, 1), _NEG_INF, jnp.float32),
-            jnp.zeros((bq, 1), jnp.float32))
+def _attn_dot(a, b, dims):
+    """An MXU dot with float32 accumulation: operands enter in their own
+    dtype, float32 ones at ``Precision.HIGHEST`` (the float32 reference
+    step must agree with the host to 1e-3)."""
+    return jax.lax.dot_general(
+        a, b, dims, preferred_element_type=jnp.float32,
+        precision=(jax.lax.Precision.HIGHEST if a.dtype == jnp.float32
+                   else None))
+
+
+def _when(cond):
+    """``pl.when``, or the body as it stands where ``cond`` is plain True."""
+    return (lambda body: body()) if cond is True else pl.when(cond)
+
+
+# Both kernels work on TRANSPOSED heads and scores: q, k, v, the output
+# and every gradient are (D, T) a head, a block of scores is (keys,
+# queries).  A row's maximum and sum then run down the sublanes —
+# elementwise over vregs, on the VPU — where a (queries, keys) tile
+# reduces every row across its 128 lanes on the XLU; the row statistics
+# come out one a lane, which is how the log-sum-exp is stored; every dot
+# but the scores' takes its operands as they lie; and no array in HBM has
+# a head's width (64) as its minor dimension, which the (8, 128) tiling
+# pads to 128 lanes — (B, H, D, T) is also the layout XLA itself picks for
+# a (B, H, T, D) bfloat16 array, so no layout copy surrounds the calls.
+
+def _heads_t(x):
+    """(B, H, T, D) → (B·H, D, T)."""
+    b, h, t, d = x.shape
+    return jnp.swapaxes(x, 2, 3).reshape(b * h, d, t)
+
+
+def _heads_back(xt, lead):
+    """(B·H, D, T) → (B, H, T, D)."""
+    return jnp.swapaxes(xt.reshape(*lead, *xt.shape[1:]), 2, 3)
+
+
+def _attn_scores_t(q_ref, k_ref, h, qi, kb, scale, causal, t_kv):
+    """The scaled (D, queries) block (the scale folded into it, in its
+    own dtype) and the float32 scores of block (qi, kb), (keys, queries):
+    those of keys past the unpadded length and, if causal, after their
+    query at ``_NEG_INF``.  A dense block costs no select."""
+    qt = (q_ref[h] * scale).astype(q_ref.dtype)
+    st = _attn_dot(k_ref[h], qt, _TN)
+    shape = bk, bq = st.shape
+    mask = None
+    if t_kv % bk or causal:
+        key = kb * bk + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        mask = key < t_kv if t_kv % bk else None
     if causal:
-        # only blocks up to (and including) the diagonal contribute
-        n_live = jnp.minimum(((qi + 1) * bq + block_k - 1) // block_k, n_kv)
+        ahead = key <= qi * bq + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        mask = ahead if mask is None else mask & ahead
+    return qt, (st if mask is None else jnp.where(mask, st, _NEG_INF))
+
+
+def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
+                      scale, causal, t_kv, n_kv):
+    """Grid (head groups, q blocks, key blocks).  One key block: a plain
+    softmax in one pass.  Several: the online rescale, with the running
+    maximum, sum and accumulator in scratch across the key axis."""
+    hb = q_ref.shape[0]
+    bq, bk = q_ref.shape[2], k_ref.shape[2]
+    qi, kb = pl.program_id(1), pl.program_id(2)
+
+    def scores_t(h):
+        return _attn_scores_t(q_ref, k_ref, h, qi, kb, scale, causal,
+                              t_kv)[1]
+
+    def pv_t(pt, h):                        # (D, queries)
+        return _attn_dot(v_ref[h], pt.astype(v_ref.dtype), _NN)
+
+    def finish(h, acc_t, m, l):
+        o_ref[h] = (acc_t * (1.0 / l)).astype(o_ref.dtype)
+        lse_ref[h] = m + jnp.log(l)
+
+    if n_kv == 1:
+        for h in range(hb):
+            st = scores_t(h)
+            m = jnp.max(st, axis=0, keepdims=True)
+            pt = jnp.exp(st - m)
+            finish(h, pv_t(pt, h), m, jnp.sum(pt, axis=0, keepdims=True))
+        return
+
+    m_scr, l_scr, acc_scr = scratch
+
+    @pl.when(kb == 0)
+    def _():
+        m_scr[...] = jnp.full(m_scr.shape, _NEG_INF, jnp.float32)
+        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+    # a causal block wholly above the diagonal holds no score
+    @_when(kb * bk < (qi + 1) * bq if causal else True)
+    def _():
+        for h in range(hb):
+            st = scores_t(h)
+            m_prev = m_scr[h]
+            m = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
+            alpha = jnp.exp(m_prev - m)
+            pt = jnp.exp(st - m)
+            l_scr[h] = alpha * l_scr[h] + jnp.sum(pt, axis=0, keepdims=True)
+            acc_scr[h] = alpha * acc_scr[h] + pv_t(pt, h)
+            m_scr[h] = m
+
+    @pl.when(kb == n_kv - 1)
+    def _():
+        for h in range(hb):
+            finish(h, acc_scr[h], m_scr[h], l_scr[h])
+
+
+def _flash_bwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref,
+                      dq_ref, dk_ref, dv_ref, *scratch,
+                      scale, causal, t_kv, n_q, n_kv):
+    """Grid (head groups, key blocks, q blocks): the probabilities of a
+    block are recomputed from the saved row log-sum-exp; dk and dv add up
+    over the inner q axis, dq over the key axis in a block that holds the
+    head's whole dq (resident in VMEM until the head group changes)."""
+    hb = q_ref.shape[0]
+    bq, bk = q_ref.shape[2], k_ref.shape[2]
+    kb, qi = pl.program_id(1), pl.program_id(2)
+    dt = q_ref.dtype
+    cols = pl.ds(pl.multiple_of(qi * bq, bq), bq)
+    scratch = list(scratch)
+    dq_acc = scratch.pop(0) if n_kv > 1 else None
+    dk_acc, dv_acc = scratch if n_q > 1 else (None, None)
+
+    if n_q > 1:
+        @pl.when(qi == 0)
+        def _():
+            dk_acc[...] = jnp.zeros(dk_acc.shape, jnp.float32)
+            dv_acc[...] = jnp.zeros(dv_acc.shape, jnp.float32)
+
+    # a causal block wholly above the diagonal has no gradient; skipped
+    # only where both sums run over several blocks (kb == 0 never is)
+    skip = causal and n_q > 1 and n_kv > 1
+
+    @_when(kb * bk < (qi + 1) * bq if skip else True)
+    def _():
+        for h in range(hb):
+            qt, st = _attn_scores_t(q_ref, k_ref, h, qi, kb, scale, causal,
+                                    t_kv)
+            do_t = do_ref[h]
+            pt = jnp.exp(st - lse_ref[h])
+            delta = jnp.sum(do_t.astype(jnp.float32)
+                            * o_ref[h].astype(jnp.float32),
+                            axis=0, keepdims=True)
+            dst = (pt * (_attn_dot(v_ref[h], do_t, _TN) - delta)).astype(dt)
+            dv_t = _attn_dot(do_t, pt.astype(dt), _NT)
+            dk_t = _attn_dot(qt, dst, _NT)      # qt carries the scale
+            dq_t = _attn_dot(k_ref[h], dst, _NN) * scale
+            if n_q == 1:
+                dk_ref[h] = dk_t.astype(dk_ref.dtype)
+                dv_ref[h] = dv_t.astype(dv_ref.dtype)
+            else:
+                dk_acc[h] += dk_t
+                dv_acc[h] += dv_t
+            if n_kv == 1:
+                dq_ref[h, :, cols] = dq_t.astype(dq_ref.dtype)
+            else:
+                @pl.when(kb == 0)
+                def _():
+                    dq_acc[h, :, cols] = dq_t
+
+                @pl.when(kb > 0)
+                def _():
+                    dq_acc[h, :, cols] += dq_t
+
+    if n_q > 1:
+        @pl.when(qi == n_q - 1)
+        def _():
+            dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
+            dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+    if n_kv > 1:
+        @pl.when(kb == n_kv - 1)
+        def _():
+            for h in range(hb):
+                dq_ref[h, :, cols] = dq_acc[h, :, cols].astype(dq_ref.dtype)
+
+
+class _AttnPlan:
+    """Block shapes of one attention call over (B·H, D, T) operands."""
+
+    def __init__(self, qt, kt):
+        self.bh, self.d, self.tq = qt.shape
+        self.tk = kt.shape[2]
+        self.bq, self.tqp = _attn_block(self.tq, _ATTN_BQ)
+        self.bk, self.tkp = _attn_block(self.tk, _ATTN_BK)
+        self.n_q, self.n_kv = self.tqp // self.bq, self.tkp // self.bk
+        # heads a step: what _ATTN_SCORES allows, and no more than keeps
+        # the backward's whole-head dq (a float32 sum and two output
+        # buffers) inside the VMEM budget
+        most = min(_ATTN_SCORES // (self.bq * self.bk),
+                   _VMEM_BUDGET // (self.d * self.tqp * 8))
+        self.hb = max(n for n in range(1, max(1, most) + 1)
+                      if self.bh % n == 0)
+
+    def spec(self, t_block, index):
+        return pl.BlockSpec((self.hb, self.d, t_block), index,
+                            memory_space=pltpu.VMEM)
+
+    def params(self, *semantics):
+        return pltpu.CompilerParams(vmem_limit_bytes=4 * _VMEM_BUDGET,
+                                    dimension_semantics=semantics)
+
+
+def _pad_t(xt, tp):
+    """Pad the last (sequence) axis to whole blocks; no copy at a length
+    that is whole blocks already."""
+    t = xt.shape[-1]
+    return xt if t == tp else jnp.pad(xt, ((0, 0), (0, 0), (0, tp - t)))
+
+
+def _flash_fwd(qt, kt, vt, sm_scale, causal):
+    """(B·H, D, T) operands → the output (B·H, D, Tq) and the rows'
+    log-sum-exp (B·H, 1, padded Tq) float32."""
+    pn = _AttnPlan(qt, kt)
+    bq, bk = pn.bq, pn.bk
+    if causal:
+        # dead key blocks re-use the last live one: no DMA for them
+        kv_index = lambda g, i, j: (
+            g, 0, jnp.minimum(j, ((i + 1) * bq - 1) // bk))
     else:
-        n_live = n_kv
-    acc, _, l = jax.lax.fori_loop(0, n_live, body, init)
-    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-
-
-def _flash_fwd_impl(q, k, v, sm_scale, causal):
-    b, h, tq, d = q.shape
-    tk = k.shape[2]
-    dp = _round_up(d, 128)
-    tqp = _round_up(tq, _BQ)
-    tkp = _round_up(tk, _BK)
-    pad4 = lambda x, tp: jnp.pad(
-        x, ((0, 0), (0, 0), (0, tp - x.shape[2]), (0, dp - d)))
-    qp = pad4(q, tqp).reshape(b * h, tqp, dp)
-    kp = pad4(k, tkp).reshape(b * h, tkp, dp)
-    vp = pad4(v, tkp).reshape(b * h, tkp, dp)
-    grid = (b * h, tqp // _BQ)
-    q_spec = pl.BlockSpec((1, _BQ, dp), lambda bh, i: (bh, i, 0),
-                          memory_space=pltpu.VMEM)
-    kv_spec = pl.BlockSpec((1, tkp, dp), lambda bh, i: (bh, 0, 0),
-                           memory_space=pltpu.VMEM)
-    out = pl.pallas_call(
-        functools.partial(_flash_fwd_kernel, sm_scale=sm_scale,
-                          causal=causal, t_kv=tk, block_k=_BK),
-        out_shape=jax.ShapeDtypeStruct((b * h, tqp, dp), q.dtype),
-        grid=grid,
+        kv_index = lambda g, i, j: (g, 0, j)
+    q_spec = pn.spec(bq, lambda g, i, j: (g, 0, i))
+    kv_spec = pn.spec(bk, kv_index)
+    lse_spec = pl.BlockSpec((pn.hb, 1, bq), lambda g, i, j: (g, 0, i),
+                            memory_space=pltpu.VMEM)
+    scratch = [] if pn.n_kv == 1 else [
+        pltpu.VMEM((pn.hb, 1, bq), jnp.float32),
+        pltpu.VMEM((pn.hb, 1, bq), jnp.float32),
+        pltpu.VMEM((pn.hb, pn.d, bq), jnp.float32)]
+    ot, lse = pl.pallas_call(
+        functools.partial(_flash_fwd_kernel, scale=sm_scale, causal=causal,
+                          t_kv=pn.tk, n_kv=pn.n_kv),
+        out_shape=(jax.ShapeDtypeStruct((pn.bh, pn.d, pn.tqp), qt.dtype),
+                   jax.ShapeDtypeStruct((pn.bh, 1, pn.tqp), jnp.float32)),
+        grid=(pn.bh // pn.hb, pn.n_q, pn.n_kv),
         in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=q_spec,
+        out_specs=(q_spec, lse_spec),
+        scratch_shapes=scratch,
         interpret=interpret_mode(),
+        compiler_params=pn.params("parallel", "parallel", "arbitrary"),
         name="flash_attention_fwd",
-    )(qp, kp, vp)
-    return out.reshape(b, h, tqp, dp)[:, :, :tq, :d]
+    )(_pad_t(qt, pn.tqp), _pad_t(kt, pn.tkp), _pad_t(vt, pn.tkp))
+    return ot[:, :, :pn.tq], lse
 
 
-def _attn_bwd_reference(q, k, v, sm_scale, causal, g):
-    """Memory-efficient backward: scan over KV blocks, recomputing
-    attention weights blockwise (never materializes the T×T matrix)."""
-    fp32 = jnp.float32
-    qf, kf, vf, gf = (t.astype(fp32) for t in (q, k, v, g))
-    tq, tk = q.shape[2], k.shape[2]
-    row = jnp.arange(tq)[:, None]
-
-    # pass 1: softmax stats per q row, blockwise
-    def stat_step(carry, kb):
-        m_prev, l_prev = carry
-        ks = jax.lax.dynamic_slice_in_dim(kf, kb * _BK, _BK, axis=2)
-        s = jnp.einsum("bhqd,bhkd->bhqk", qf, ks) * sm_scale
-        col = kb * _BK + jnp.arange(_BK)[None, :]
-        mask = col < tk
-        if causal:
-            mask = jnp.logical_and(mask, col <= row)
-        s = jnp.where(mask, s, _NEG_INF)
-        m_new = jnp.maximum(m_prev, s.max(-1))
-        l_new = l_prev * jnp.exp(m_prev - m_new) + \
-            jnp.exp(s - m_new[..., None]).sum(-1)
-        return (m_new, l_new), None
-
-    tkp = _round_up(tk, _BK)
-    kf = jnp.pad(kf, ((0, 0), (0, 0), (0, tkp - tk), (0, 0)))
-    vf = jnp.pad(vf, ((0, 0), (0, 0), (0, tkp - tk), (0, 0)))
-    n_kv = tkp // _BK
-    b, h = q.shape[:2]
-    m0 = jnp.full((b, h, tq), _NEG_INF, fp32)
-    l0 = jnp.zeros((b, h, tq), fp32)
-    (m, l), _ = jax.lax.scan(stat_step, (m0, l0), jnp.arange(n_kv))
-    l = jnp.maximum(l, 1e-30)
-
-    # delta = rowsum(dO * O) computed blockwise from recomputed O
-    def out_step(carry, kb):
-        acc = carry
-        ks = jax.lax.dynamic_slice_in_dim(kf, kb * _BK, _BK, axis=2)
-        vs = jax.lax.dynamic_slice_in_dim(vf, kb * _BK, _BK, axis=2)
-        p = _block_probs(qf, ks, kb, m, l, sm_scale, causal, tk, row)
-        return acc + jnp.einsum("bhqk,bhkd->bhqd", p, vs), None
-
-    o, _ = jax.lax.scan(out_step, jnp.zeros_like(qf), jnp.arange(n_kv))
-    delta = (gf * o).sum(-1)
-
-    def grad_step(carry, kb):
-        dq = carry
-        ks = jax.lax.dynamic_slice_in_dim(kf, kb * _BK, _BK, axis=2)
-        vs = jax.lax.dynamic_slice_in_dim(vf, kb * _BK, _BK, axis=2)
-        p = _block_probs(qf, ks, kb, m, l, sm_scale, causal, tk, row)
-        dp = jnp.einsum("bhqd,bhkd->bhqk", gf, vs)
-        ds = p * (dp - delta[..., None]) * sm_scale
-        dq = dq + jnp.einsum("bhqk,bhkd->bhqd", ds, ks)
-        dk_b = jnp.einsum("bhqk,bhqd->bhkd", ds, qf)
-        dv_b = jnp.einsum("bhqk,bhqd->bhkd", p, gf)
-        return dq, (dk_b, dv_b)
-
-    dq, (dk_blocks, dv_blocks) = jax.lax.scan(
-        grad_step, jnp.zeros_like(qf), jnp.arange(n_kv))
-    # (n_kv, b, h, BK, d) → (b, h, n_kv·BK, d), trimmed to tk
-    dk = jnp.moveaxis(dk_blocks, 0, 2).reshape(b, h, tkp, -1)[:, :, :tk]
-    dv = jnp.moveaxis(dv_blocks, 0, 2).reshape(b, h, tkp, -1)[:, :, :tk]
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
-
-
-def _block_probs(qf, ks, kb, m, l, sm_scale, causal, tk, row):
-    s = jnp.einsum("bhqd,bhkd->bhqk", qf, ks) * sm_scale
-    col = kb * _BK + jnp.arange(_BK)[None, :]
-    mask = col < tk
-    if causal:
-        mask = jnp.logical_and(mask, col <= row)
-    s = jnp.where(mask, s, _NEG_INF)
-    return jnp.exp(s - m[..., None]) / l[..., None]
+def _flash_bwd(qt, kt, vt, ot, lse, do_t, sm_scale, causal):
+    pn = _AttnPlan(qt, kt)
+    bq, bk = pn.bq, pn.bk
+    if causal and pn.n_q > 1 and pn.n_kv > 1:
+        # dead q blocks re-use the first live one: no DMA for them
+        q_of = lambda j, i: jnp.maximum(i, (j * bk) // bq)
+    else:
+        q_of = lambda j, i: i
+    q_spec = pn.spec(bq, lambda g, j, i: (g, 0, q_of(j, i)))
+    kv_spec = pn.spec(bk, lambda g, j, i: (g, 0, j))
+    lse_spec = pl.BlockSpec((pn.hb, 1, bq),
+                            lambda g, j, i: (g, 0, q_of(j, i)),
+                            memory_space=pltpu.VMEM)
+    dq_spec = pn.spec(pn.tqp, lambda g, j, i: (g, 0, 0))
+    scratch = []
+    if pn.n_kv > 1:
+        scratch.append(pltpu.VMEM((pn.hb, pn.d, pn.tqp), jnp.float32))
+    if pn.n_q > 1:
+        scratch += [pltpu.VMEM((pn.hb, pn.d, bk), jnp.float32)] * 2
+    dq_t, dk_t, dv_t = pl.pallas_call(
+        functools.partial(_flash_bwd_kernel, scale=sm_scale, causal=causal,
+                          t_kv=pn.tk, n_q=pn.n_q, n_kv=pn.n_kv),
+        out_shape=(jax.ShapeDtypeStruct((pn.bh, pn.d, pn.tqp), qt.dtype),
+                   jax.ShapeDtypeStruct((pn.bh, pn.d, pn.tkp), kt.dtype),
+                   jax.ShapeDtypeStruct((pn.bh, pn.d, pn.tkp), vt.dtype)),
+        grid=(pn.bh // pn.hb, pn.n_kv, pn.n_q),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, lse_spec, q_spec],
+        out_specs=(dq_spec, kv_spec, kv_spec),
+        scratch_shapes=scratch,
+        interpret=interpret_mode(),
+        compiler_params=pn.params("parallel", "arbitrary", "arbitrary"),
+        name="flash_attention_bwd",
+    )(_pad_t(qt, pn.tqp), _pad_t(kt, pn.tkp), _pad_t(vt, pn.tkp),
+      _pad_t(ot, pn.tqp), lse, _pad_t(do_t, pn.tqp))
+    return dq_t[:, :, :pn.tq], dk_t[:, :, :pn.tk], dv_t[:, :, :pn.tk]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _flash_attention(q, k, v, sm_scale, causal):
-    return _flash_fwd_impl(q, k, v, sm_scale, causal)
+def _flash_core(q, k, v, sm_scale, causal):
+    return _flash_vjp_fwd(q, k, v, sm_scale, causal)[0]
 
 
 def _flash_vjp_fwd(q, k, v, sm_scale, causal):
-    return _flash_fwd_impl(q, k, v, sm_scale, causal), (q, k, v)
+    qt, kt, vt = _heads_t(q), _heads_t(k), _heads_t(v)
+    ot, lse = _flash_fwd(qt, kt, vt, sm_scale, causal)
+    return _heads_back(ot, q.shape[:2]), (qt, kt, vt, ot, lse)
 
 
 def _flash_vjp_bwd(sm_scale, causal, res, g):
-    q, k, v = res
-    return _attn_bwd_reference(q, k, v, sm_scale, causal, g)
+    qt = res[0]
+    grads = _flash_bwd(*res, _heads_t(g.astype(qt.dtype)), sm_scale, causal)
+    return tuple(_heads_back(x, g.shape[:2]) for x in grads)
 
 
-_flash_attention.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
+_flash_core.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
 def flash_attention(q, k, v, sm_scale=None, causal=False):
-    """Blockwise attention, O(T) memory: softmax(QKᵀ·scale)·V.
-
-    Shapes (B, H, T, D). New capability relative to the reference (which
-    caps sequence length by device memory, SURVEY.md §5.7); pairs with
+    """softmax(QKᵀ·scale)·V over (B, H, T, D) operands as a pair of
+    Pallas kernels, forward and backward: scores, probabilities and
+    their gradients live in VMEM one block at a time, and what the
+    backward pass keeps is q, k, v, the output and one float32 a row.
+    Inside, heads are (D, T): ``q``, ``k``, ``v`` are transposed on the
+    way in and the results on the way out, which XLA folds into the
+    transposes that make (B, H, T, D) out of a packed projection.
+    New capability relative to the reference (which caps sequence length
+    by device memory, SURVEY.md §5.7); pairs with
     parallel/ring_attention.py for the sequence-parallel path.
 
-    In a process without a TPU the kernel always runs (interpret mode),
-    so CPU tests cover it; with one, :func:`dispatch` decides between
-    the kernel and the O(T²) XLA formulation.
+    Like the other wrappers here this always runs the kernel (interpreted
+    off a TPU); the op ``dot_product_attention`` is the entry point that
+    routes between it and the XLA composition.  ``causal`` masks key j
+    for query i where j > i, as the composition's ``tril`` does.
     """
     scale = float(sm_scale) if sm_scale is not None else q.shape[-1] ** -0.5
-    causal = bool(causal)
-    kernel = functools.partial(_flash_attention, sm_scale=scale,
-                               causal=causal)
-    if interpret_mode():
-        with jax.named_scope(kernel_name(kernel)):
-            return kernel(q, k, v)
-    return dispatch(kernel,
-                    functools.partial(_xla_attention, scale=scale,
-                                      causal=causal), q, k, v)
-
-
-def _xla_attention(q, k, v, scale, causal):
-    logits = jnp.einsum("bhtd,bhsd->bhts", q, k,
-                        preferred_element_type=jnp.float32) * scale
-    if causal:
-        T, S = logits.shape[-2:]
-        mask = jnp.tril(jnp.ones((T, S), bool))
-        logits = jnp.where(mask, logits, _NEG_INF)
-    p = jax.nn.softmax(logits, axis=-1)
-    return jnp.einsum("bhts,bhsd->bhtd", p.astype(q.dtype), v)
+    return _flash_core(q, k, v, scale, bool(causal))
 
 
 # ======================================================================

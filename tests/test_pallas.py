@@ -90,6 +90,76 @@ def test_fused_layer_norm_grads():
 
 
 # ---------------- flash attention --------------------------------------
+# One entry point, ``dot_product_attention``: MXNET_USE_PALLAS=1 takes the
+# kernel pair (interpreted here), =0 the XLA composition, which is the
+# oracle — output and all three gradients are held to its autodiff.
+
+def _attend(monkeypatch, flag, *qkv_mask, **kw):
+    from incubator_mxnet_tpu.ops import nn_ops
+    monkeypatch.setenv("MXNET_USE_PALLAS", flag)
+    return nn_ops.dot_product_attention.fn(*qkv_mask, **kw)
+
+
+def _qkv(b, h, tq, tk, d, dtype=jnp.float32, seed=8):
+    return (_rand(b, h, tq, d, dtype=dtype, seed=seed) * 0.5,
+            _rand(b, h, tk, d, dtype=dtype, seed=seed + 1) * 0.5,
+            _rand(b, h, tk, d, dtype=dtype, seed=seed + 2),
+            _rand(b, h, tq, d, dtype=dtype, seed=seed + 3))
+
+
+F32, BF16 = jnp.float32, jnp.bfloat16
+# (b, h, tq, tk, d, dtype): one block and ragged lengths, BERT's head
+# (one 512-key block, a plain softmax), cross lengths, several blocks
+ATTN = [(2, 3, 64, 64, 32, F32), (2, 3, 200, 200, 64, F32),
+        (1, 2, 512, 512, 64, F32), (1, 2, 512, 512, 64, BF16),
+        (1, 2, 200, 200, 32, BF16), (1, 2, 70, 150, 32, F32),
+        (1, 2, 96, 96, 32, F32), (1, 2, 640, 384, 32, F32)]
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("b,h,tq,tk,d,dtype", ATTN)
+def test_flash_attention_matches_composition(monkeypatch, b, h, tq, tk, d,
+                                             dtype, causal):
+    q, k, v, ct = _qkv(b, h, tq, tk, d, dtype)
+    f32 = lambda a: onp.asarray(a.astype(F32))
+
+    def run(flag):
+        out, vjp = jax.vjp(lambda *a: _attend(monkeypatch, flag, *a,
+                                              causal=causal), q, k, v)
+        return (out,) + vjp(ct)
+
+    # bfloat16: the kernel rounds exp(s - max) where the composition
+    # rounds the normalised probability, half a bfloat16 ulp apart
+    tol = dict(rtol=2e-2, atol=2e-2) if dtype == BF16 else \
+        dict(rtol=2e-4, atol=2e-5)
+    for name, got, want in zip(("out", "dq", "dk", "dv"), run("1"),
+                               run("0")):
+        assert got.dtype == want.dtype and got.shape == want.shape
+        onp.testing.assert_allclose(f32(got), f32(want), err_msg=name,
+                                    **tol)
+
+
+@pytest.mark.parametrize("blocks", [(128, 128, 128 * 128),
+                                    (256, 128, 2 * 256 * 128),
+                                    (128, 256, 4 * 128 * 256)])
+def test_flash_attention_online_rescale_and_head_groups(monkeypatch, blocks):
+    """Smaller blocks than the module's: several key blocks (the online
+    rescale forward, dq and dk/dv summed over blocks backward), causal
+    blocks skipped, several heads a grid step."""
+    for name, val in zip(("_ATTN_BQ", "_ATTN_BK", "_ATTN_SCORES"), blocks):
+        monkeypatch.setattr(pk, name, val)
+    q, k, v, ct = _qkv(1, 4, 384, 512, 32, seed=30)
+    for causal in (False, True):
+        for got, want in zip(
+                jax.vjp(lambda *a: pk.flash_attention(*a, causal=causal),
+                        q, k, v)[1](ct),
+                jax.vjp(lambda *a: _attn_ref(*a, causal), q, k, v)[1](ct)):
+            onp.testing.assert_allclose(onp.asarray(got), onp.asarray(want),
+                                        rtol=2e-4, atol=2e-5)
+        onp.testing.assert_allclose(
+            onp.asarray(pk.flash_attention(q, k, v, causal=causal)),
+            onp.asarray(_attn_ref(q, k, v, causal)), rtol=2e-4, atol=2e-5)
+
 
 def _attn_ref(q, k, v, causal):
     scale = q.shape[-1] ** -0.5
@@ -102,55 +172,62 @@ def _attn_ref(q, k, v, causal):
     return jnp.einsum("bhqk,bhkd->bhqd", p, v)
 
 
-@pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.parametrize("t,d", [(64, 32), (200, 64)])
-def test_flash_attention_matches_reference(causal, t, d):
-    q = _rand(2, 3, t, d, seed=8) * 0.5
-    k = _rand(2, 3, t, d, seed=9) * 0.5
-    v = _rand(2, 3, t, d, seed=10)
-    got = pk.flash_attention(q, k, v, causal=causal)
-    want = _attn_ref(q, k, v, causal)
-    onp.testing.assert_allclose(onp.asarray(got), onp.asarray(want),
-                                rtol=1e-4, atol=1e-4)
-
-
-def test_flash_attention_cross_lengths():
-    q = _rand(1, 2, 70, 32, seed=11) * 0.5
-    k = _rand(1, 2, 150, 32, seed=12) * 0.5
-    v = _rand(1, 2, 150, 32, seed=13)
-    got = pk.flash_attention(q, k, v)
-    want = _attn_ref(q, k, v, False)
-    onp.testing.assert_allclose(onp.asarray(got), onp.asarray(want),
-                                rtol=1e-4, atol=1e-4)
-
-
-@pytest.mark.parametrize("causal", [False, True])
-def test_flash_attention_grads(causal):
-    q = _rand(1, 2, 96, 32, seed=14) * 0.5
-    k = _rand(1, 2, 96, 32, seed=15) * 0.5
-    v = _rand(1, 2, 96, 32, seed=16)
-
-    def f_pallas(q, k, v):
-        return (pk.flash_attention(q, k, v, causal=causal) ** 2).sum()
-
-    def f_ref(q, k, v):
-        return (_attn_ref(q, k, v, causal) ** 2).sum()
-
-    got = jax.grad(f_pallas, argnums=(0, 1, 2))(q, k, v)
-    want = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
-    for g_, w_ in zip(got, want):
-        onp.testing.assert_allclose(onp.asarray(g_), onp.asarray(w_),
-                                    rtol=2e-3, atol=2e-4)
-
-
 def test_flash_attention_under_jit_and_vmap():
-    q = _rand(2, 2, 64, 32, seed=17) * 0.5
-    k = _rand(2, 2, 64, 32, seed=18) * 0.5
-    v = _rand(2, 2, 64, 32, seed=19)
+    q, k, v, _ = _qkv(2, 2, 64, 64, 32, seed=17)
     jitted = jax.jit(lambda q, k, v: pk.flash_attention(q, k, v, causal=True))
     onp.testing.assert_allclose(onp.asarray(jitted(q, k, v)),
                                 onp.asarray(_attn_ref(q, k, v, True)),
                                 rtol=1e-4, atol=1e-4)
+    mapped = jax.vmap(lambda q, k, v: pk.flash_attention(q, k, v))
+    onp.testing.assert_allclose(
+        onp.asarray(mapped(q[None], k[None], v[None])[0]),
+        onp.asarray(_attn_ref(q, k, v, False)), rtol=1e-4, atol=1e-4)
+
+
+def _pallas_calls(fn, *args):
+    """Names of the ``pallas_call``s in fn's jaxpr, inner programs too."""
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn.params["name"]
+                continue
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from walk(sub)
+    return sorted(walk(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
+def test_dot_product_attention_routing(monkeypatch):
+    """What the entry point routes where, read from the jaxpr of its
+    gradient and from the ``kernel_routes`` counter."""
+    from incubator_mxnet_tpu import profiler
+    q, k, v, _ = _qkv(1, 2, 128, 128, 32, seed=50)
+    mask = jnp.ones((1, 1, 1, 128), bool)
+
+    def grad_of(flag, *args, **kw):
+        pk.kernel_routes(reset=True)
+        calls = _pallas_calls(jax.grad(
+            lambda *a: _attend(monkeypatch, flag, *a, **kw).sum(),
+            argnums=(0, 1, 2)), *args)
+        return calls, pk.kernel_routes()["flash_attention"]
+
+    pair = ["flash_attention_bwd", "flash_attention_fwd"]
+    assert grad_of("1", q, k, v) == (pair, {"kernel": 1})
+    assert grad_of("1", q, k, v, causal=True) == (pair, {"kernel": 1})
+    # a mask (BERT's key-padding mask is one), float16: the composition,
+    # whatever the flag says
+    assert grad_of("1", q, k, v, mask) == ([], {"xla:mask": 1})
+    half = [a.astype(jnp.float16) for a in (q, k, v)]
+    assert grad_of("1", *half) == ([], {"xla:dtype": 1})
+    assert grad_of("0", q, k, v) == ([], {"xla:flag": 1})
+    # off a TPU the default is the composition, as for every other op
+    assert grad_of("auto", q, k, v) == ([], {"xla:no_tpu": 1})
+    with pk.gspmd_trace():
+        monkeypatch.setattr(pk.jax, "default_backend", lambda: "tpu")
+        assert grad_of("auto", q, k, v) == ([], {"xla:gspmd": 1})
+    # the counter is a stats provider of profiler.dumps()
+    assert profiler.provider_stats()["kernel_routes"] == {
+        "flash_attention": {"xla:gspmd": 1}}
+    assert "kernel_routes" in profiler.dumps()
 
 
 def test_nn_ops_dispatch_to_pallas(monkeypatch):
@@ -172,9 +249,11 @@ def test_nn_ops_dispatch_to_pallas(monkeypatch):
         onp.asarray(want), rtol=1e-4, atol=1e-5)
 
 
-def test_transformer_flash_attention_matches_gspmd():
+@pytest.mark.parametrize("flag", ["auto", "1"])
+def test_transformer_flash_attention_matches_gspmd(monkeypatch, flag):
     from incubator_mxnet_tpu.models.transformer import (TransformerConfig,
                                                         TransformerLM)
+    monkeypatch.setenv("MXNET_USE_PALLAS", flag)
     cfg = dict(vocab_size=64, d_model=32, n_heads=2, n_layers=2, d_ff=64,
                max_len=32, dtype="float32")
     m_g = TransformerLM(TransformerConfig(**cfg, attention="gspmd"))
@@ -352,21 +431,15 @@ def test_interpret_mode_lets_backend_init_errors_out(monkeypatch):
 
 
 def test_flash_attention_xla_route_when_pallas_off_on_tpu(monkeypatch):
-    rng = onp.random.RandomState(0)
-    q = jnp.asarray(rng.randn(1, 2, 16, 8), jnp.float32)
-    k = jnp.asarray(rng.randn(1, 2, 16, 8), jnp.float32)
-    v = jnp.asarray(rng.randn(1, 2, 16, 8), jnp.float32)
-    ref = onp.asarray(pk._xla_attention(q, k, v, 8 ** -0.5, True))
-    # in a process without a TPU the kernel always runs (interpreted);
-    # the XLA route is MXNET_USE_PALLAS=0 on real hardware — drive it by
-    # patching interpret_mode
-    monkeypatch.setenv("MXNET_USE_PALLAS", "0")
-    monkeypatch.setattr(pk, "interpret_mode", lambda: False)
+    q, k, v, _ = _qkv(1, 2, 16, 16, 8, seed=0)
+    ref = onp.asarray(_attn_ref(q, k, v, True))
+    # MXNET_USE_PALLAS=0 is the composition on real hardware too
+    monkeypatch.setattr(pk.jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(
-        pk, "_flash_attention",
-        lambda *a: pytest.fail("kernel ran with MXNET_USE_PALLAS=0"))
-    out = onp.asarray(pk.flash_attention(q, k, v, causal=True))
-    onp.testing.assert_allclose(out, ref, rtol=1e-6)
+        pk, "flash_attention",
+        lambda *a, **kw: pytest.fail("kernel ran with MXNET_USE_PALLAS=0"))
+    out = onp.asarray(_attend(monkeypatch, "0", q, k, v, causal=True))
+    onp.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-6)
 
 
 @pytest.mark.parametrize("norm", ["layer_norm", "rms_norm"])

@@ -136,6 +136,7 @@ def test_the_loss_has_one_kernel_name_on_both_sides_of_the_dispatch():
 
 def test_kernel_scopes_and_pallas_names_in_a_traced_step(monkeypatch):
     monkeypatch.setenv("MXNET_USE_PALLAS", "1")     # interpreted kernels
+    pk.kernel_routes(reset=True)
     # shapes of its own: an op's jit keeps the route it was first traced with
     _, eqns = _step("toy_bert", False, batch=3, seq_len=8)
     calls = collections.Counter()
@@ -145,12 +146,16 @@ def test_kernel_scopes_and_pallas_names_in_a_traced_step(monkeypatch):
             calls[(parsed.phase, parsed.kernel)] += 1
             assert parsed.call == parsed.kernel + (
                 "_fwd" if parsed.phase == "forward" else "_bwd"), op
-            assert parsed.blocks[-1] in ("embed_ln", "ln1", "ln2"), op
-    # the 5 LayerNorms, forward and backward: attention is one XLA op
-    # (dot_product_attention), the loss over (batch, tokens, vocabulary)
-    # logits is log_softmax + pick
+            assert parsed.blocks[-1] in ("embed_ln", "ln1", "ln2",
+                                         "attention"), op
+    # the 5 LayerNorms and the 2 layers' attention, forward and backward;
+    # the loss over (batch, tokens, vocabulary) logits is log_softmax + pick
     assert calls == {("forward", "layer_norm"): 5,
-                     ("backward", "layer_norm"): 5}
+                     ("backward", "layer_norm"): 5,
+                     ("forward", "flash_attention"): 2,
+                     ("backward", "flash_attention"): 2}
+    # each op's body was traced once for its one signature
+    assert pk.kernel_routes()["flash_attention"] == {"kernel": 1}
 
 
 def test_kernel_name_comes_from_the_wrapper():
@@ -162,7 +167,7 @@ def test_kernel_name_comes_from_the_wrapper():
     # the reader's vocabulary is the program's
     made = {pk.kernel_name(f) for f in (
         pk.fused_layer_norm, pk.fused_rms_norm, pk.fused_softmax,
-        pk.fused_softmax_xent, pk._flash_attention)}
+        pk.fused_softmax_xent, pk.flash_attention)}
     assert made | {"matmul_bn", "conv3_bn"} == set(sr.KERNELS)
 
 
@@ -179,11 +184,11 @@ def test_every_pallas_call_site_has_a_name_of_its_own():
                     (module.__name__, node.lineno)
         names += re.findall(r'"((?:%s)_(?:fwd|bwd)\w*)"'
                             % "|".join(sr.KERNELS), text)
-    assert sites == 14
+    assert sites == 15
     # forward and backward apart, also where two share one call site
-    assert len(names) == len(set(names)) == 16, sorted(names)
+    assert len(names) == len(set(names)) == 17, sorted(names)
     assert {"layer_norm_fwd", "layer_norm_bwd", "softmax_xent_fwd",
-            "flash_attention_fwd", "matmul_bn_bwd_dw",
+            "flash_attention_fwd", "flash_attention_bwd", "matmul_bn_bwd_dw",
             "conv3_bn_bwd_dx_blocked"} <= set(names)
 
 

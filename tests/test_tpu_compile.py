@@ -114,11 +114,23 @@ def test_softmax_xent_fwd_bwd(one_chip, for_the_chip, rows, cols, dtype):
     assert text.count("tpu_custom_call") == 2
 
 
-def test_flash_attention_fwd(one_chip, for_the_chip):
-    q = _spec(one_chip, (8, 16, 2048, 64), BF16)
-    text = _compile(lambda q, k, v: pk.flash_attention(q, k, v, causal=True),
-                    q, q, q)
-    assert text.count("tpu_custom_call") == 1
+# BERT-base's attention in the benchmark's cell, the float32 reference
+# step's (batch 2), long causal sequences (several blocks each way; at
+# 8,192 a head's whole dq allows two heads a step, not four)
+@pytest.mark.parametrize("shape,dtype,causal", [
+    ((32, 12, 512, 64), BF16, False),
+    ((2, 12, 512, 64), F32, False),
+    ((8, 16, 2048, 64), BF16, True),
+    ((1, 8, 8192, 64), BF16, True)])
+def test_flash_attention_fwd_bwd(one_chip, for_the_chip, shape, dtype,
+                                 causal):
+    from incubator_mxnet_tpu.ops import nn_ops
+    q = _spec(one_chip, shape, dtype)
+    attend = functools.partial(nn_ops.dot_product_attention.fn, causal=causal)
+    text = _compile(_fwd_bwd(attend, 3), q, q, q)
+    assert text.count("tpu_custom_call") == 2
+    # neither pass holds a (T, S) tensor outside the kernels
+    assert f"{shape[2]},{shape[2]}]" not in text
 
 
 # ResNet-50's four stages at batch 256: (pixels a side, mid width, out width)
