@@ -134,7 +134,9 @@ def policy_scope(policy):
 # ---------------------------------------------------------------------------
 
 _KEEP_FP32_SUFFIXES = ("gamma", "beta", "running_mean", "running_var",
-                       "moving_mean", "moving_var")
+                       "moving_mean", "moving_var",
+                       # a router's selection bias and counters (aux state)
+                       "score_bias", "moe_stats")
 
 
 def convert_block(block, target_dtype="bfloat16", target_dtype_ops=None,
@@ -142,7 +144,7 @@ def convert_block(block, target_dtype="bfloat16", target_dtype_ops=None,
     """Convert a Block to mixed precision (reference convert_hybrid_block).
 
     Casts the block's parameters to ``target_dtype`` (norm-layer
-    scale/offset and moving statistics stay fp32) and attaches a
+    scale/offset, moving statistics and a router's aux state stay fp32) and attaches a
     ``CastPolicy`` built from the amp lists — honored per-op on every
     forward through the block, so ``fp32_ops=['softmax']`` really does
     run softmax in fp32 on bf16 activations.

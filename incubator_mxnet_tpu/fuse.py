@@ -185,10 +185,13 @@ class FusedTrainStep:
             with jax.named_scope("forward"):
                 out, updates = apply({**params, **aux}, x, training=True,
                                      key=key, with_updates=True)
-                if isinstance(out, tuple):
-                    out = out[0]
+                # a net's first output is what a loss sees, unless the loss
+                # says it weighs all of them (main and extra heads)
+                outs = out if isinstance(out, tuple) else (out,)
+                if not getattr(loss_block, "takes_all_outputs", False):
+                    outs = outs[:1]
                 with jax.named_scope("loss"):
-                    loss = loss_block(NDArray(out), NDArray(y))
+                    loss = loss_block(*map(NDArray, outs), NDArray(y))
                 return jnp.mean(loss.data), updates
 
         if self._remat:

@@ -7,6 +7,7 @@ from .block import HybridBlock
 
 __all__ = ["Loss", "L2Loss", "L1Loss", "SigmoidBinaryCrossEntropyLoss",
            "SigmoidBCELoss", "SoftmaxCrossEntropyLoss", "SoftmaxCELoss",
+           "WeightedHeadsSoftmaxCELoss",
            "KLDivLoss", "HuberLoss", "HingeLoss", "SquaredHingeLoss",
            "LogisticLoss", "TripletLoss", "CosineEmbeddingLoss", "CTCLoss",
            "PoissonNLLLoss", "SDMLLoss"]
@@ -121,6 +122,37 @@ class SoftmaxCrossEntropyLoss(Loss):
 
 
 SoftmaxCELoss = SoftmaxCrossEntropyLoss
+
+
+class WeightedHeadsSoftmaxCELoss(Loss):
+    """Softmax cross-entropy of several heads over one label tensor, each
+    head with its weight: ``Σ_h weights[h] · CE(preds[h], label[:, h])``,
+    as a model with a multi-token-prediction head trains (main head on the
+    next token, the extra head on the one after, weight lambda).
+
+    ``forward(*preds, label)``: every ``preds[h]`` is ``(B, T, classes)``
+    and ``label`` ``(B, heads, T)``; returns the loss of each sequence,
+    ``(B,)``.  ``takes_all_outputs`` tells ``fuse.FusedTrainStep`` to hand
+    over every output of the net, not only the first."""
+
+    takes_all_outputs = True
+
+    def __init__(self, weights=(1.0, 0.3), batch_axis=0, **kwargs):
+        super().__init__(None, batch_axis, **kwargs)
+        self._weights = tuple(weights)
+
+    def forward(self, *args):
+        *preds, label = args
+        assert len(preds) == len(self._weights)
+        total = None
+        for h, (pred, weight) in enumerate(zip(preds, self._weights)):
+            rows = invoke("softmax_xent",
+                          pred.reshape((-1, pred.shape[-1])),
+                          label[:, h].reshape((-1,)))
+            part = invoke("mean", rows.reshape(pred.shape[:2]).astype(
+                "float32"), axis=1) * weight
+            total = part if total is None else total + part
+        return total
 
 
 class KLDivLoss(Loss):

@@ -10,3 +10,4 @@ from .conv_layers import (
     GlobalMaxPool1D, GlobalMaxPool2D, GlobalMaxPool3D,
     GlobalAvgPool1D, GlobalAvgPool2D, GlobalAvgPool3D, ReflectionPad2D,
 )
+from .transformer_layers import RMSNorm, SwiGLU, RoutedFFN

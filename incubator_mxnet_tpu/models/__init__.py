@@ -4,9 +4,13 @@
 (dp/pp/tp/sp/ep) sharding support; drives ``__graft_entry__.dryrun_multichip``.
 ``lstm_lm`` — LSTM language model (BASELINE config 5, reference example/rnn).
 ``bert`` — BERT-style encoder (BASELINE config 3, gluon-nlp lineage).
+``mla_moe`` — causal decoder with latent attention (MLA), routed
+feed-forward layers of which this chip holds a share, and a multi-token-
+prediction module (the DeepSeek-V3 family's layer equations).
 Vision models live in ``gluon.model_zoo.vision`` (reference layout).
 """
 from . import transformer
 from .transformer import TransformerLM, TransformerConfig
 from .lstm_lm import LSTMLanguageModel
 from .bert import BERTEncoder, BERTModel
+from .mla_moe import MLAMoEDecoder

@@ -635,9 +635,12 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref,
 class _AttnPlan:
     """Block shapes of one attention call over (B·H, D, T) operands."""
 
-    def __init__(self, qt, kt):
+    def __init__(self, qt, kt, vt):
         self.bh, self.d, self.tq = qt.shape
         self.tk = kt.shape[2]
+        # v, the output and their gradients have a width of their own
+        # (latent attention: 192 in q and k, 128 in v)
+        self.dv = vt.shape[1]
         self.bq, self.tqp = _attn_block(self.tq, _ATTN_BQ)
         self.bk, self.tkp = _attn_block(self.tk, _ATTN_BK)
         self.n_q, self.n_kv = self.tqp // self.bq, self.tkp // self.bk
@@ -649,8 +652,8 @@ class _AttnPlan:
         self.hb = max(n for n in range(1, max(1, most) + 1)
                       if self.bh % n == 0)
 
-    def spec(self, t_block, index):
-        return pl.BlockSpec((self.hb, self.d, t_block), index,
+    def spec(self, t_block, index, d=None):
+        return pl.BlockSpec((self.hb, d or self.d, t_block), index,
                             memory_space=pltpu.VMEM)
 
     def params(self, *semantics):
@@ -668,7 +671,7 @@ def _pad_t(xt, tp):
 def _flash_fwd(qt, kt, vt, sm_scale, causal):
     """(B·H, D, T) operands → the output (B·H, D, Tq) and the rows'
     log-sum-exp (B·H, 1, padded Tq) float32."""
-    pn = _AttnPlan(qt, kt)
+    pn = _AttnPlan(qt, kt, vt)
     bq, bk = pn.bq, pn.bk
     if causal:
         # dead key blocks re-use the last live one: no DMA for them
@@ -677,21 +680,22 @@ def _flash_fwd(qt, kt, vt, sm_scale, causal):
     else:
         kv_index = lambda g, i, j: (g, 0, j)
     q_spec = pn.spec(bq, lambda g, i, j: (g, 0, i))
-    kv_spec = pn.spec(bk, kv_index)
+    o_spec = pn.spec(bq, lambda g, i, j: (g, 0, i), pn.dv)
+    k_spec, v_spec = pn.spec(bk, kv_index), pn.spec(bk, kv_index, pn.dv)
     lse_spec = pl.BlockSpec((pn.hb, 1, bq), lambda g, i, j: (g, 0, i),
                             memory_space=pltpu.VMEM)
     scratch = [] if pn.n_kv == 1 else [
         pltpu.VMEM((pn.hb, 1, bq), jnp.float32),
         pltpu.VMEM((pn.hb, 1, bq), jnp.float32),
-        pltpu.VMEM((pn.hb, pn.d, bq), jnp.float32)]
+        pltpu.VMEM((pn.hb, pn.dv, bq), jnp.float32)]
     ot, lse = pl.pallas_call(
         functools.partial(_flash_fwd_kernel, scale=sm_scale, causal=causal,
                           t_kv=pn.tk, n_kv=pn.n_kv),
-        out_shape=(jax.ShapeDtypeStruct((pn.bh, pn.d, pn.tqp), qt.dtype),
+        out_shape=(jax.ShapeDtypeStruct((pn.bh, pn.dv, pn.tqp), qt.dtype),
                    jax.ShapeDtypeStruct((pn.bh, 1, pn.tqp), jnp.float32)),
         grid=(pn.bh // pn.hb, pn.n_q, pn.n_kv),
-        in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=(q_spec, lse_spec),
+        in_specs=[q_spec, k_spec, v_spec],
+        out_specs=(o_spec, lse_spec),
         scratch_shapes=scratch,
         interpret=interpret_mode(),
         compiler_params=pn.params("parallel", "parallel", "arbitrary"),
@@ -701,7 +705,7 @@ def _flash_fwd(qt, kt, vt, sm_scale, causal):
 
 
 def _flash_bwd(qt, kt, vt, ot, lse, do_t, sm_scale, causal):
-    pn = _AttnPlan(qt, kt)
+    pn = _AttnPlan(qt, kt, vt)
     bq, bk = pn.bq, pn.bk
     if causal and pn.n_q > 1 and pn.n_kv > 1:
         # dead q blocks re-use the first live one: no DMA for them
@@ -709,7 +713,9 @@ def _flash_bwd(qt, kt, vt, ot, lse, do_t, sm_scale, causal):
     else:
         q_of = lambda j, i: i
     q_spec = pn.spec(bq, lambda g, j, i: (g, 0, q_of(j, i)))
-    kv_spec = pn.spec(bk, lambda g, j, i: (g, 0, j))
+    o_spec = pn.spec(bq, lambda g, j, i: (g, 0, q_of(j, i)), pn.dv)
+    k_spec = pn.spec(bk, lambda g, j, i: (g, 0, j))
+    v_spec = pn.spec(bk, lambda g, j, i: (g, 0, j), pn.dv)
     lse_spec = pl.BlockSpec((pn.hb, 1, bq),
                             lambda g, j, i: (g, 0, q_of(j, i)),
                             memory_space=pltpu.VMEM)
@@ -718,16 +724,17 @@ def _flash_bwd(qt, kt, vt, ot, lse, do_t, sm_scale, causal):
     if pn.n_kv > 1:
         scratch.append(pltpu.VMEM((pn.hb, pn.d, pn.tqp), jnp.float32))
     if pn.n_q > 1:
-        scratch += [pltpu.VMEM((pn.hb, pn.d, bk), jnp.float32)] * 2
+        scratch += [pltpu.VMEM((pn.hb, pn.d, bk), jnp.float32),
+                    pltpu.VMEM((pn.hb, pn.dv, bk), jnp.float32)]
     dq_t, dk_t, dv_t = pl.pallas_call(
         functools.partial(_flash_bwd_kernel, scale=sm_scale, causal=causal,
                           t_kv=pn.tk, n_q=pn.n_q, n_kv=pn.n_kv),
         out_shape=(jax.ShapeDtypeStruct((pn.bh, pn.d, pn.tqp), qt.dtype),
                    jax.ShapeDtypeStruct((pn.bh, pn.d, pn.tkp), kt.dtype),
-                   jax.ShapeDtypeStruct((pn.bh, pn.d, pn.tkp), vt.dtype)),
+                   jax.ShapeDtypeStruct((pn.bh, pn.dv, pn.tkp), vt.dtype)),
         grid=(pn.bh // pn.hb, pn.n_kv, pn.n_q),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, lse_spec, q_spec],
-        out_specs=(dq_spec, kv_spec, kv_spec),
+        in_specs=[q_spec, k_spec, v_spec, o_spec, lse_spec, o_spec],
+        out_specs=(dq_spec, k_spec, v_spec),
         scratch_shapes=scratch,
         interpret=interpret_mode(),
         compiler_params=pn.params("parallel", "arbitrary", "arbitrary"),
@@ -762,6 +769,9 @@ def flash_attention(q, k, v, sm_scale=None, causal=False):
     Pallas kernels, forward and backward: scores, probabilities and
     their gradients live in VMEM one block at a time, and what the
     backward pass keeps is q, k, v, the output and one float32 a row.
+    ``v`` (and with it the output) may have a width of its own, as
+    latent attention's has (192 in q and k, 128 in v): no operand is
+    padded to another's width.
     Inside, heads are (D, T): ``q``, ``k``, ``v`` are transposed on the
     way in and the results on the way out, which XLA folds into the
     transposes that make (B, H, T, D) out of a packed projection.

@@ -133,6 +133,48 @@ def test_flash_attention_fwd_bwd(one_chip, for_the_chip, shape, dtype,
     assert f"{shape[2]},{shape[2]}]" not in text
 
 
+def test_flash_attention_with_a_narrower_v(one_chip, for_the_chip):
+    """Latent attention at the published widths and the benchmark's
+    length: 192 wide in q and k (128 + 64 rotary), 128 in v, causal, 4,096
+    keys (several key blocks with the online rescale; the backward holds
+    one head's whole dq, 192 x 4,096, in VMEM)."""
+    from incubator_mxnet_tpu.ops import nn_ops
+    qk = _spec(one_chip, (2, 32, 4096, 192), BF16)
+    v = _spec(one_chip, (2, 32, 4096, 128), BF16)
+    attend = functools.partial(nn_ops.dot_product_attention.fn, causal=True)
+    text = _compile(_fwd_bwd(attend, 3), qk, qk, v)
+    assert text.count("tpu_custom_call") == 2
+    assert "4096,4096]" not in text
+    # no operand is padded to another's width: nothing 192 wide but q, k
+    # and their gradients reaches a kernel
+    assert "bf16[64,128,4096]" in text and "bf16[64,192,4096]" in text
+
+
+def test_moe_ffn_fwd_bwd(one_chip, for_the_chip):
+    """The routed layer at the published widths and the benchmark's
+    traffic: 8,192 tokens, top-8 over 256 experts of which 16 are held,
+    hidden 2,048, expert width 768: two forward grouped matmuls, and in
+    the backward pass the first again, two for the rows' gradients and two
+    for the weights'."""
+    from incubator_mxnet_tpu.ops import moe_ops
+    s = functools.partial(_spec, one_chip)
+    ffn = functools.partial(moe_ops.moe_ffn.fn, n_experts=256, first=0,
+                            capacity_factor=1.5)
+
+    def run(x, gates, w_in, w_out, idx):
+        return _fwd_bwd(lambda *d: ffn(d[0], idx, *d[1:])[0], 4)(
+            x, gates, w_in, w_out)
+
+    text = _compile(run, s((8192, 2048), BF16), s((8192, 8), F32),
+                    s((16, 2048, 1536), BF16), s((16, 768, 2048), BF16),
+                    s((8192, 8), I32))
+    # seven in the pass every step takes, seven in the body of the loop
+    # that runs only when rows outgrow the buffer
+    assert text.count("tpu_custom_call") == 14
+    # no (tokens, experts, capacity) one-hot tensor
+    assert "8192,16," not in text and "8192,256," not in text
+
+
 # ResNet-50's four stages at batch 256: (pixels a side, mid width, out width)
 STAGES = [(56, 64, 256), (28, 128, 512), (14, 256, 1024), (7, 512, 2048)]
 
