@@ -42,7 +42,8 @@ from .. import profiler as _profiler
 from ..locks import named_lock
 
 __all__ = ["fused_softmax", "fused_layer_norm", "flash_attention",
-           "dispatch", "kernel_name", "kernel_routes", "interpret_mode",
+           "dispatch", "kernel_name", "kernel_routes", "attention_plans",
+           "interpret_mode",
            "gspmd_trace", "fused_softmax_xent", "fused_rms_norm"]
 
 _NEG_INF = -1e30
@@ -435,6 +436,18 @@ fused_layer_norm.defvjp(_fused_ln_fwd, _fused_ln_bwd)
 # the online rescale).  No length was found below which the kernels
 # lose: at (32, 12, 128, 64) both sides sit on the ~0.25 ms floor of one
 # dispatch, at (64, 12, 256, 64) 1.75 against 2.59.
+# A long causal call wants another schedule than that one-block call
+# (_AttnPlan).  One layer at (2, 32, 4096, 192 / 128) causal bfloat16, the
+# forward and the backward kernel's call alone, ms (my chip runs, PR 31):
+# one head a step on the 8 x 8 grid of every block pair (the parent)
+# 4.81 + 7.29; a grid over the 36 live pairs alone 4.14 + 7.17 (a dead
+# step cost ~0.37 us forward, under 0.1 us backward); heads a step for each
+# kernel apart, 4 forward and 2 backward, 3.51 + 6.78 (2 forward: 3.65;
+# 8: 3.30; 2 heads both ways on the parent's grid: 4.25 + 6.87); the causal
+# select on the 8 diagonal pairs only 3.41 + 6.76; the next head's scores
+# issued before a head's softmax 3.15 + 6.72 (the same order in the
+# backward bought 0.03: left out); 4 heads backward under the 38 MiB that
+# takes 3.15 + 6.56.  In the step: 3.12 + 6.53 (4.75 + 7.11 before).
 _ATTN_BQ = 512
 _ATTN_BK = 512
 _ATTN_SCORES = 4 * 512 * 512
@@ -463,11 +476,6 @@ def _attn_dot(a, b, dims):
                    else None))
 
 
-def _when(cond):
-    """``pl.when``, or the body as it stands where ``cond`` is plain True."""
-    return (lambda body: body()) if cond is True else pl.when(cond)
-
-
 # Both kernels work on TRANSPOSED heads and scores: q, k, v, the output
 # and every gradient are (D, T) a head, a block of scores is (keys,
 # queries).  A row's maximum and sum then run down the sublanes —
@@ -490,36 +498,86 @@ def _heads_back(xt, lead):
     return jnp.swapaxes(xt.reshape(*lead, *xt.shape[1:]), 2, 3)
 
 
-def _attn_scores_t(q_ref, k_ref, h, qi, kb, scale, causal, t_kv):
-    """The scaled (D, queries) block (the scale folded into it, in its
-    own dtype) and the float32 scores of block (qi, kb), (keys, queries):
-    those of keys past the unpadded length and, if causal, after their
-    query at ``_NEG_INF``.  A dense block costs no select."""
+def _attn_scores_t(q_ref, k_ref, h, scale):
+    """The scaled (D, queries) block of head ``h`` (the scale folded into
+    it, in its own dtype) and its float32 scores against the key block,
+    (keys, queries), unmasked."""
     qt = (q_ref[h] * scale).astype(q_ref.dtype)
-    st = _attn_dot(k_ref[h], qt, _TN)
+    return qt, _attn_dot(k_ref[h], qt, _TN)
+
+
+def _attn_mask(st, qi, kb, causal, t_kv):
+    """The scores of block (qi, kb) with those of keys past the unpadded
+    length and, if causal, after their query at ``_NEG_INF``."""
     shape = bk, bq = st.shape
-    mask = None
-    if t_kv % bk or causal:
-        key = kb * bk + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-        mask = key < t_kv if t_kv % bk else None
+    key = kb * bk + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    mask = key < t_kv if t_kv % bk else None
     if causal:
         ahead = key <= qi * bq + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
         mask = ahead if mask is None else mask & ahead
-    return qt, (st if mask is None else jnp.where(mask, st, _NEG_INF))
+    return jnp.where(mask, st, _NEG_INF)
 
 
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
-                      scale, causal, t_kv, n_kv):
-    """Grid (head groups, q blocks, key blocks).  One key block: a plain
+def _attn_masked(qi, kb, bq, bk, causal, t_kv, n_q, n_kv):
+    """Whether block (qi, kb) holds a score to mask.  A dense call: where
+    the keys are padded (every block pays, or none).  A causal call of
+    one block: always.  A causal call of several blocks: a scalar of the
+    grid step, true where the block straddles the diagonal, and on the
+    last key block where that one is padded; every other live block is
+    dense and costs no select."""
+    if not causal:
+        return bool(t_kv % bk)
+    if n_q == n_kv == 1:
+        return True
+    masked = kb * bk + bk - 1 > qi * bq
+    return masked | (kb == n_kv - 1) if t_kv % bk else masked
+
+
+def _masked_or_dense(masked, step):
+    """``step(True)`` or ``step(False)`` by ``masked``, a bool of the
+    trace or a scalar of the grid step (then both bodies are compiled and
+    a step runs one)."""
+    if isinstance(masked, bool):
+        step(masked)
+    else:
+        pl.when(masked)(lambda: step(True))
+        pl.when(jnp.logical_not(masked))(lambda: step(False))
+
+
+def _attn_pair(tables, q_axis):
+    """(qi, kb) of this grid step: read from the live pairs' tables where
+    the grid runs over those (axis 1), else the two inner grid axes
+    (q blocks on ``q_axis``)."""
+    if tables:
+        qi_tab, kb_tab = tables
+        return qi_tab[pl.program_id(1)], kb_tab[pl.program_id(1)]
+    ids = None, pl.program_id(1), pl.program_id(2)
+    return ids[q_axis], ids[3 - q_axis]
+
+
+def _attn_last_kb(qi, bq, bk, n_kv, live):
+    """The last key block q block ``qi`` meets: on the live grid the one
+    its diagonal crosses."""
+    if not live:
+        return n_kv - 1
+    return jnp.minimum(n_kv - 1, ((qi + 1) * bq - 1) // bk)
+
+
+def _flash_fwd_kernel(*refs, scale, causal, t_kv, n_q, n_kv, live):
+    """Grid (head groups, q blocks, key blocks), or (head groups, live
+    pairs) ordered by q block then key block.  One key block: a plain
     softmax in one pass.  Several: the online rescale, with the running
-    maximum, sum and accumulator in scratch across the key axis."""
+    maximum, sum and accumulator in scratch across a q block's keys."""
+    tables, refs = (refs[:2], refs[2:]) if live else ((), refs)
+    q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch = refs
     hb = q_ref.shape[0]
     bq, bk = q_ref.shape[2], k_ref.shape[2]
-    qi, kb = pl.program_id(1), pl.program_id(2)
+    qi, kb = _attn_pair(tables, 1)
+    masked = _attn_masked(qi, kb, bq, bk, causal, t_kv, n_q, n_kv)
 
-    def scores_t(h):
-        return _attn_scores_t(q_ref, k_ref, h, qi, kb, scale, causal,
-                              t_kv)[1]
+    def scores_t(h, masked):
+        st = _attn_scores_t(q_ref, k_ref, h, scale)[1]
+        return _attn_mask(st, qi, kb, causal, t_kv) if masked else st
 
     def pv_t(pt, h):                        # (D, queries)
         return _attn_dot(v_ref[h], pt.astype(v_ref.dtype), _NN)
@@ -529,11 +587,15 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
         lse_ref[h] = m + jnp.log(l)
 
     if n_kv == 1:
-        for h in range(hb):
-            st = scores_t(h)
-            m = jnp.max(st, axis=0, keepdims=True)
-            pt = jnp.exp(st - m)
-            finish(h, pv_t(pt, h), m, jnp.sum(pt, axis=0, keepdims=True))
+        def one_pass(masked):
+            for h in range(hb):
+                st = scores_t(h, masked)
+                m = jnp.max(st, axis=0, keepdims=True)
+                pt = jnp.exp(st - m)
+                finish(h, pv_t(pt, h), m,
+                       jnp.sum(pt, axis=0, keepdims=True))
+
+        _masked_or_dense(masked, one_pass)
         return
 
     m_scr, l_scr, acc_scr = scratch
@@ -544,11 +606,12 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
         l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    # a causal block wholly above the diagonal holds no score
-    @_when(kb * bk < (qi + 1) * bq if causal else True)
-    def _():
+    def rescale(masked):
+        st = scores_t(0, masked)
         for h in range(hb):
-            st = scores_t(h)
+            # the next head's scores are issued before this head's
+            # softmax: a dot for the MXU beside the VPU's work
+            ahead = scores_t(h + 1, masked) if h + 1 < hb else None
             m_prev = m_scr[h]
             m = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
             alpha = jnp.exp(m_prev - m)
@@ -556,44 +619,49 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
             l_scr[h] = alpha * l_scr[h] + jnp.sum(pt, axis=0, keepdims=True)
             acc_scr[h] = alpha * acc_scr[h] + pv_t(pt, h)
             m_scr[h] = m
+            st = ahead
 
-    @pl.when(kb == n_kv - 1)
+    _masked_or_dense(masked, rescale)
+
+    @pl.when(kb == _attn_last_kb(qi, bq, bk, n_kv, live))
     def _():
         for h in range(hb):
             finish(h, acc_scr[h], m_scr[h], l_scr[h])
 
 
-def _flash_bwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref,
-                      dq_ref, dk_ref, dv_ref, *scratch,
-                      scale, causal, t_kv, n_q, n_kv):
-    """Grid (head groups, key blocks, q blocks): the probabilities of a
+def _flash_bwd_kernel(*refs, scale, causal, t_kv, n_q, n_kv, live):
+    """Grid (head groups, key blocks, q blocks), or (head groups, live
+    pairs) ordered by key block then q block: the probabilities of a
     block are recomputed from the saved row log-sum-exp; dk and dv add up
-    over the inner q axis, dq over the key axis in a block that holds the
-    head's whole dq (resident in VMEM until the head group changes)."""
+    over a key block's q blocks, dq over the key blocks in a block that
+    holds the head's whole dq (resident in VMEM until the head group
+    changes)."""
+    tables, refs = (refs[:2], refs[2:]) if live else ((), refs)
+    (q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref,
+     dq_ref, dk_ref, dv_ref, *scratch) = refs
     hb = q_ref.shape[0]
     bq, bk = q_ref.shape[2], k_ref.shape[2]
-    kb, qi = pl.program_id(1), pl.program_id(2)
+    qi, kb = _attn_pair(tables, 2)
+    masked = _attn_masked(qi, kb, bq, bk, causal, t_kv, n_q, n_kv)
     dt = q_ref.dtype
     cols = pl.ds(pl.multiple_of(qi * bq, bq), bq)
-    scratch = list(scratch)
     dq_acc = scratch.pop(0) if n_kv > 1 else None
     dk_acc, dv_acc = scratch if n_q > 1 else (None, None)
+    # the first q block a key block meets: on the live grid the one the
+    # diagonal crosses it in
+    first_qi = (kb * bk) // bq if live else 0
 
     if n_q > 1:
-        @pl.when(qi == 0)
+        @pl.when(qi == first_qi)
         def _():
             dk_acc[...] = jnp.zeros(dk_acc.shape, jnp.float32)
             dv_acc[...] = jnp.zeros(dv_acc.shape, jnp.float32)
 
-    # a causal block wholly above the diagonal has no gradient; skipped
-    # only where both sums run over several blocks (kb == 0 never is)
-    skip = causal and n_q > 1 and n_kv > 1
-
-    @_when(kb * bk < (qi + 1) * bq if skip else True)
-    def _():
+    def block(masked):
         for h in range(hb):
-            qt, st = _attn_scores_t(q_ref, k_ref, h, qi, kb, scale, causal,
-                                    t_kv)
+            qt, st = _attn_scores_t(q_ref, k_ref, h, scale)
+            if masked:
+                st = _attn_mask(st, qi, kb, causal, t_kv)
             do_t = do_ref[h]
             pt = jnp.exp(st - lse_ref[h])
             delta = jnp.sum(do_t.astype(jnp.float32)
@@ -620,128 +688,239 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref,
                 def _():
                     dq_acc[h, :, cols] += dq_t
 
+    _masked_or_dense(masked, block)
+
     if n_q > 1:
         @pl.when(qi == n_q - 1)
         def _():
             dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
             dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
     if n_kv > 1:
-        @pl.when(kb == n_kv - 1)
+        @pl.when(kb == _attn_last_kb(qi, bq, bk, n_kv, live))
         def _():
             for h in range(hb):
                 dq_ref[h, :, cols] = dq_acc[h, :, cols].astype(dq_ref.dtype)
 
 
-class _AttnPlan:
-    """Block shapes of one attention call over (B·H, D, T) operands."""
+# The VMEM a call asks Mosaic for: the rows' limit as a rule, more (of a
+# v5e core's 128 MiB) where the heads a step that ``_ATTN_SCORES`` allows
+# need it.  A kernel's bill is its pipelined blocks twice (Mosaic double
+# buffers them) and its scratch once, a head, and Mosaic's own scratch
+# beside them: 1.3-1.5 MiB whatever the heads (described compiles, PR 31:
+# the backward at (64, 192 / 128, 4,096) needs 10.29 / 19.05 / 36.55 MiB
+# with 1 / 2 / 4 heads a step for a bill of 8.78 MiB a head).
+_ATTN_VMEM = 4 * _VMEM_BUDGET
+_ATTN_VMEM_MOST = 64 * 1024 * 1024
+_ATTN_VMEM_OWN = 2 * 1024 * 1024
 
-    def __init__(self, qt, kt, vt):
+
+class _AttnPlan:
+    """The schedule of one attention call over (B·H, D, T) operands:
+    blocks, which (q block, key block) pairs get a grid step, and the
+    heads a step takes in each kernel."""
+
+    def __init__(self, qt, kt, vt, causal):
         self.bh, self.d, self.tq = qt.shape
-        self.tk = kt.shape[2]
         # v, the output and their gradients have a width of their own
         # (latent attention: 192 in q and k, 128 in v)
         self.dv = vt.shape[1]
-        self.bq, self.tqp = _attn_block(self.tq, _ATTN_BQ)
-        self.bk, self.tkp = _attn_block(self.tk, _ATTN_BK)
-        self.n_q, self.n_kv = self.tqp // self.bq, self.tkp // self.bk
-        # heads a step: what _ATTN_SCORES allows, and no more than keeps
-        # the backward's whole-head dq (a float32 sum and two output
-        # buffers) inside the VMEM budget
+        # under causal no query sees a key past the last query: those get
+        # no block (their dk and dv are zero)
+        self.tk = min(kt.shape[2], self.tq) if causal else kt.shape[2]
+        self.bq, self.tqp = bq, _ = _attn_block(self.tq, _ATTN_BQ)
+        self.bk, self.tkp = bk, _ = _attn_block(self.tk, _ATTN_BK)
+        self.n_q, self.n_kv = self.tqp // bq, self.tkp // bk
+        # the pairs that hold a score, by q block then key block; where
+        # some do not (causal, several blocks each way) the grid runs
+        # over these alone
+        self.pairs = [(i, j) for i in range(self.n_q)
+                      for j in range(self.n_kv)
+                      if not causal or j * bk < (i + 1) * bq]
+        self.live = len(self.pairs) < self.n_q * self.n_kv
+        self.diagonal = sum(causal and j * bk + bk - 1 > i * bq
+                            for i, j in self.pairs)
+        item = qt.dtype.itemsize
+        d, dv, tqp = self.d, self.dv, self.tqp
+        # a head's q-side and key-side blocks: (q, o, lse) and (k, v);
+        # the (1, block) float32 row statistics take eight sublanes
+        q_side = item * (d + dv) * bq + 32 * bq
+        k_side = item * (d + dv) * bk
+        self.hb_fwd, self.vmem_fwd = self._heads(
+            2 * (q_side + k_side)
+            + (4 * dv * bq + 64 * bq if self.n_kv > 1 else 0))
+        # the backward: do beside them, dk and dv out, the head's whole dq
+        # out and (over several key blocks) its float32 sum
+        self.hb_bwd, self.vmem_bwd = self._heads(
+            2 * (q_side + item * dv * bq + 2 * k_side + item * d * tqp)
+            + (4 * d * tqp if self.n_kv > 1 else 0)
+            + (4 * (d + dv) * bk if self.n_q > 1 else 0))
+
+    def _heads(self, per_head):
+        """Heads a step of a kernel whose blocks and scratch take
+        ``per_head`` bytes a head, and the VMEM limit it is compiled
+        with: as many heads as ``_ATTN_SCORES`` allows, that divide B·H
+        and fit ``_ATTN_VMEM_MOST``; one where not even one fits (then
+        Mosaic refuses the call, beyond ~120k keys at width 64)."""
         most = min(_ATTN_SCORES // (self.bq * self.bk),
-                   _VMEM_BUDGET // (self.d * self.tqp * 8))
-        self.hb = max(n for n in range(1, max(1, most) + 1)
-                      if self.bh % n == 0)
+                   (_ATTN_VMEM_MOST - _ATTN_VMEM_OWN) // per_head)
+        heads = max(n for n in range(1, max(1, most) + 1)
+                    if self.bh % n == 0)
+        return heads, min(_ATTN_VMEM_MOST, max(
+            _ATTN_VMEM, _round_up(heads * per_head + _ATTN_VMEM_OWN,
+                                  1024 * 1024)))
 
-    def spec(self, t_block, index, d=None):
-        return pl.BlockSpec((self.hb, d or self.d, t_block), index,
-                            memory_space=pltpu.VMEM)
+    def keys(self, xt):
+        """The keys (or values) some query sees, in whole blocks."""
+        return _fit_t(_fit_t(xt, self.tk), self.tkp)
 
-    def params(self, *semantics):
-        return pltpu.CompilerParams(vmem_limit_bytes=4 * _VMEM_BUDGET,
-                                    dimension_semantics=semantics)
+    def stats(self):
+        groups_fwd, groups_bwd = self.bh // self.hb_fwd, self.bh // self.hb_bwd
+        return {"heads_fwd": self.hb_fwd, "heads_bwd": self.hb_bwd,
+                "grid_steps_fwd": groups_fwd * len(self.pairs),
+                "grid_steps_bwd": groups_bwd * len(self.pairs),
+                "pairs": self.n_q * self.n_kv,
+                "live_pairs": len(self.pairs),
+                "diagonal_pairs": self.diagonal}
+
+    def call(self, kernel, name, hb, vmem, q_axis, specs, out_shape, scratch,
+             *operands):
+        """The ``pallas_call`` of one kernel at ``hb`` heads a step under
+        a VMEM limit of ``vmem`` bytes.
+        ``specs(q_map, k_map)`` gives ``(in_specs, out_specs)`` from the
+        index maps of a q-side and a key-side block; a rectangular grid
+        has its q blocks on ``q_axis``, the live grid is ordered so that
+        axis runs inside the other."""
+        if self.live:
+            order = sorted(self.pairs, key=lambda p: p[::-1]) \
+                if q_axis == 2 else self.pairs
+            tables = [jnp.asarray(t, jnp.int32) for t in zip(*order)]
+            grid = (self.bh // hb, len(order))
+            semantics = "parallel", "arbitrary"
+            q_map = lambda g, p, qi, kb: (g, 0, qi[p])
+            k_map = lambda g, p, qi, kb: (g, 0, kb[p])
+        else:
+            tables = []
+            inner = (self.n_q, self.n_kv)
+            grid = (self.bh // hb, *(inner if q_axis == 1 else inner[::-1]))
+            # q blocks are independent; what adds up over key blocks, or in
+            # the backward's resident dq over both, is not
+            semantics = ("parallel", "parallel" if q_axis == 1
+                         else "arbitrary", "arbitrary")
+            q_map = lambda *ids: (ids[0], 0, ids[q_axis])
+            k_map = lambda *ids: (ids[0], 0, ids[3 - q_axis])
+        in_specs, out_specs = specs(q_map, k_map)
+        return pl.pallas_call(
+            functools.partial(kernel, n_q=self.n_q, n_kv=self.n_kv,
+                              t_kv=self.tk, live=self.live),
+            out_shape=out_shape,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=len(tables), grid=grid,
+                in_specs=in_specs, out_specs=out_specs,
+                scratch_shapes=scratch),
+            interpret=interpret_mode(),
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=vmem,
+                dimension_semantics=semantics),
+            name=name,
+        )(*tables, *operands)
 
 
-def _pad_t(xt, tp):
-    """Pad the last (sequence) axis to whole blocks; no copy at a length
-    that is whole blocks already."""
-    t = xt.shape[-1]
-    return xt if t == tp else jnp.pad(xt, ((0, 0), (0, 0), (0, tp - t)))
+def _attn_spec(hb, d, t_block, index):
+    return pl.BlockSpec((hb, d, t_block), index, memory_space=pltpu.VMEM)
+
+
+_plans = {}
+_plans_lock = named_lock("ops.attention_plans")
+
+
+def attention_plans(reset=False):
+    """``{signature: plan}`` of every attention call the kernel pair was
+    traced for so far: ``heads_fwd`` / ``heads_bwd`` a grid step,
+    ``grid_steps_fwd`` / ``grid_steps_bwd`` of a whole call, and of one
+    head group's ``pairs`` of (q block, key block) the ``live_pairs`` that
+    get a step and the ``diagonal_pairs`` that pay for the causal mask.
+    Written when the forward kernel's call is traced, so it counts
+    signatures and not calls (a jitted op is traced once for equal
+    shapes).  The ``attention_plans`` provider of ``profiler.dumps()``."""
+    with _plans_lock:
+        out = {sig: dict(plan) for sig, plan in sorted(_plans.items())}
+        if reset:
+            _plans.clear()
+    return out
+
+
+_profiler.register_stats_provider("attention_plans", attention_plans)
+
+
+def _fit_t(xt, t):
+    """The last (sequence) axis cut or zero-padded to ``t`` positions; no
+    copy at a length that is ``t`` already."""
+    have = xt.shape[2]
+    if have < t:
+        return jnp.pad(xt, ((0, 0), (0, 0), (0, t - have)))
+    return xt if have == t else xt[:, :, :t]
 
 
 def _flash_fwd(qt, kt, vt, sm_scale, causal):
     """(B·H, D, T) operands → the output (B·H, D, Tq) and the rows'
     log-sum-exp (B·H, 1, padded Tq) float32."""
-    pn = _AttnPlan(qt, kt, vt)
-    bq, bk = pn.bq, pn.bk
-    if causal:
-        # dead key blocks re-use the last live one: no DMA for them
-        kv_index = lambda g, i, j: (
-            g, 0, jnp.minimum(j, ((i + 1) * bq - 1) // bk))
-    else:
-        kv_index = lambda g, i, j: (g, 0, j)
-    q_spec = pn.spec(bq, lambda g, i, j: (g, 0, i))
-    o_spec = pn.spec(bq, lambda g, i, j: (g, 0, i), pn.dv)
-    k_spec, v_spec = pn.spec(bk, kv_index), pn.spec(bk, kv_index, pn.dv)
-    lse_spec = pl.BlockSpec((pn.hb, 1, bq), lambda g, i, j: (g, 0, i),
-                            memory_space=pltpu.VMEM)
+    pn = _AttnPlan(qt, kt, vt, causal)
+    hb, bq, bk = pn.hb_fwd, pn.bq, pn.bk
+    with _plans_lock:
+        _plans[f"bh{pn.bh} d{pn.d}/{pn.dv} t{pn.tq}x{kt.shape[2]} "
+               f"{'causal' if causal else 'dense'} {qt.dtype}"] = pn.stats()
+
+    def specs(q_map, k_map):
+        return ([_attn_spec(hb, pn.d, bq, q_map),
+                 _attn_spec(hb, pn.d, bk, k_map),
+                 _attn_spec(hb, pn.dv, bk, k_map)],
+                (_attn_spec(hb, pn.dv, bq, q_map),
+                 _attn_spec(hb, 1, bq, q_map)))
+
     scratch = [] if pn.n_kv == 1 else [
-        pltpu.VMEM((pn.hb, 1, bq), jnp.float32),
-        pltpu.VMEM((pn.hb, 1, bq), jnp.float32),
-        pltpu.VMEM((pn.hb, pn.dv, bq), jnp.float32)]
-    ot, lse = pl.pallas_call(
-        functools.partial(_flash_fwd_kernel, scale=sm_scale, causal=causal,
-                          t_kv=pn.tk, n_kv=pn.n_kv),
-        out_shape=(jax.ShapeDtypeStruct((pn.bh, pn.dv, pn.tqp), qt.dtype),
-                   jax.ShapeDtypeStruct((pn.bh, 1, pn.tqp), jnp.float32)),
-        grid=(pn.bh // pn.hb, pn.n_q, pn.n_kv),
-        in_specs=[q_spec, k_spec, v_spec],
-        out_specs=(o_spec, lse_spec),
-        scratch_shapes=scratch,
-        interpret=interpret_mode(),
-        compiler_params=pn.params("parallel", "parallel", "arbitrary"),
-        name="flash_attention_fwd",
-    )(_pad_t(qt, pn.tqp), _pad_t(kt, pn.tkp), _pad_t(vt, pn.tkp))
-    return ot[:, :, :pn.tq], lse
+        pltpu.VMEM((hb, 1, bq), jnp.float32),
+        pltpu.VMEM((hb, 1, bq), jnp.float32),
+        pltpu.VMEM((hb, pn.dv, bq), jnp.float32)]
+    ot, lse = pn.call(
+        functools.partial(_flash_fwd_kernel, scale=sm_scale, causal=causal),
+        "flash_attention_fwd", hb, pn.vmem_fwd, 1, specs,
+        (jax.ShapeDtypeStruct((pn.bh, pn.dv, pn.tqp), qt.dtype),
+         jax.ShapeDtypeStruct((pn.bh, 1, pn.tqp), jnp.float32)),
+        scratch, _fit_t(qt, pn.tqp), pn.keys(kt), pn.keys(vt))
+    return _fit_t(ot, pn.tq), lse
 
 
 def _flash_bwd(qt, kt, vt, ot, lse, do_t, sm_scale, causal):
-    pn = _AttnPlan(qt, kt, vt)
-    bq, bk = pn.bq, pn.bk
-    if causal and pn.n_q > 1 and pn.n_kv > 1:
-        # dead q blocks re-use the first live one: no DMA for them
-        q_of = lambda j, i: jnp.maximum(i, (j * bk) // bq)
-    else:
-        q_of = lambda j, i: i
-    q_spec = pn.spec(bq, lambda g, j, i: (g, 0, q_of(j, i)))
-    o_spec = pn.spec(bq, lambda g, j, i: (g, 0, q_of(j, i)), pn.dv)
-    k_spec = pn.spec(bk, lambda g, j, i: (g, 0, j))
-    v_spec = pn.spec(bk, lambda g, j, i: (g, 0, j), pn.dv)
-    lse_spec = pl.BlockSpec((pn.hb, 1, bq),
-                            lambda g, j, i: (g, 0, q_of(j, i)),
-                            memory_space=pltpu.VMEM)
-    dq_spec = pn.spec(pn.tqp, lambda g, j, i: (g, 0, 0))
+    pn = _AttnPlan(qt, kt, vt, causal)
+    hb, bq, bk = pn.hb_bwd, pn.bq, pn.bk
+
+    def specs(q_map, k_map):
+        q_spec, o_spec = (_attn_spec(hb, d, bq, q_map)
+                          for d in (pn.d, pn.dv))
+        k_spec, v_spec = (_attn_spec(hb, d, bk, k_map)
+                          for d in (pn.d, pn.dv))
+        return ([q_spec, k_spec, v_spec, o_spec,
+                 _attn_spec(hb, 1, bq, q_map), o_spec],
+                (_attn_spec(hb, pn.d, pn.tqp, lambda g, *_: (g, 0, 0)),
+                 k_spec, v_spec))
+
     scratch = []
     if pn.n_kv > 1:
-        scratch.append(pltpu.VMEM((pn.hb, pn.d, pn.tqp), jnp.float32))
+        scratch.append(pltpu.VMEM((hb, pn.d, pn.tqp), jnp.float32))
     if pn.n_q > 1:
-        scratch += [pltpu.VMEM((pn.hb, pn.d, bk), jnp.float32),
-                    pltpu.VMEM((pn.hb, pn.dv, bk), jnp.float32)]
-    dq_t, dk_t, dv_t = pl.pallas_call(
-        functools.partial(_flash_bwd_kernel, scale=sm_scale, causal=causal,
-                          t_kv=pn.tk, n_q=pn.n_q, n_kv=pn.n_kv),
-        out_shape=(jax.ShapeDtypeStruct((pn.bh, pn.d, pn.tqp), qt.dtype),
-                   jax.ShapeDtypeStruct((pn.bh, pn.d, pn.tkp), kt.dtype),
-                   jax.ShapeDtypeStruct((pn.bh, pn.dv, pn.tkp), vt.dtype)),
-        grid=(pn.bh // pn.hb, pn.n_kv, pn.n_q),
-        in_specs=[q_spec, k_spec, v_spec, o_spec, lse_spec, o_spec],
-        out_specs=(dq_spec, k_spec, v_spec),
-        scratch_shapes=scratch,
-        interpret=interpret_mode(),
-        compiler_params=pn.params("parallel", "arbitrary", "arbitrary"),
-        name="flash_attention_bwd",
-    )(_pad_t(qt, pn.tqp), _pad_t(kt, pn.tkp), _pad_t(vt, pn.tkp),
-      _pad_t(ot, pn.tqp), lse, _pad_t(do_t, pn.tqp))
-    return dq_t[:, :, :pn.tq], dk_t[:, :, :pn.tk], dv_t[:, :, :pn.tk]
+        scratch += [pltpu.VMEM((hb, pn.d, bk), jnp.float32),
+                    pltpu.VMEM((hb, pn.dv, bk), jnp.float32)]
+    dq_t, dk_t, dv_t = pn.call(
+        functools.partial(_flash_bwd_kernel, scale=sm_scale, causal=causal),
+        "flash_attention_bwd", hb, pn.vmem_bwd, 2, specs,
+        (jax.ShapeDtypeStruct((pn.bh, pn.d, pn.tqp), qt.dtype),
+         jax.ShapeDtypeStruct((pn.bh, pn.d, pn.tkp), kt.dtype),
+         jax.ShapeDtypeStruct((pn.bh, pn.dv, pn.tkp), vt.dtype)),
+        scratch, _fit_t(qt, pn.tqp), pn.keys(kt), pn.keys(vt),
+        _fit_t(ot, pn.tqp), lse, _fit_t(do_t, pn.tqp))
+    # the keys no query sees get zeros
+    return (_fit_t(dq_t, pn.tq),
+            *(_fit_t(_fit_t(x, pn.tk), kt.shape[2]) for x in (dk_t, dv_t)))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))

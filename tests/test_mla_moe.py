@@ -165,6 +165,46 @@ def test_the_steps_program_is_the_same_for_two_seeds_and_two_routings(
     assert "[384,64]" in text_a
 
 
+def test_both_attention_kernels_of_the_step_sit_under_flash_attention(
+        config, monkeypatch):
+    """``mla_attention_roofline_pct`` sums the device time of the
+    instructions whose ``op_name`` holds the segment ``flash_attention``:
+    both ``pallas_call``s of every attention of the toy decoder's step
+    (1 dense + 2 routed layers and the MTP block: 4 each way) carry it,
+    under the names the trace's kernel table reads."""
+    monkeypatch.setenv("MXNET_USE_PALLAS", "1")     # interpreted kernels
+    built = _net(config, 13)
+    amp.convert_block(built["net"], "bfloat16")
+    step = make_fused_train_step(built["net"], built["loss"],
+                                 built["optimizer"],
+                                 dict(built["optimizer_params"]))
+    # shapes of its own: an op's jit keeps the route it was first traced with
+    x, y = model.make_batch(13, 0, 3, config, TRAFFIC)
+    pk.kernel_routes(reset=True)
+    pk.attention_plans(reset=True)
+    jaxpr = jax.make_jaxpr(step.step_fn)(step.params, step.aux,
+                                         step.opt_state, x, y, step._key)
+
+    def calls(jaxpr, prefix=""):
+        for eqn in jaxpr.eqns:
+            stack = f"{prefix}/{eqn.source_info.name_stack}"
+            if eqn.primitive.name == "pallas_call":
+                yield eqn.params["name"], stack.split("/")
+                continue
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from calls(sub, stack)
+
+    pair = [(name, stack) for name, stack in calls(jaxpr.jaxpr)
+            if name.startswith("flash_attention")]
+    assert sorted(name for name, _ in pair) == \
+        ["flash_attention_bwd"] * 4 + ["flash_attention_fwd"] * 4
+    assert all("flash_attention" in stack for _, stack in pair)
+    assert pk.kernel_routes()["flash_attention"] == {"kernel": 1}
+    # one signature, traced once: 32 positions are one block each way
+    (plan,) = pk.attention_plans().values()
+    assert (plan["pairs"], plan["live_pairs"]) == (1, 1)
+
+
 # ---------------------------------------------------- the routed layer alone
 
 def _layer_inputs(tokens=200, hidden=16, width=8, experts=8):

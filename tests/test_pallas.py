@@ -161,6 +161,99 @@ def test_flash_attention_online_rescale_and_head_groups(monkeypatch, blocks):
             onp.asarray(_attn_ref(q, k, v, causal)), rtol=2e-4, atol=2e-5)
 
 
+# The schedule of a causal call over several blocks each way, at reduced
+# block sizes: (bq, bk, heads _ATTN_SCORES allows, VMEM the plan may ask
+# for or None, b, h, tq, tk, d, dv) and what ``attention_plans`` must
+# read of it: (heads_fwd, heads_bwd, live pairs, all pairs, diagonal).
+LIVE = {
+    "4x4": ((128, 128, 4, None, 1, 4, 512, 512, 32, 32), (4, 4, 10, 16, 4)),
+    "3x3": ((128, 128, 2, None, 1, 2, 384, 384, 32, 32), (2, 2, 6, 9, 3)),
+    "more_q_blocks": ((128, 128, 4, None, 1, 4, 512, 256, 32, 32),
+                      (4, 4, 7, 8, 2)),
+    # keys past the last query are seen by no one: cut, their dk, dv zero
+    "more_key_blocks": ((128, 128, 4, None, 1, 4, 256, 512, 32, 32),
+                        (4, 4, 3, 4, 2)),
+    "ragged_last_key_block": ((128, 128, 4, None, 1, 4, 500, 500, 32, 32),
+                              (4, 4, 10, 16, 4)),
+    # the padded keys of the last key block sit below the diagonal
+    "ragged_below_diagonal": ((128, 128, 4, None, 1, 4, 512, 300, 32, 32),
+                              (4, 4, 9, 12, 3)),
+    # latent attention's 192 / 128 scaled down; six heads take three a step
+    "narrower_v": ((128, 128, 4, None, 2, 3, 512, 512, 48, 32),
+                   (3, 3, 10, 16, 4)),
+    # the backward's whole-head dq leaves it fewer heads than the forward
+    "heads_apart": ((128, 128, 4, 1 << 20, 1, 4, 512, 512, 48, 32),
+                    (4, 1, 10, 16, 4)),
+    "wide_q_blocks": ((256, 128, 2, None, 1, 2, 512, 512, 32, 32),
+                      (2, 2, 6, 8, 4)),
+    "wide_key_blocks": ((128, 256, 2, None, 1, 2, 512, 512, 32, 32),
+                        (2, 2, 6, 8, 4)),
+}
+
+
+@pytest.mark.parametrize("case", LIVE)
+def test_flash_attention_live_grid(monkeypatch, case):
+    """A causal call of several blocks each way runs a grid over the live
+    (q block, key block) pairs alone, masks only the blocks the diagonal
+    crosses (and a padded last key block), and takes heads a step for
+    each kernel apart: output, dq, dk, dv against the composition."""
+    (bq, bk, heads, vmem, b, h, tq, tk, d, dv), want = LIVE[case]
+    monkeypatch.setattr(pk, "_ATTN_BQ", bq)
+    monkeypatch.setattr(pk, "_ATTN_BK", bk)
+    monkeypatch.setattr(pk, "_ATTN_SCORES", heads * bq * bk)
+    if vmem:
+        monkeypatch.setattr(pk, "_ATTN_VMEM_MOST", vmem)
+        monkeypatch.setattr(pk, "_ATTN_VMEM_OWN", 0)
+    q, k = _rand(b, h, tq, d, seed=60) * 0.5, _rand(b, h, tk, d, seed=61) * 0.5
+    v, ct = _rand(b, h, tk, dv, seed=62), _rand(b, h, tq, dv, seed=63)
+    pk.attention_plans(reset=True)
+    out, vjp = jax.vjp(lambda *a: pk.flash_attention(*a, causal=True),
+                       q, k, v)
+    (plan,) = pk.attention_plans().values()
+    assert (plan["heads_fwd"], plan["heads_bwd"], plan["live_pairs"],
+            plan["pairs"], plan["diagonal_pairs"]) == want
+    assert plan["grid_steps_fwd"] == b * h // want[0] * want[2]
+    assert plan["grid_steps_bwd"] == b * h // want[1] * want[2]
+    ref, ref_vjp = jax.vjp(lambda *a: _attn_ref(*a, True), q, k, v)
+    for name, got, exp in zip(("out", "dq", "dk", "dv"), (out, *vjp(ct)),
+                              (ref, *ref_vjp(ct))):
+        onp.testing.assert_allclose(onp.asarray(got), onp.asarray(exp),
+                                    rtol=2e-4, atol=2e-5, err_msg=name)
+
+
+def test_attention_plans_of_the_benchmark_cells():
+    """The counter that says how the schedule engaged, for the two
+    signatures the benchmark's cells trace (abstractly: nothing runs).
+    The routed decoder's: 36 live pairs of 64, 8 on the diagonal, several
+    heads a step in both kernels.  BERT's: one dense pair, four heads a
+    step both ways, the grid it always had."""
+    from incubator_mxnet_tpu import profiler
+
+    def plan_of(b, h, t, d, dv, causal):
+        pk.attention_plans(reset=True)
+        qk = jax.ShapeDtypeStruct((b, h, t, d), BF16)
+        v = jax.ShapeDtypeStruct((b, h, t, dv), BF16)
+        jax.eval_shape(lambda q, k, v: jax.vjp(
+            lambda *a: pk.flash_attention(*a, causal=causal), q, k, v)[1](
+                v), qk, qk, v)
+        (sig, plan), = pk.attention_plans().items()
+        return sig, plan
+
+    sig, plan = plan_of(2, 32, 4096, 192, 128, True)
+    assert sig == "bh64 d192/128 t4096x4096 causal bfloat16"
+    assert plan == {"heads_fwd": 4, "heads_bwd": 4, "grid_steps_fwd": 576,
+                    "grid_steps_bwd": 576, "pairs": 64, "live_pairs": 36,
+                    "diagonal_pairs": 8}
+    # a stats provider of profiler.dumps(), beside kernel_routes
+    assert profiler.provider_stats()["attention_plans"] == {sig: plan}
+    assert "attention_plans" in profiler.dumps()
+    sig, plan = plan_of(32, 12, 512, 64, 64, False)
+    assert sig == "bh384 d64/64 t512x512 dense bfloat16"
+    assert plan == {"heads_fwd": 4, "heads_bwd": 4, "grid_steps_fwd": 96,
+                    "grid_steps_bwd": 96, "pairs": 1, "live_pairs": 1,
+                    "diagonal_pairs": 0}
+
+
 def _attn_ref(q, k, v, causal):
     scale = q.shape[-1] ** -0.5
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale

@@ -115,36 +115,53 @@ def test_softmax_xent_fwd_bwd(one_chip, for_the_chip, rows, cols, dtype):
 
 
 # BERT-base's attention in the benchmark's cell, the float32 reference
-# step's (batch 2), long causal sequences (several blocks each way; at
-# 8,192 a head's whole dq allows two heads a step, not four)
-@pytest.mark.parametrize("shape,dtype,causal", [
-    ((32, 12, 512, 64), BF16, False),
-    ((2, 12, 512, 64), F32, False),
-    ((8, 16, 2048, 64), BF16, True),
-    ((1, 8, 8192, 64), BF16, True)])
+# step's (batch 2), long causal sequences (several blocks each way: a
+# grid over the live pairs, their tables prefetched into SMEM; at 8,192
+# four heads a step both ways: a head's whole dq, float32 sum and two
+# output buffers, is 4 MiB of the backward's bill), and the longest
+# causal length at width 64 that compiled before the backward had a bill
+# of its own (60,416: 118 x 118 blocks, 7,021 live pairs, two heads a
+# step under the 64 MiB the plan may ask for; 60,928 did not compile
+# under 32 MiB), so that no change to the bill shrinks it unseen
+@pytest.mark.parametrize("shape,dtype,causal,heads", [
+    ((32, 12, 512, 64), BF16, False, (4, 4)),
+    ((2, 12, 512, 64), F32, False, (4, 4)),
+    ((8, 16, 2048, 64), BF16, True, (4, 4)),
+    ((1, 8, 8192, 64), BF16, True, (4, 4)),
+    ((1, 8, 60416, 64), BF16, True, (4, 2))])
 def test_flash_attention_fwd_bwd(one_chip, for_the_chip, shape, dtype,
-                                 causal):
+                                 causal, heads):
     from incubator_mxnet_tpu.ops import nn_ops
     q = _spec(one_chip, shape, dtype)
     attend = functools.partial(nn_ops.dot_product_attention.fn, causal=causal)
+    pk.attention_plans(reset=True)
     text = _compile(_fwd_bwd(attend, 3), q, q, q)
     assert text.count("tpu_custom_call") == 2
     # neither pass holds a (T, S) tensor outside the kernels
     assert f"{shape[2]},{shape[2]}]" not in text
+    (plan,) = pk.attention_plans().values()
+    assert (plan["heads_fwd"], plan["heads_bwd"]) == heads
 
 
 def test_flash_attention_with_a_narrower_v(one_chip, for_the_chip):
     """Latent attention at the published widths and the benchmark's
     length: 192 wide in q and k (128 + 64 rotary), 128 in v, causal, 4,096
-    keys (several key blocks with the online rescale; the backward holds
-    one head's whole dq, 192 x 4,096, in VMEM)."""
+    keys (8 x 8 blocks of which 36 are live, four heads a step both ways;
+    the backward holds four heads' whole dq, 192 x 4,096 each, in VMEM
+    and asks Mosaic for the 38 MiB that takes)."""
     from incubator_mxnet_tpu.ops import nn_ops
     qk = _spec(one_chip, (2, 32, 4096, 192), BF16)
     v = _spec(one_chip, (2, 32, 4096, 128), BF16)
     attend = functools.partial(nn_ops.dot_product_attention.fn, causal=True)
+    pk.attention_plans(reset=True)
     text = _compile(_fwd_bwd(attend, 3), qk, qk, v)
     assert text.count("tpu_custom_call") == 2
     assert "4096,4096]" not in text
+    assert pk.attention_plans() == {
+        "bh64 d192/128 t4096x4096 causal bfloat16": {
+            "heads_fwd": 4, "heads_bwd": 4, "grid_steps_fwd": 576,
+            "grid_steps_bwd": 576, "pairs": 64, "live_pairs": 36,
+            "diagonal_pairs": 8}}
     # no operand is padded to another's width: nothing 192 wide but q, k
     # and their gradients reaches a kernel
     assert "bf16[64,128,4096]" in text and "bf16[64,192,4096]" in text
