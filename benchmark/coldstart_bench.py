@@ -83,6 +83,7 @@ def _zoo_artifact(prefix, model, aot_buckets=None):
 # /metrics exposes.
 _CHILD = r"""
 import json, os, sys, time
+import jax
 repo_root, prefix, t0 = sys.argv[1], sys.argv[2], float(sys.argv[3])
 sys.path.insert(0, repo_root)
 import numpy as onp
@@ -103,6 +104,7 @@ print(json.dumps({
     "cold_start_ms": snap.get("m.cold_start_ms"),
     "aot_loads": snap.get("m.aot_loads", 0),
     "aot_load_failures": snap.get("m.aot_load_failures", 0),
+    "platform": jax.devices()[0].platform,
 }), flush=True)
 """
 
@@ -193,7 +195,7 @@ def bench(args):
         "buckets": buckets,
         "model": args.model_zoo or f"mlp{args.width}x{args.depth}",
         "trials": args.trials,
-        "platform": os.environ.get("JAX_PLATFORMS", "tpu"),
+        "platform": cold["platform"],
     }
     failures = []
     if args.check:

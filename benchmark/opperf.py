@@ -3,9 +3,9 @@
 
 Walks the op registry, generates inputs per op (curated specs for layer
 ops, shape heuristics for tensor ops), and times forward and backward
-with the honest-sync discipline from bench.py: every measurement chains
-through device values and ends with a host readback INSIDE the timed
-region (block_until_ready does not wait on this platform).
+so that a timed region cannot end before the device does: every
+measurement chains through device values and ends with a host readback
+INSIDE the timed region (docs/performance.md "Closing a timed window").
 
 Usage:
   python benchmark/opperf.py [--output opperf.json] [--ops relu,dot,...]

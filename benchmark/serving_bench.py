@@ -78,6 +78,12 @@ def _toy_artifact(prefix, width=128, depth=6):
     return prefix
 
 
+def _platform():
+    """Where the work ran, asked of the process that ran it."""
+    import jax
+    return jax.devices()[0].platform
+
+
 def _zoo_artifact(prefix, model):
     from scripts.export_model_zoo import main as export_main
     export_main(["--model", model, "--out", prefix,
@@ -188,7 +194,7 @@ def bench(args):
         "compile_total": compile_after,
         "compile_stable": compile_after == compile_before,
         "bitwise_equal_unbatched": bool(bitwise_ok),
-        "platform": os.environ.get("JAX_PLATFORMS", "tpu"),
+        "platform": _platform(),
     }
     return rec
 
@@ -277,7 +283,7 @@ def fleet_bench(args):
         "failed_requests": len(failed),
         "requests_per_count": total,
         "verified": bool(verified),
-        "platform": os.environ.get("JAX_PLATFORMS", "tpu"),
+        "platform": _platform(),
     }
     failures = []
     if failed:
@@ -396,7 +402,7 @@ def trace_overhead(args):
         "offpath_ns_per_hook": round(offpath_ns, 1),
         "bitwise_equal_with_tracing": bool(parity),
         "requests_per_volley": total,
-        "platform": os.environ.get("JAX_PLATFORMS", "tpu"),
+        "platform": _platform(),
     }
     if args.check:
         if not parity:
@@ -479,7 +485,7 @@ def flight_overhead(args):
         "disabled_ns_per_call": round(disabled_ns, 1),
         "bitwise_equal_with_flight": bool(parity),
         "requests_per_volley": total,
-        "platform": os.environ.get("JAX_PLATFORMS", "tpu"),
+        "platform": _platform(),
     }
     if args.check:
         if not parity:
@@ -594,7 +600,7 @@ def routerha_overhead(args):
         "owner_lookup_miss_ns": round(owner_miss_ns, 1),
         "bitwise_equal_with_ha": bool(parity),
         "requests_per_volley": total,
-        "platform": os.environ.get("JAX_PLATFORMS", "tpu"),
+        "platform": _platform(),
     }
     if args.check:
         if not parity:
@@ -722,7 +728,7 @@ def _smoke_instrumented(args, prefix, _predictor_compiles):
         "bitwise_equal_unbatched": bool(ok_bitwise),
         "allclose_unbatched": bool(ok_close),
         "health": health["status"],
-        "platform": os.environ.get("JAX_PLATFORMS", "tpu"),
+        "platform": _platform(),
     }
     failures = []
     if any(c != 200 for c in codes):

@@ -77,6 +77,7 @@ def repro_line(args):
 
 
 def bench(args):
+    import jax
     from incubator_mxnet_tpu import fault
     from incubator_mxnet_tpu.serving.loadgen import parse_workload
     from incubator_mxnet_tpu.serving.loadgen.capacity import (
@@ -102,7 +103,7 @@ def bench(args):
         "schedule_deterministic": deterministic,
         "arrivals": len(s1.arrivals),
         "repro": repro_line(args),
-        "platform": os.environ.get("JAX_PLATFORMS", "tpu"),
+        "platform": jax.devices()[0].platform,
     }
 
     with tempfile.TemporaryDirectory() as tmp:

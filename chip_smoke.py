@@ -146,7 +146,13 @@ def _check_losses(losses, classes):
 def child_train(args):
     jax, device = _child_setup(args)
     import jax.numpy as jnp
-    from bench import PEAK_FLOPS, TRAIN_FLOPS_PER_IMG  # one table of peaks
+    # one table of peaks and one FLOP count: the benchmark's (PERF.md
+    # section 3 has the convention: 2 a multiply-add, 3 forward passes)
+    with open(os.path.join(HERE, "chipbench", "peaks.json")) as f:
+        peaks = json.load(f)
+    with open(os.path.join(HERE, "chipbench", "configs",
+                           "resnet50_v1.json")) as f:
+        train_flops_per_img = 3 * 2 * json.load(f)["forward_macs_per_image"]
     from incubator_mxnet_tpu import amp, native
     check(native.available(), "libmxtpu.so did not load after the rebuild")
     say(f"[train] native runtime library loaded: {native._LIB_PATH}")
@@ -199,9 +205,10 @@ def child_train(args):
         # block_until_ready has to wait for the device: a window it closes
         # cannot be shorter than the chip's compute-bound minimum, nor much
         # shorter than the same steps closed by a readback
-        check(device["kind"] in PEAK_FLOPS, f"no peak FLOP/s on record for "
+        check(device["kind"] in peaks, f"no peak FLOP/s on record for "
               f"device kind {device['kind']!r}")
-        floor_ms = 1e3 * bs * TRAIN_FLOPS_PER_IMG / PEAK_FLOPS[device["kind"]]
+        floor_ms = 1e3 * bs * train_flops_per_img / \
+            peaks[device["kind"]]["bf16_flops_per_s"]
         check(block_ms >= floor_ms and block_ms >= 0.8 * readback_ms,
               f"block_until_ready returned early: {block_ms:.2f} ms a step "
               f"against a {floor_ms:.2f} ms compute floor and "

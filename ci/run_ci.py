@@ -26,7 +26,6 @@ Stages (each isolated, failures collected, nonzero exit if any fail):
              a seeded reshard violation failing its own strict-mode
              subprocess — the stage's negative control
   multichip  __graft_entry__.dryrun_multichip on a virtual 8-device mesh
-  bench      bench.py refuses to publish a result without a TPU
   chaos      kvstore + checkpoint test subset re-run under a fixed
              MXNET_FAULT_SPEC (deterministic transient faults on the
              PS transport, delays on checkpoint writes) so every PR
@@ -996,15 +995,6 @@ def stage_multichip(args):
     return proc.returncode == 0, (proc.stdout or proc.stderr)[-200:]
 
 
-def stage_bench(args):
-    """CI runs on the CPU, where bench.py must REFUSE: non-zero exit and
-    no result line (a CPU timing is never published as a result)."""
-    proc = sh([sys.executable, "bench.py"], timeout=600,
-              env={"JAX_PLATFORMS": "cpu"})
-    ok = proc.returncode != 0 and not proc.stdout.strip()
-    return ok, (proc.stderr or proc.stdout).strip()[-200:]
-
-
 STAGES = {"build": stage_build, "sanity": stage_sanity,
           "lint": stage_lint, "locklint": stage_locklint,
           "unit": stage_unit, "slow": stage_slow,
@@ -1022,7 +1012,7 @@ STAGES = {"build": stage_build, "sanity": stage_sanity,
           "graphlint": stage_graphlint,
           "memlint": stage_memlint,
           "shardlint": stage_shardlint,
-          "multichip": stage_multichip, "bench": stage_bench}
+          "multichip": stage_multichip}
 
 
 def main(argv=None):

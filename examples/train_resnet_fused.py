@@ -1,8 +1,8 @@
 #!/usr/bin/env python
 """Train ResNet-50 on the Pallas fused-bottleneck path (eager Trainer).
 
-Demonstrates the user-facing API for the NHWC fused configuration the
-headline benchmark uses (`BENCH_LAYOUT=NHWC BENCH_FUSED=1`):
+Demonstrates the user-facing API for the NHWC fused configuration (in no
+cell of the benchmark: whether it beats the default is ROADMAP S4's A/B):
 
     net = vision.resnet50_v1(layout="NHWC", fused=True)
 

@@ -35,7 +35,7 @@ def _bn_axis(layout):
 
 def _check_fused(fused, layout, cls):
     """fused=True must never silently degrade to the plain path: a
-    benchmark tagged 'fusedblk' (bench.py metric suffix) has to mean the
+    measurement of a net built with fused=True has to mean that the
     fused kernels actually ran."""
     if not fused:
         return
@@ -329,12 +329,12 @@ class S2DStem(HybridBlock):
     4x4/s1 conv over 12 channels replaces the 7x7/s2 conv over 3.
 
     Same function class and FLOPs as the classic stem (the 7x7 kernel
-    embeds exactly into the s2d domain — equivalence verified to 1.2e-6
-    by scripts/perf_probe.py stem), but the contraction reads 12*16=192
+    embeds exactly into the s2d domain: a 4x4 kernel over the 2x2-packed
+    input covers an 8x8 window of the image), but it reads 12*16=192
     taps instead of 3*49=147 over a C=3 input that packs the 128-lane
-    MXU at 2.3% density — the top conv-lowering lever identified in
-    docs/performance.md.  Select with resnet50_v1(stem="s2d") or
-    BENCH_STEM=s2d.
+    MXU at 2.3% density.  Whether it is faster in a whole step has not
+    been measured on the chip (ROADMAP D2).  Select with
+    resnet50_v1(stem="s2d").
     """
 
     def __init__(self, channels, layout="NCHW", **kwargs):

@@ -1,12 +1,12 @@
 """Fused 1x1-conv (matmul) + BatchNorm Pallas kernels for bottleneck nets.
 
-The round-4 on-chip roofline (docs/performance.md) showed the bf16
-ResNet-50 train step is HBM-bandwidth-bound on BN-structured activation
-traffic: XLA cannot fuse the batch-stat reductions *into* the producing
-conv, so every BatchNorm costs an extra activation-sized read (stats)
-plus a materialized normalized copy feeding the next conv.  The MXU-side
-convs themselves run at 84-91% of peak — the FLOPs are fine, the bytes
-are not.
+Built on the premise that the bf16 ResNet-50 train step is bound by
+HBM traffic around BatchNorm: where XLA does not fuse the batch-stat
+reductions *into* the producing conv, every BatchNorm costs an extra
+activation-sized read (stats) plus a materialized normalized copy
+feeding the next conv.  On today's chip and compiler XLA does fuse the
+statistics into the convolution (PERF.md section 5); whether these
+kernels win anything there is ROADMAP S4's A/B, which has not been run.
 
 These kernels remove that traffic for the 1x1 convolutions (2/3 of the
 convs in a bottleneck ResNet), which are plain matmuls over the

@@ -2,9 +2,9 @@
 
 `fused_block.py` removed the BN-structured HBM traffic around the 1x1
 convolutions of a bottleneck ResNet; this module does the same for the
-remaining 3x3 stage convs (stride 1, pad 1, NHWC), which the round-4
-roofline (docs/performance.md) identified as the last structural
-activation traffic:
+remaining 3x3 stage convs (stride 1, pad 1, NHWC), the last
+BatchNorm-structured activation traffic of a bottleneck block (never
+timed against the XLA composition on the chip: ROADMAP S4):
 
   * the previous BatchNorm's normalize+ReLU runs as the conv's PROLOGUE
     in-register — the normalized activation (`y1n` in the old
@@ -539,10 +539,10 @@ def fused_conv3_bn(x, w, scale=None, bias=None):
         raise ValueError(f"fused_conv3_bn needs a 3x3 HWIO kernel, "
                          f"got {w.shape}")
     args = (x, w) if scale is None else (x, w, scale, bias)
-    # per-width tuning knob: after the on-chip fc3 A/B
-    # (scripts/perf_probe.py fc3), restrict the kernel to the input
-    # widths where it wins, e.g. MXNET_FUSED_CONV3_WIDTHS=64,128 —
-    # losing widths ride the XLA composition with no code change
+    # per-width tuning knob: once an on-chip A/B of the kernel against
+    # the XLA composition exists (none has been run), restrict the
+    # kernel to the input widths where it wins, e.g.
+    # MXNET_FUSED_CONV3_WIDTHS=64,128 — the rest ride the composition
     widths = os.environ.get("MXNET_FUSED_CONV3_WIDTHS")
     if widths is not None and x.shape[-1] not in {
             int(v) for v in widths.split(",") if v}:

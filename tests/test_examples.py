@@ -77,3 +77,31 @@ def test_train_resnet_fused_smoke():
     # split as the fleet-SIGKILL / session-chaos subprocess proofs.
     _run("train_resnet_fused.py", "--cpu", "--batch", "2",
          "--image-size", "32", "--steps", "4")
+
+
+def test_docs_name_only_python_files_that_exist():
+    """Every `*.py` path README.md and docs/performance.md name in
+    backticks exists: with a directory, under the repo root or the
+    package; a bare name, as some file's name; a glob matches a file."""
+    import glob
+    import re
+    names = set()
+    for _, dirs, files in os.walk(REPO):
+        dirs[:] = [d for d in dirs if not d.startswith(".")
+                   and d not in ("chiprun_out", "__pycache__")]
+        names.update(f for f in files if f.endswith(".py"))
+    missing = []
+    for doc in ("README.md", os.path.join("docs", "performance.md")):
+        with open(os.path.join(REPO, doc)) as f:
+            spans = re.findall(r"`([^`\n]+)`", f.read())
+        for path in {p for s in spans
+                     for p in re.findall(r"[\w.*/-]+\.py\b", s)}:
+            if "/" not in path:
+                found = path in names
+            else:
+                found = any(glob.glob(os.path.join(base, path))
+                            for base in (REPO, os.path.join(
+                                REPO, "incubator_mxnet_tpu")))
+            if not found:
+                missing.append((doc, path))
+    assert not missing, missing

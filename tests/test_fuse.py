@@ -1,9 +1,9 @@
-"""Fused train step (fuse.py) — the performance path bench.py runs.
+"""Fused train step (fuse.py) — the path every benchmark cell trains through.
 
 The whole-step program (forward + backward + optimizer + BN stat
 updates, donated buffers) must match the eager Trainer path formula-
 for-formula; these tests pin that equivalence per optimizer and the
-BN-stat round-trip that bench.py's throughput claims rest on.
+BN-stat round-trip that a measured step's correctness rests on.
 """
 import numpy as onp
 import pytest

@@ -166,10 +166,11 @@ def test_zoo_fused_bottleneck_matches_unfused():
 
     Block-level parity is the right oracle: FULL-model grad equality is
     not testable at f32 — the 50-layer tiny-batch-BN gradient is
-    chaotic at rounding scale (a 1e-6 input perturbation moves plain-
-    path grads by ~0.37 relative; measured, see ROUND4.md session-3
-    notes), so fused-vs-plain full-model diffs just re-measure that
-    chaos."""
+    chaotic at rounding scale (measured on the CPU at bs=4: a 1e-6
+    relative input perturbation moves the PLAIN path's own worst grad
+    by ~0.37 relative, as much as fused-vs-plain differ; several BNs
+    have var/meansq ~ 2e-2 and rstd amplifies ~7x a layer), so
+    fused-vs-plain full-model diffs just re-measure that chaos."""
     import incubator_mxnet_tpu as mx
     from incubator_mxnet_tpu import nd, autograd
     from incubator_mxnet_tpu.gluon.model_zoo.vision.resnet import \

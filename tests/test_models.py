@@ -136,10 +136,9 @@ def test_lstm_lm_overfits():
 
 
 def test_resnet_s2d_stem_variant():
-    """resnet50_v1(stem='s2d') — the MLPerf space-to-depth stem
-    (BENCH_STEM=s2d path): same output contract as the classic stem,
-    stem conv reads the s2d-packed 12-channel input, and the fused
-    train step runs end to end."""
+    """resnet50_v1(stem='s2d') — the MLPerf space-to-depth stem: same
+    output contract as the classic stem, stem conv reads the s2d-packed
+    12-channel input, and the fused train step runs end to end."""
     import jax.numpy as jnp
     from incubator_mxnet_tpu.gluon.model_zoo import vision
     from incubator_mxnet_tpu.fuse import make_fused_train_step

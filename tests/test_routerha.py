@@ -467,6 +467,21 @@ def test_restore_race_with_async_snapshotter_20_of_20(tmp_path):
     dst = SessionManager("dec", toy_decoder(dim=DIM, max_len=64),
                          buckets=[1], warmup=False,
                          snapshot_dir=str(snap), snapshot_steps=100)
+    # the test's own hook, on this instance only: the restore says when
+    # it has looked, found the publish in flight and is about to retry —
+    # the window the rename below has to land in.  The wait budget is
+    # not what is under test, so a loaded host may not run it out.
+    in_window = threading.Event()
+    in_flight = dst._snapshot_in_flight
+
+    def in_flight_seen(*a):
+        racing = in_flight(*a)
+        if racing:
+            in_window.set()
+        return racing
+
+    dst._snapshot_in_flight = in_flight_seen
+    dst.RESTORE_RACE_WAIT_S = 60.0
     for i in range(20):
         sid = f"race{i}"
         src.create(sid)
@@ -488,9 +503,10 @@ def test_restore_race_with_async_snapshotter_20_of_20(tmp_path):
             except Exception as e:  # noqa: BLE001 - recorded for the assert
                 result["err"] = e
 
+        in_window.clear()
         t = threading.Thread(target=adopt)
         t.start()
-        time.sleep(0.15)                  # restore is inside the race
+        assert in_window.wait(30), f"trial {i}: restore never raced"
         staged.rename(committed)          # the "atomic publish" lands
         t.join(timeout=30)
         assert not t.is_alive(), f"trial {i}: restore hung"
