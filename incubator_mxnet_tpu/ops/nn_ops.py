@@ -14,6 +14,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax import nn as jnn
 
+from .. import profiler as _profiler
+from ..locks import named_lock
 from .registry import register
 
 # BatchNorm batch-stat algorithm, fixed at import: compiled traces are
@@ -534,14 +536,58 @@ def softmax_activation(x, mode="instance"):
 # Dropout (key is an explicit input — functional PRNG)
 # ---------------------------------------------------------------------------
 
+_masks = {}
+_masks_lock = named_lock("ops.dropout_masks")
+
+
+def dropout_masks(reset=False):
+    """``{signature: {elements, generator, kept_bytes}}`` of every mask
+    :func:`dropout` was traced to draw so far: the mask's ``elements``
+    (its broadcast shape under ``axes``), the ``generator`` of its bits
+    and the ``kept_bytes`` it holds from the forward pass to the backward
+    (one ``pred`` an element).  Written where the op's body is traced, so
+    it counts signatures and not calls (a jitted op is traced once for
+    equal shapes).  The ``dropout_masks`` provider of
+    ``profiler.dumps()``."""
+    with _masks_lock:
+        out = {sig: dict(mask) for sig, mask in sorted(_masks.items())}
+        if reset:
+            _masks.clear()
+    return out
+
+
+_profiler.register_stats_provider("dropout_masks", dropout_masks)
+
+
 @register("Dropout", aliases=("dropout",))
 def dropout(x, key, p=0.5, mode="training", axes=()):
+    """Inverted dropout whose mask is drawn ONCE: 32-bit words from XLA's
+    ``RngBitGenerator`` (an instruction of its own, which XLA neither
+    fuses nor duplicates) under the integer compare
+    ``bits < round((1 - p) * 2**32)``, kept as a ``pred`` behind an
+    optimization barrier, so the forward select and the backward pass's
+    (autodiff's ``where(keep, g / (1 - p), 0)``) read one array.  Without
+    the barrier XLA fuses the mask's producer into every consumer — with
+    threefry bits that was the rounds again in the prologue of each
+    gradient matmul (PERF.md §6, PR 33); a ``jax.custom_vjp`` alone does
+    not stop it.  ``key`` is the op's threefry key (two words, as
+    ``random.next_key`` gives it); the generator's four are that key
+    twice, the layout of ``jax.random.key(seed, impl="rbg")``.  The mask
+    is a function of the key on one backend, not equal across backends
+    (``random.py``)."""
     if p <= 0.0 or mode != "training":
         return x + 0
     shape = list(x.shape)
     for a in axes:
         shape[a] = 1
-    keep = jax.random.bernoulli(key, 1.0 - p, tuple(shape))
+    _, bits = lax.rng_bit_generator(jnp.concatenate([key, key]),
+                                    tuple(shape), dtype=jnp.uint32)
+    keep_below = min(round((1.0 - p) * 2 ** 32), 2 ** 32 - 1)
+    keep = lax.optimization_barrier(bits < jnp.uint32(keep_below))
+    with _masks_lock:
+        _masks["x".join(map(str, shape)) + f" p{p:g} {x.dtype}"] = {
+            "elements": keep.size, "generator": "rng_bit_generator",
+            "kept_bytes": keep.nbytes}
     return jnp.where(keep, x / (1.0 - p), jnp.zeros((), x.dtype))
 
 

@@ -7,8 +7,14 @@ functional keys; this module bridges the two worlds:
 
 * Eager mode: a process-global seed + monotonically increasing counter;
   each random op folds the counter into the seed key, so ``mx.random.seed(n)``
-  gives reproducible streams (documented contract: streams are threefry,
-  NOT bitwise-equal to the reference's Philox/MT — SURVEY.md §7 "RNG parity").
+  gives reproducible streams (documented contract, docs/migration.md
+  "RNG streams differ": keys, ``next_key``'s ``fold_in`` and every sampler
+  here are threefry, NOT bitwise-equal to the reference's Philox/MT —
+  SURVEY.md §7 "RNG parity").  One consumer of these keys draws its bits
+  elsewhere: the op ``Dropout`` hands its threefry key to XLA's
+  ``RngBitGenerator`` (``ops/nn_ops.dropout``), so a dropout mask is a
+  function of its key on one backend, and NOT equal across backends (a
+  TPU and a CPU draw different masks from one key).
 * Traced mode (hybridize/CachedOp): the tracer installs a base key that is
   an *input* to the compiled program via ``key_scope``; random ops split
   from it deterministically, keeping compiled graphs pure.

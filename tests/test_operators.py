@@ -3,11 +3,14 @@
 Small shapes so the finite-difference checker stays fast; numeric
 gradients validate the registered vjp of each op family.
 """
+import jax
+import jax.numpy as jnp
 import numpy as onp
 import pytest
 
 import incubator_mxnet_tpu as mx
-from incubator_mxnet_tpu import nd
+from incubator_mxnet_tpu import autograd, nd, profiler
+from incubator_mxnet_tpu.ops.nn_ops import dropout as _dropout, dropout_masks
 from incubator_mxnet_tpu.test_utils import (assert_almost_equal,
                                             check_numeric_gradient)
 
@@ -185,6 +188,102 @@ def test_dropout_modes():
     kept = (out.asnumpy() != 0).mean()
     assert 0.2 < kept < 0.8
     assert out.asnumpy().max() == pytest.approx(2.0)  # inverted scaling
+
+
+@pytest.mark.parametrize("axes", [(), (0,), (1, 2), (-1,)])
+@pytest.mark.parametrize("p", [0.1, 0.5])
+def test_dropout_backward_reads_the_forward_mask(p, axes):
+    """The gradient of the sum is the forward's own mask, scaled: one mask
+    is drawn and kept, none is drawn again for the backward pass."""
+    x, key = jnp.ones((64, 8, 16), "float32"), jax.random.PRNGKey(11)
+    out = _dropout(x, key, p=p, axes=axes)
+    grad = jax.grad(lambda v: _dropout(v, key, p=p, axes=axes).sum())(x)
+    kept = onp.asarray(out) != 0
+    assert 0 < kept.sum() < kept.size
+    scale = onp.float32(1) / onp.float32(1 - p)
+    assert onp.array_equal(onp.asarray(grad), onp.where(kept, scale, 0))
+    # a mask broadcast along `axes` is one draw for the whole axis
+    for a in axes:
+        assert (kept == onp.take(kept, [0], axis=a)).all()
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_dropout_mask_is_a_function_of_the_key(dtype):
+    x = jnp.ones((64, 128), dtype)
+    k1, k2 = jax.random.split(jax.random.PRNGKey(5))
+    a, again, b = (_dropout(x, k, p=0.5) for k in (k1, k1, k2))
+    assert a.dtype == x.dtype
+    assert onp.array_equal(onp.asarray(a != 0), onp.asarray(again != 0))
+    differ = onp.asarray((a != 0) != (b != 0)).mean()
+    assert 0.4 < differ < 0.6      # independent masks differ on half
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2147483659])
+def test_dropout_keep_share_and_mean_within_four_sigma(seed):
+    """Exact Bernoulli(1 - p): over 2**20 draws the kept share lies within
+    four binomial standard deviations of 0.9, and the mean of the scaled
+    output of ones within the matching bound of 1."""
+    n, p = 2 ** 20, 0.1
+    out = onp.asarray(_dropout(jnp.ones((1024, 1024), "float32"),
+                               jax.random.PRNGKey(seed), p=p))
+    sigma = (p * (1 - p) / n) ** 0.5
+    assert abs((out != 0).mean() - (1 - p)) < 4 * sigma
+    assert abs(out.astype("float64").mean() - 1.0) < 4 * sigma / (1 - p)
+    assert set(onp.unique(out)) == {onp.float32(0),
+                                    onp.float32(1) / onp.float32(0.9)}
+
+
+@pytest.mark.parametrize("kw", [
+    {"mode": "inference"}, {"mode": "always"}, {"mode": "eval"},
+    {"mode": "training", "p": 0.0}])
+def test_dropout_op_is_the_identity_outside_training(kw):
+    """The registered op drops only under ``mode="training"`` with p > 0
+    (``nd.Dropout`` maps the reference's ``always`` onto it)."""
+    x = jnp.arange(24, dtype="float32").reshape(4, 6)
+    out = _dropout(x, jax.random.PRNGKey(0), **{"p": 0.5, **kw})
+    assert onp.array_equal(onp.asarray(out), onp.asarray(x))
+
+
+def test_dropout_nd_modes_follow_autograd():
+    x = nd.ones((32, 32))
+    assert_almost_equal(nd.Dropout(x, p=0.5), x)            # not training
+    assert (nd.Dropout(x, p=0.5, mode="always").asnumpy() == 0).any()
+    with autograd.record(train_mode=False):
+        assert_almost_equal(nd.Dropout(x, p=0.5), x)
+    with autograd.record():
+        assert (nd.Dropout(x, p=0.5).asnumpy() == 0).any()
+
+
+def test_dropout_eager_recorded_and_jitted_draw_one_mask_for_one_key():
+    """Eager under ``autograd.record`` (``jax.vjp`` over the op's body, op
+    by op) and the same op inside ``jax.jit`` give one mask for one key,
+    and the recorded backward pass reads it."""
+    key = jax.random.PRNGKey(42)
+    x = nd.ones((48, 96))
+    x.attach_grad()
+    with autograd.record():
+        y = nd.Dropout(x, key=nd.array(onp.asarray(key), dtype="uint32"),
+                       p=0.3)
+    y.backward()
+    jitted = jax.jit(lambda v, k: _dropout(v, k, p=0.3))(x.data, key)
+    assert onp.array_equal(y.asnumpy(), onp.asarray(jitted))
+    assert onp.array_equal(x.grad.asnumpy() != 0, y.asnumpy() != 0)
+
+
+def test_dropout_masks_counter_names_each_signature_once():
+    dropout_masks(reset=True)
+    key = jax.random.PRNGKey(0)
+    for _ in range(2):
+        _dropout(jnp.ones((4, 8, 16), "bfloat16"), key, p=0.1)
+    _dropout(jnp.ones((4, 8, 16), "float32"), key, p=0.1, axes=(1,))
+    _dropout(jnp.ones((4, 8, 16), "float32"), key, p=0.1, mode="inference")
+    assert profiler.provider_stats()["dropout_masks"] == {
+        "4x1x16 p0.1 float32": {"elements": 64, "kept_bytes": 64,
+                                "generator": "rng_bit_generator"},
+        "4x8x16 p0.1 bfloat16": {"elements": 512, "kept_bytes": 512,
+                                 "generator": "rng_bit_generator"}}
+    assert "dropout_masks" in profiler.dumps()
+    assert dropout_masks(reset=True) and not dropout_masks()
 
 
 def test_embedding_and_one_hot():

@@ -247,6 +247,39 @@ def test_fused_conv3_bn_image_block_beyond_vmem_rides_xla(one_chip,
     assert "tpu_custom_call" not in text
 
 
+def test_dropout_mask_is_drawn_once_and_kept(one_chip):
+    """BERT's `dropout(x . w) + res`, forward and backward, at the
+    benchmark's widths: the mask's generator is ONE instruction of the
+    compiled program (threefry rounds were fused into all three of the
+    forward matmul and the two gradient matmuls: PERF.md §6, PR 33), the
+    mask is a `pred` array, and the backward pass's matmul fusions take
+    it as an operand instead of drawing it again."""
+    import re
+    from incubator_mxnet_tpu.ops import nn_ops
+    rows = _spec(one_chip, (16384, 768), BF16)
+    w = _spec(one_chip, (768, 768), BF16)
+    key = _spec(one_chip, (2,), jnp.uint32)
+
+    def proj(x, w, res, key):
+        return nn_ops.dropout.fn(jnp.dot(x, w), key, p=0.1) + res
+
+    text = _compile(_fwd_bwd(proj, 3), rows, w, rows, key)
+    assert len(re.findall(r" rng-bit-generator\(", text)) == 1
+    # nothing left of a counter-based generator's rounds over the rows
+    assert not [c for c in text.split("\n\n") if "shift-right-logical" in c
+                and "16384" in c.splitlines()[0]]
+    entry = text[text.index("\nENTRY "):]
+    (mask,) = re.findall(r"(%[\w.\-]+) = pred\[16384,768\]", entry)
+    readers = [line for line in entry.splitlines() if " fusion(" in line
+               and mask in re.findall(
+                   r"%[\w.\-]+", line.split(" fusion(")[1].split(")")[0])]
+    backward = [line for line in readers if "transpose(jvp" in line]
+    assert len(readers) == 3 and len(backward) == 2
+    assert nn_ops.dropout_masks()["16384x768 p0.1 bfloat16"] == {
+        "elements": 16384 * 768, "generator": "rng_bit_generator",
+        "kept_bytes": 16384 * 768}
+
+
 def test_mosaic_kernel_under_a_mesh_needs_gspmd_trace(topo, for_the_chip):
     """Why `fuse.FusedTrainStep` traces its step under `gspmd_trace` when it
     is given a mesh: GSPMD cannot partition a Mosaic kernel, so a dp program
