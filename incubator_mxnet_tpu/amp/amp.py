@@ -136,7 +136,9 @@ def policy_scope(policy):
 _KEEP_FP32_SUFFIXES = ("gamma", "beta", "running_mean", "running_var",
                        "moving_mean", "moving_var",
                        # a router's selection bias and counters (aux state)
-                       "score_bias", "moe_stats")
+                       "score_bias", "moe_stats",
+                       # a state-space mixer's decay, step bias and skip
+                       "a_log", "dt_bias", "d_skip")
 
 
 def convert_block(block, target_dtype="bfloat16", target_dtype_ops=None,
@@ -144,7 +146,8 @@ def convert_block(block, target_dtype="bfloat16", target_dtype_ops=None,
     """Convert a Block to mixed precision (reference convert_hybrid_block).
 
     Casts the block's parameters to ``target_dtype`` (norm-layer
-    scale/offset, moving statistics and a router's aux state stay fp32) and attaches a
+    scale/offset, moving statistics, a router's aux state and a state-space
+    mixer's decay, step bias and skip stay fp32) and attaches a
     ``CastPolicy`` built from the amp lists — honored per-op on every
     forward through the block, so ``fp32_ops=['softmax']`` really does
     run softmax in fp32 on bf16 activations.

@@ -102,8 +102,7 @@ class FusedTrainStep:
     """
 
     def __init__(self, block, loss_fn, optimizer="sgd", optimizer_params=None,
-                 mesh=None, batch_spec=None, donate=True, remat=None,
-                 chunk_steps=None):
+                 mesh=None, batch_spec=None, donate=True, chunk_steps=None):
         self.block = block
         self.loss_block = loss_fn
         opt_params = dict(optimizer_params or {})
@@ -131,9 +130,6 @@ class FusedTrainStep:
             raise ValueError(
                 f"fused step supports sgd/nag/adam/adamw; got {optimizer!r} "
                 f"(use the eager Trainer for others)")
-        if remat not in (None, "dots", "nothing"):
-            raise ValueError(
-                f"remat must be None, 'dots' or 'nothing'; got {remat!r}")
         # chunk budget for the whole-loop compilation path (fuse_loop):
         # K == 1 stays on this per-step program, K > 1 lets a
         # ChunkedTrainLoop scan K steps per dispatch
@@ -157,7 +153,6 @@ class FusedTrainStep:
             jax.device_put(
                 (self.params, self.aux, self.opt_state, self._key),
                 placement)
-        self._remat = remat
         # kept for the chunked loop (fuse_loop): the scanned program
         # re-applies the same batch sharding to its (K, batch, ...)
         # blocks, with the scan axis unsharded
@@ -193,17 +188,6 @@ class FusedTrainStep:
                 with jax.named_scope("loss"):
                     loss = loss_block(*map(NDArray, outs), NDArray(y))
                 return jnp.mean(loss.data), updates
-
-        if self._remat:
-            # rematerialization (SURVEY §"HBM bandwidth"): trade recompute
-            # for activation traffic.  'dots' keeps matmul outputs and
-            # recomputes the elementwise/norm tail in the backward pass;
-            # 'nothing' recomputes the whole forward.
-            policies = {
-                "dots": jax.checkpoint_policies.checkpoint_dots,
-                "nothing": jax.checkpoint_policies.nothing_saveable,
-            }
-            loss_of = jax.checkpoint(loss_of, policy=policies[self._remat])
 
         def step(params, aux, opt_state, x, y, key):
             # this body runs while tracing, whoever traces it (the jit

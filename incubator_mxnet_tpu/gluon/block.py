@@ -35,7 +35,7 @@ from .. import random as _random
 from ..context import current_context
 from ..ndarray import NDArray
 from .parameter import Parameter, ParameterDict, _TraceParams, \
-    DeferredInitializationError
+    _trace_map, DeferredInitializationError
 
 __all__ = ["Block", "HybridBlock", "CachedOp"]
 
@@ -236,19 +236,59 @@ class Block:
         for hook in self._forward_pre_hooks:
             hook(self, args)
         policy = getattr(self, "_amp_policy", None)
+        forward = self.forward
+        if self.__dict__.get("_recompute") and _trace_map() is not None:
+            forward = self._recomputed_forward
         with self._scope():
             if policy is not None:
                 from ..amp import amp as _amp
                 with _amp.policy_scope(policy):
-                    out = self.forward(*args, **kwargs)
+                    out = forward(*args, **kwargs)
             else:
-                out = self.forward(*args, **kwargs)
+                out = forward(*args, **kwargs)
         for hook in self._forward_hooks:
             hook(self, args, out)
         return out
 
     def forward(self, *args, **kwargs):
         raise NotImplementedError
+
+    # -- recomputation ----------------------------------------------------
+    def recompute(self, active=True):
+        """Ask that, wherever this block is traced into a compiled program
+        (a fused train step, a hybridized parent, ``functional()``), its
+        ``forward`` run under ``jax.checkpoint``: the backward pass keeps
+        the block's inputs and parameters and runs everything inside it
+        again, so that a stack of such blocks holds one block's activations
+        at a time.  Eager calls are as before.  A block that registers
+        aux-state updates (BatchNorm's moving statistics, a router's
+        bias) cannot be run twice and is refused when it is traced.  In a
+        trace, the instructions run again carry ``rematted_computation`` in
+        their ``op_name`` (docs/observability.md)."""
+        self.__dict__["_recompute"] = bool(active)
+        return self
+
+    def _recomputed_forward(self, *args):
+        outer = _trace_map()
+        mine = [p for p in self.collect_params().values() if p in outer]
+
+        def pure(values, inputs):
+            inner = dict(outer)
+            inner.update(zip(mine, map(NDArray, values)))
+            with _TraceParams(inner), _CollectStateUpdates() as updates:
+                out = self.forward(*map(NDArray, inputs))
+            if updates:
+                raise ValueError(
+                    f"{type(self).__name__} registers aux-state updates "
+                    f"({', '.join(sorted(p.name for p, _ in updates))}); a "
+                    "block under recompute() runs twice a step and its "
+                    "updates cannot leave the checkpointed region: ask for "
+                    "recomputation on blocks that hold no such state")
+            return jax.tree_util.tree_map(lambda o: o.data, out)
+
+        out = jax.checkpoint(pure)([outer[p].data for p in mine],
+                                   [a.data for a in args])
+        return jax.tree_util.tree_map(NDArray, out)
 
     def summary(self, *inputs):
         """Print per-block output shapes (reference block.py summary)."""

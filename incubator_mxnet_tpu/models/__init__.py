@@ -7,6 +7,9 @@
 ``mla_moe`` — causal decoder with latent attention (MLA), routed
 feed-forward layers of which this chip holds a share, and a multi-token-
 prediction module (the DeepSeek-V3 family's layer equations).
+``falcon_h1`` — causal decoder whose every block runs a Mamba-2 state-space
+mixer beside grouped-query attention, then a gated MLP, with fixed
+multipliers (the Falcon-H1 family's layer equations).
 Vision models live in ``gluon.model_zoo.vision`` (reference layout).
 """
 from . import transformer
@@ -14,3 +17,4 @@ from .transformer import TransformerLM, TransformerConfig
 from .lstm_lm import LSTMLanguageModel
 from .bert import BERTEncoder, BERTModel
 from .mla_moe import MLAMoEDecoder
+from .falcon_h1 import FalconH1Decoder

@@ -22,6 +22,7 @@ from . import shape_ops  # noqa: F401
 from . import index_ops  # noqa: F401
 from . import nn_ops  # noqa: F401
 from . import moe_ops  # noqa: F401  (rope, moe_route, moe_ffn)
+from . import ssm_ops  # noqa: F401  (causal_conv1d, ssd_scan)
 from . import linalg_ops  # noqa: F401
 from . import random_ops  # noqa: F401
 from . import sequence_ops  # noqa: F401

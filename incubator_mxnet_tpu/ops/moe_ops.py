@@ -2,7 +2,8 @@
 (expert parallelism), and the rotary embedding of the decoders that use
 them.  Three registered ops:
 
-* ``rope``       — rotary embedding over interleaved pairs.
+* ``rope``       — rotary embedding over interleaved pairs, or over the
+  two halves.
 * ``moe_route``  — the router: float32 sigmoid scores over *all* experts,
   the ``top_k`` largest of score + bias, normalised gates, and the
   gradient-free bias update from this chip's load.
@@ -64,15 +65,24 @@ def _scoped(fn, *args):
 # ======================================================================
 
 @register("rope")
-def rope(x, theta=10000.0):
+def rope(x, theta=10000.0, interleaved=True):
     """Rotary position embedding of ``x`` (B, T, heads, D) over interleaved
     pairs: the pair ``(x[2i], x[2i+1])`` of position ``t`` turns by the
-    angle ``t · theta^(-2i/D)`` and stays where it was.  The angles are
-    float32; the result has ``x``'s dtype."""
+    angle ``t · theta^(-2i/D)`` and stays where it was.  With
+    ``interleaved=False`` the pairs are ``(x[i], x[i + D/2])``, the two
+    halves (``rotate_half``).  The angles are float32; the result has
+    ``x``'s dtype."""
     def rope(x):
         t, d = x.shape[1], x.shape[-1]
         inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=F32) / d)
         angle = jnp.arange(t, dtype=F32)[:, None] * inv_freq
+        if not interleaved:
+            cos, sin = (jnp.tile(f(angle), 2)[None, :, None, :]
+                        for f in (jnp.cos, jnp.sin))
+            # (x1, x2) -> (-x2, x1): the halves change places
+            turned = jnp.roll(x, d // 2, axis=-1).astype(F32) * jnp.where(
+                jnp.arange(d) < d // 2, -1.0, 1.0)
+            return (x.astype(F32) * cos + turned * sin).astype(x.dtype)
         cos = jnp.repeat(jnp.cos(angle), 2, axis=-1)[None, :, None, :]
         sin = jnp.repeat(jnp.sin(angle), 2, axis=-1)[None, :, None, :]
         # (x[2i], x[2i+1]) -> (-x[2i+1], x[2i]) as a signed permutation

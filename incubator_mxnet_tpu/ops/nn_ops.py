@@ -334,8 +334,11 @@ def rms_norm(x, gamma, axis=-1, eps=1e-6):
 
     if axis in (-1, x.ndim - 1):
         from . import pallas_kernels as pk
+        # a gain of more than one axis (a grouped norm: x (..., groups,
+        # width) under a gain (groups, width)) is the composition's
         return pk.dispatch(functools.partial(pk.fused_rms_norm, eps=eps),
-                           xla, x, gamma)
+                           xla, x, gamma,
+                           unless="gain_shape" if gamma.ndim > 1 else None)
     return xla(x, gamma)
 
 
@@ -625,11 +628,14 @@ def dot_product_attention(q, k, v, mask=None, scale=None, causal=False):
     with float32 scores.  The kernel takes dense and causal attention
     over bfloat16 / float32 operands; an explicit ``mask`` (a
     key-padding mask included) and float16 (Mosaic loads no float16
-    vector on a v5e) take the composition."""
+    vector on a v5e) take the composition.  ``k`` and ``v`` may be (B,
+    Hkv, S, D) with ``H % Hkv == 0`` (grouped-query attention: query head
+    ``i`` reads key head ``i // (H / Hkv)``)."""
     from . import pallas_kernels as pk
     scale = float(scale) if scale is not None else q.shape[-1] ** -0.5
 
     def xla(q, k, v):
+        k, v = pk.repeat_kv_heads(q, k, v)
         logits = jnp.einsum("bhtd,bhsd->bhts", q, k,
                             preferred_element_type=jnp.float32) * scale
         if causal:

@@ -43,6 +43,7 @@ from ..locks import named_lock
 
 __all__ = ["fused_softmax", "fused_layer_norm", "flash_attention",
            "dispatch", "kernel_name", "kernel_routes", "attention_plans",
+           "repeat_kv_heads",
            "interpret_mode",
            "gspmd_trace", "fused_softmax_xent", "fused_rms_norm"]
 
@@ -943,6 +944,21 @@ def _flash_vjp_bwd(sm_scale, causal, res, g):
 _flash_core.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
+def repeat_kv_heads(q, k, v):
+    """Grouped-query attention: ``k`` and ``v`` with fewer heads than ``q``
+    (``Hq % Hkv == 0``), each repeated so that query head ``i`` reads key
+    head ``i // (Hq / Hkv)``.  Exact, and its transpose sums ``dk`` and
+    ``dv`` over a group's query heads.  Equal head counts come back as they
+    are: no operation is traced."""
+    hq, hkv = q.shape[1], k.shape[1]
+    if hq == hkv:
+        return k, v
+    if hq % hkv or v.shape[1] != hkv:
+        raise ValueError(f"attention: {hq} query heads over {hkv} key and "
+                         f"{v.shape[1]} value heads")
+    return tuple(jnp.repeat(x, hq // hkv, axis=1) for x in (k, v))
+
+
 def flash_attention(q, k, v, sm_scale=None, causal=False):
     """softmax(QKᵀ·scale)·V over (B, H, T, D) operands as a pair of
     Pallas kernels, forward and backward: scores, probabilities and
@@ -950,7 +966,9 @@ def flash_attention(q, k, v, sm_scale=None, causal=False):
     backward pass keeps is q, k, v, the output and one float32 a row.
     ``v`` (and with it the output) may have a width of its own, as
     latent attention's has (192 in q and k, 128 in v): no operand is
-    padded to another's width.
+    padded to another's width.  ``k`` and ``v`` may have fewer heads than
+    ``q`` (:func:`repeat_kv_heads`: repeated here, under this kernel's
+    scope, so what the repeat costs is counted as attention's).
     Inside, heads are (D, T): ``q``, ``k``, ``v`` are transposed on the
     way in and the results on the way out, which XLA folds into the
     transposes that make (B, H, T, D) out of a packed projection.
@@ -964,7 +982,7 @@ def flash_attention(q, k, v, sm_scale=None, causal=False):
     for query i where j > i, as the composition's ``tril`` does.
     """
     scale = float(sm_scale) if sm_scale is not None else q.shape[-1] ** -0.5
-    return _flash_core(q, k, v, scale, bool(causal))
+    return _flash_core(q, *repeat_kv_heads(q, k, v), scale, bool(causal))
 
 
 # ======================================================================

@@ -5,6 +5,7 @@ updates, donated buffers) must match the eager Trainer path formula-
 for-formula; these tests pin that equivalence per optimizer and the
 BN-stat round-trip that a measured step's correctness rests on.
 """
+import jax
 import numpy as onp
 import pytest
 
@@ -103,23 +104,59 @@ def test_fused_step_loss_decreases():
     assert last < first * 0.7, (first, last)
 
 
-@pytest.mark.parametrize("remat", ["dots", "nothing"])
-def test_fused_step_remat_matches_plain(remat):
-    """Rematerialization must not change the computed update — only the
-    schedule.  Same seed, same data: identical loss trajectory."""
-    import incubator_mxnet_tpu as mx
+def _stack(recompute):
+    """Two residual MLP blocks and a head, no aux state; ``recompute`` names
+    the blocks that ask for it."""
+    class Residual(gluon.HybridBlock):
+        def __init__(self):
+            super().__init__()
+            self.up = nn.Dense(32, in_units=16, flatten=False)
+            self.down = nn.Dense(16, in_units=32, flatten=False)
 
-    def run(r):
-        mx.random.seed(0)
-        net = _net()
+        def forward(self, x):
+            return x + self.down(self.up(x).tanh())
+
+    mx.random.seed(0)
+    net = nn.HybridSequential()
+    net.add(nn.Flatten(), nn.Dense(16, in_units=192), Residual(), Residual(),
+            nn.Dense(5, in_units=16))
+    net.initialize()
+    for i in recompute:
+        net[i].recompute()
+    return net
+
+
+@pytest.mark.parametrize("recompute", [(2,), (2, 3)],
+                         ids=["one_block", "every_block"])
+def test_fused_step_recompute_matches_plain(recompute):
+    """Blocks under ``recompute()`` run again in the backward pass: the
+    schedule changes, the update does not.  Same seed, same data: the same
+    losses, and the recomputed instructions are in the step's program."""
+    def run(blocks):
         step = make_fused_train_step(
-            net, gluon.loss.SoftmaxCrossEntropyLoss(), "sgd",
-            {"learning_rate": 0.1, "momentum": 0.9}, remat=r)
+            _stack(blocks), gluon.loss.SoftmaxCrossEntropyLoss(), "sgd",
+            {"learning_rate": 0.1, "momentum": 0.9})
         x, y = _data(bs=8)
-        return [float(step(x, y)) for _ in range(3)]
+        losses = [float(step(x, y)) for _ in range(3)]
+        text = str(jax.make_jaxpr(step.step_fn)(
+            step.params, step.aux, step.opt_state, x.data, y.data,
+            step._key))
+        return losses, text.count("remat2[")
 
-    plain, rem = run(None), run(remat)
-    assert plain == pytest.approx(rem, rel=1e-5), (plain, rem)
+    (plain, none), (again, some) = run(()), run(recompute)
+    assert plain == again, (plain, again)
+    assert none == 0 and some >= len(recompute)
+
+
+def test_recompute_refuses_a_block_with_aux_state_updates():
+    """BatchNorm's moving statistics cannot leave a checkpointed region."""
+    net = _net()
+    net[1].recompute()          # the BatchNorm
+    step = make_fused_train_step(
+        net, gluon.loss.SoftmaxCrossEntropyLoss(), "sgd",
+        {"learning_rate": 0.1})
+    with pytest.raises(ValueError, match="registers aux-state updates"):
+        step(*_data(bs=8))
 
 
 def test_fused_step_rejects_unknown_optimizer():

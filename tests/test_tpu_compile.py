@@ -280,6 +280,103 @@ def test_dropout_mask_is_drawn_once_and_kept(one_chip):
         "kept_bytes": 16384 * 768}
 
 
+def test_ssd_scan_fwd_bwd(one_chip, for_the_chip):
+    """The chunked state-space scan at the published widths and the
+    benchmark's length: 4,096 positions of 32 heads x 128 over a 256-wide
+    state in 2 groups, 32 chunks of 128, forward and backward.  It is the
+    XLA composition on both sides of the dispatch (no Mosaic call), keeps
+    32 float32 entry states a sequence for the backward pass and no
+    (T, T) tensor."""
+    from incubator_mxnet_tpu.ops import ssm_ops
+    s = functools.partial(_spec, one_chip)
+    ssm_ops.ssm_plans(reset=True)
+    text = _compile(
+        _fwd_bwd(functools.partial(ssm_ops.ssd_scan.fn, chunk=128), 6),
+        s((1, 4096, 32, 128), BF16), s((1, 4096, 32), F32), s((32,), F32),
+        s((1, 4096, 2, 256), BF16), s((1, 4096, 2, 256), BF16),
+        s((32,), F32))
+    assert "tpu_custom_call" not in text and "4096,4096]" not in text
+    assert ssm_ops.ssm_plans() == {
+        "b1 t4096 h32x128 g2 n256 bfloat16": {
+            "chunk": 128, "chunks": 32, "heads_a_step": 32,
+            "state_bytes_saved": 4 * 32 * 32 * 128 * 256, "padded_rows": 0}}
+    # the entry states (chunk, batch, group, head, P, N) are float32, the
+    # decay-weighted scores (chunk, group, head, Q, Q) go to the MXU bfloat16
+    assert "f32[32,1,2,16,128,256]" in text
+    assert "bf16[32,2,16,128,128]" in text
+
+
+def test_flash_attention_with_fewer_key_heads(one_chip, for_the_chip):
+    """Grouped-query attention at the published widths and the benchmark's
+    length: 20 query heads over 4 key heads, 128 wide, causal, 4,096 keys.
+    The key heads are repeated to 20 outside the kernels, which then run
+    PR 31's live-pair grid (8 x 8 blocks, 36 live)."""
+    from incubator_mxnet_tpu.ops import nn_ops
+    q = _spec(one_chip, (1, 20, 4096, 128), BF16)
+    kv = _spec(one_chip, (1, 4, 4096, 128), BF16)
+    attend = functools.partial(nn_ops.dot_product_attention.fn, causal=True)
+    pk.attention_plans(reset=True)
+    text = _compile(_fwd_bwd(attend, 3), q, kv, kv)
+    assert text.count("tpu_custom_call") == 2
+    assert "4096,4096]" not in text
+    (plan,) = pk.attention_plans().values()
+    assert list(pk.attention_plans()) == [
+        "bh20 d128/128 t4096x4096 causal bfloat16"]
+    assert (plan["pairs"], plan["live_pairs"], plan["diagonal_pairs"]) == \
+        (64, 36, 8)
+
+
+def test_the_falcon_h1_step_fits_the_chip_with_its_blocks_recomputed(
+        one_chip, for_the_chip):
+    """The whole fused train step of ``falcon_h1_34b`` at the cell's size (1
+    x 4,096 tokens, four whole blocks at the published widths, 2.05 B
+    parameters with their Adam moments) plans under the chip's memory with
+    half a GiB to spare — from shapes alone (``jax.eval_shape`` over the
+    configuration's ``build`` and ``make_fused_train_step``: nothing is
+    allocated)."""
+    import json
+    import os
+    import sys
+    from incubator_mxnet_tpu import amp
+    from incubator_mxnet_tpu.fuse import make_fused_train_step
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, repo)
+    from chipbench.configs import falcon_h1_34b as model
+    with open(os.path.join(repo, "chipbench", "configs",
+                           "falcon_h1_34b.json")) as f:
+        config = json.load(f)
+    held = {}
+
+    def make():
+        built = model.build(0, config)
+        amp.convert_block(built["net"], config["dtype"])
+        step = make_fused_train_step(
+            built["net"], built["loss"], built["optimizer"],
+            dict(built["optimizer_params"]))
+        held["step"] = step.step_fn
+        return step.params, step.aux, step.opt_state, step._key
+
+    *state, key = jax.eval_shape(make)
+    on_chip = lambda v: _spec(one_chip, v.shape, v.dtype)
+    with xc.compile_cache_bypassed():
+        compiled = jax.jit(held["step"], donate_argnums=(0, 1, 2)).lower(
+            *jax.tree_util.tree_map(on_chip, state),
+            _spec(one_chip, (1, 4096), I32),
+            _spec(one_chip, (1, 1, 4096), I32), on_chip(key)).compile()
+    plan = compiled.memory_analysis()
+    assert sum(v.size for v in state[0].values()) == 2_054_718_848
+    assert plan.argument_size_in_bytes > 11.4 * 2 ** 30
+    assert plan.alias_size_in_bytes > 11.4 * 2 ** 30       # donated
+    peak = (plan.argument_size_in_bytes + plan.output_size_in_bytes
+            - plan.alias_size_in_bytes + plan.temp_size_in_bytes)
+    # what memory_stats()["bytes_limit"] reads on the chip (PR 34)
+    assert peak < (15.748 - 0.5) * 2 ** 30, peak / 2 ** 30
+    text = compiled.as_text()
+    assert "rematted_computation" in text
+    # 4 blocks' attention forward, again and backward, and the norms
+    assert text.count("flash_attention_fwd") >= 8
+
+
 def test_mosaic_kernel_under_a_mesh_needs_gspmd_trace(topo, for_the_chip):
     """Why `fuse.FusedTrainStep` traces its step under `gspmd_trace` when it
     is given a mesh: GSPMD cannot partition a Mosaic kernel, so a dp program
