@@ -10,6 +10,7 @@ import pytest
 
 import incubator_mxnet_tpu as mx
 from incubator_mxnet_tpu import autograd, nd, profiler
+from incubator_mxnet_tpu.ops import index_ops
 from incubator_mxnet_tpu.ops.nn_ops import dropout as _dropout, dropout_masks
 from incubator_mxnet_tpu.test_utils import (assert_almost_equal,
                                             check_numeric_gradient)
@@ -291,6 +292,183 @@ def test_embedding_and_one_hot():
     idx = nd.array([0, 3], dtype="int32")
     out = nd.Embedding(idx, w, input_dim=4, output_dim=3)
     assert out.asnumpy().tolist() == [[0, 1, 2], [9, 10, 11]]
+
+
+# The gradient of `Embedding` by its table, routed by shape (PR 35).  The
+# route asks `jax.default_backend()`; these tests answer "tpu" for it as
+# tests/test_tpu_compile.py's `for_the_chip` does, and no switch of the
+# op's.  At toy shapes the matmul is the cheaper by the model.
+
+@pytest.fixture
+def on_the_chip(monkeypatch):
+    monkeypatch.setattr(index_ops.jax, "default_backend", lambda: "tpu")
+
+
+_embedding = index_ops.embedding.fn
+
+
+def _take_reversed(ids, weight):
+    return index_ops._take_rows(weight, ids)
+
+EMBEDDING_IDS = {
+    "duplicates": onp.array([3, 0, 3, 3, 10, 0], "int32"),
+    "out_of_range": onp.array([-4, 0, 11, 25, 10, -1], "int32"),
+    "float_ids": onp.array([3., 0., 3., 12., -2., 7.], "float32"),
+    "rank2": onp.array([[3, 0, 3], [10, 12, -1]], "int32"),
+    "rank3": onp.array([[[3, 0], [3, 10]], [[10, 3], [5, 5]]], "int32"),
+}
+
+
+def _table_grad(lookup, ids, weight, g):
+    out, vjp = jax.vjp(lambda w: lookup(jnp.asarray(ids), w), weight)
+    return out, vjp(g)[0]
+
+
+@pytest.mark.parametrize("case", sorted(EMBEDDING_IDS))
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_embedding_matmul_route_gradient_is_the_scatters(on_the_chip, dtype,
+                                                         case):
+    ids = EMBEDDING_IDS[case]
+    rng = onp.random.default_rng(35)
+    weight = jnp.asarray(rng.standard_normal((11, 8)), dtype)
+    # sixteenths: every sum of duplicates is exact in float32
+    g = jnp.asarray(onp.round(16 * rng.standard_normal(ids.shape + (8,)))
+                    / 16, dtype)
+    index_ops.embedding_grads(reset=True)
+    out, grad = _table_grad(_embedding, ids, weight, g)
+    (entry,) = index_ops.embedding_grads().values()
+    assert entry["route"] == "matmul"
+    want_out, want = _table_grad(_take_reversed, ids, weight, g)
+    assert out.dtype == grad.dtype == weight.dtype
+    assert onp.array_equal(onp.asarray(out, "float32"),
+                           onp.asarray(want_out, "float32"))
+    # summed in float32 and rounded once, whatever the table's dtype
+    _, exact = _table_grad(_take_reversed, ids, weight.astype("float32"),
+                           g.astype("float32"))
+    assert onp.array_equal(onp.asarray(grad, "float32"),
+                           onp.asarray(exact.astype(dtype), "float32"))
+    # the scatter rounds once a duplicate: equal in float32, close in bfloat16
+    assert_almost_equal(onp.asarray(grad, "float32"),
+                        onp.asarray(want, "float32"),
+                        rtol=0 if dtype == "float32" else 2 ** -6, atol=0)
+
+
+@pytest.mark.parametrize("ids_dtype,zero", [("int32", jax.dtypes.float0),
+                                            ("float32", "float32")])
+def test_embedding_ids_get_the_zero_cotangent_of_their_dtype(
+        on_the_chip, ids_dtype, zero):
+    ids = jnp.asarray([3, 0, 3, 12], ids_dtype)
+    weight = jnp.ones((11, 8), "float32")
+    out, vjp = jax.vjp(_embedding, ids, weight)
+    by_ids, by_table = vjp(jnp.ones_like(out))
+    assert by_ids.dtype == zero and by_ids.shape == ids.shape
+    if zero == "float32":           # a float0 array holds nothing to read
+        assert not onp.asarray(by_ids).any()
+    assert by_table.sum() == out.size
+
+
+def test_embedding_output_used_twice_gets_the_sum(on_the_chip):
+    """The routed decoder's pattern: the embedding feeds the trunk and the
+    MTP module, so the backward sees the sum of two cotangents."""
+    ids = jnp.asarray(EMBEDDING_IDS["rank2"])
+    rng = onp.random.default_rng(36)
+    weight, a, b = (jnp.asarray(rng.standard_normal(s), "float32")
+                    for s in ((11, 8), (2, 3, 8), (2, 3, 8)))
+
+    def loss(lookup, w):
+        e = lookup(ids, w)
+        return jnp.sum(e * a) + jnp.sum(jnp.tanh(e) * b)
+
+    got = jax.grad(lambda w: loss(_embedding, w))(weight)
+    want = jax.grad(lambda w: loss(_take_reversed, w))(weight)
+    assert_almost_equal(onp.asarray(got), onp.asarray(want), rtol=1e-6,
+                        atol=1e-6)
+
+
+def test_embedding_eager_recorded_backward_takes_the_route(on_the_chip):
+    """`nd.Embedding` under `autograd.record` with MXNet's float ids."""
+    ids = onp.array([[4., 1., 4.], [6., 9., 0.]], "float32")
+    w = nd.array(onp.arange(35, dtype="float32").reshape(7, 5))
+    w.attach_grad()
+    with autograd.record():
+        out = nd.Embedding(nd.array(ids), w, input_dim=7, output_dim=5)
+    out.backward()
+    counts = onp.bincount(onp.clip(ids, 0, 6).astype(int).ravel(),
+                          minlength=7)
+    assert onp.array_equal(w.grad.asnumpy(),
+                           onp.repeat(counts[:, None], 5, 1))
+    assert index_ops.embedding_grads()["6 -> 7x5 float32"]["route"] == \
+        "matmul"
+
+
+@pytest.mark.parametrize("signature,route,form", [
+    ((16384, 30522, 768), "scatter", "sorted"),            # BERT-base
+    ((8194, 16160, 2048), "scatter", "sorted"),            # JoyAI, cut
+    ((4096, 32640, 5120), "matmul", "sorted_gathered"),    # Falcon-H1, cut
+    ((8192, 129280, 2048), "scatter", "in_place"),         # JoyAI, published
+    ((4096, 261120, 5120), "scatter", "in_place"),         # Falcon, published
+    ((4096, 32768, 5120), "scatter", "in_place"),          # 128 rows more
+    ((4096, 32640, 2560), "matmul", "sorted"),             # a slow narrow row
+    ((4096, 32640, 4096), "scatter", "sorted"),
+])
+def test_embedding_grad_route_compares_costs(on_the_chip, signature, route,
+                                             form):
+    entry = index_ops.embedding_grad_route(*signature, "bfloat16")
+    assert (entry["route"], entry["scatter_form"]) == (route, form)
+    assert entry["matmul_flops"] == 2 * onp.prod(signature, dtype="int64")
+    cheaper = min(entry["est_matmul_ms"], entry["est_scatter_ms"])
+    assert entry[f"est_{route}_ms"] == cheaper
+
+
+def test_embedding_grad_route_off_the_tpu_and_in_an_unmeasured_dtype(
+        monkeypatch):
+    falcon = (4096, 32640, 5120)
+    assert index_ops.embedding_grad_route(*falcon, "bfloat16")["route"] == \
+        "scatter"                                   # the CPU has no cliff
+    monkeypatch.setattr(index_ops.jax, "default_backend", lambda: "tpu")
+    assert index_ops.embedding_grad_route(*falcon, "float32")["route"] == \
+        "matmul"
+    half = index_ops.embedding_grad_route(*falcon, "float16")
+    assert half["route"] == "scatter" and half["est_matmul_ms"] is None
+
+
+def test_embedding_at_berts_signature_traces_to_bare_take(on_the_chip):
+    def traced(fn, ids, rows, width):
+        return str(jax.make_jaxpr(fn)(
+            jax.ShapeDtypeStruct((ids,), "int32"),
+            jax.ShapeDtypeStruct((rows, width), "bfloat16")))
+
+    def bare(data, weight):
+        return jnp.take(weight, data.astype(jnp.int32), axis=0, mode="clip")
+
+    assert traced(_embedding, 16384, 30522, 768) == \
+        traced(bare, 16384, 30522, 768)
+    assert "custom_vjp" in traced(_embedding, 4096, 32640, 5120)
+    assert "custom_vjp" not in traced(bare, 4096, 32640, 5120)
+
+
+def test_embedding_grads_counter_names_each_route_once(on_the_chip):
+    index_ops.embedding_grads(reset=True)
+    table = jax.ShapeDtypeStruct((30522, 768), "bfloat16")
+    for _ in range(2):
+        jax.eval_shape(_embedding, jax.ShapeDtypeStruct((32, 512), "int32"),
+                       table)
+    jax.eval_shape(_embedding, jax.ShapeDtypeStruct((1, 4096), "int32"),
+                   jax.ShapeDtypeStruct((32640, 5120), "bfloat16"))
+    assert profiler.provider_stats()["embedding_grads"] == {
+        "16384 -> 30522x768 bfloat16": {
+            "route": "scatter", "ids": 16384, "table_rows": 30522,
+            "width": 768, "matmul_flops": 768111280128,
+            "est_matmul_ms": 4.267, "est_scatter_ms": 0.645,
+            "scatter_form": "sorted"},
+        "4096 -> 32640x5120 bfloat16": {
+            "route": "matmul", "ids": 4096, "table_rows": 32640,
+            "width": 5120, "matmul_flops": 1369020825600,
+            "est_matmul_ms": 7.606, "est_scatter_ms": 56.712,
+            "scatter_form": "sorted_gathered"}}
+    assert "embedding_grads" in profiler.dumps()
+    assert index_ops.embedding_grads(reset=True)
+    assert not index_ops.embedding_grads()
 
 
 def test_softmax_output_and_ctc_exist():

@@ -377,6 +377,78 @@ def test_the_falcon_h1_step_fits_the_chip_with_its_blocks_recomputed(
     assert text.count("flash_attention_fwd") >= 8
 
 
+def _embedding_fwd_bwd(one_chip, ids, rows, width):
+    """`Embedding` forward and the gradient by its table for a cotangent
+    that is an argument (a constant one would fold away), compiled."""
+    from incubator_mxnet_tpu.ops import index_ops
+
+    def run(weight, data, g):
+        out, vjp = jax.vjp(lambda w: index_ops.embedding.fn(data, w), weight)
+        return out, vjp(g)[0]
+
+    with xc.compile_cache_bypassed():
+        return jax.jit(run).lower(
+            _spec(one_chip, (rows, width), BF16), _spec(one_chip, (ids,), I32),
+            _spec(one_chip, (ids, width), BF16)).compile()
+
+
+def test_embedding_grad_at_falcons_signature_is_one_matmul(one_chip,
+                                                           for_the_chip):
+    """4,096 ids into `[32640, 5120]`: the gradient by the table is one
+    convolution fusion with the one-hot's compare fused into its operand --
+    no scatter, no `[4096, 32640]` array among the entry computation's
+    instructions, no temporary as large as the gradient (PERF.md section 6,
+    PR 35)."""
+    compiled = _embedding_fwd_bwd(one_chip, 4096, 32640, 5120)
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY "):]
+    assert "scatter" not in text
+    assert len([line for line in entry.splitlines()
+                if " fusion(" in line and "convolution" in line]) == 1
+    assert "[4096,32640]" not in entry and "[32640,4096]" not in entry
+    assert "pred[4096,32640]" in text          # inside the fusion
+    assert compiled.memory_analysis().temp_size_in_bytes < 32640 * 5120 * 2
+
+
+def test_embedding_grad_at_berts_signature_stays_the_scatter(one_chip,
+                                                             for_the_chip):
+    text = _embedding_fwd_bwd(one_chip, 16384, 30522, 768).as_text()
+    assert "scatter-add" in text and "convolution" not in text
+    assert "[16384,30522]" not in text
+
+
+@pytest.mark.parametrize("ids,rows,width", [
+    (16384, 30522, 768), (8194, 16160, 2048), (4096, 32640, 4096),
+    (4096, 32640, 5120), (4096, 32640, 4224), (4096, 32640, 4352),
+    (4096, 32640, 7808), (4096, 32640, 7936),
+    (4080, 32640, 5120), (4096, 32768, 5120), (8192, 129280, 2048)])
+def test_xla_scatter_forms_are_where_the_route_says(one_chip, ids, rows,
+                                                    width):
+    """`embedding_grad_route` models three compiled forms of the gather's
+    transpose and tells them apart by shape.  The compiler's own choice,
+    read from the program: a `sort` where the ids are sorted, a second
+    custom fusion where the updates are gathered first, neither where the
+    table is updated in place.  An XLA that draws the lines elsewhere
+    fails here, and the route's constants want a new sweep."""
+    from incubator_mxnet_tpu.ops import index_ops
+
+    def grad(data, g):
+        _, vjp = jax.vjp(
+            lambda w: jnp.take(w, data, axis=0, mode="clip"),
+            jnp.zeros((rows, width), BF16))
+        return vjp(g)[0]
+
+    text = _compile(grad, _spec(one_chip, (ids,), I32),
+                    _spec(one_chip, (ids, width), BF16))
+    entry = text[text.index("\nENTRY "):]
+    fusions = len([line for line in entry.splitlines()
+                   if "kind=kCustom" in line])
+    form = ("in_place" if " sort(" not in entry else
+            "sorted_gathered" if fusions == 2 else "sorted")
+    assert form == index_ops.embedding_grad_route(
+        ids, rows, width, BF16)["scatter_form"]
+
+
 def test_mosaic_kernel_under_a_mesh_needs_gspmd_trace(topo, for_the_chip):
     """Why `fuse.FusedTrainStep` traces its step under `gspmd_trace` when it
     is given a mesh: GSPMD cannot partition a Mosaic kernel, so a dp program
