@@ -53,6 +53,16 @@ def register_state_update(param: Parameter, new_value):
                 new_value.data if isinstance(new_value, NDArray) else new_value)
 
 
+# lists that a forward appends traced values to (``nn.record_routing``), as
+# callables that give the live list or None: what a block under recompute()
+# appends leaves its checkpointed region as further outputs of it
+_trace_sinks = []
+
+
+def register_trace_sink(live):
+    _trace_sinks.append(live)
+
+
 class _CollectStateUpdates:
     def __enter__(self):
         if not hasattr(_state_updates, "stack"):
@@ -260,11 +270,13 @@ class Block:
         ``forward`` run under ``jax.checkpoint``: the backward pass keeps
         the block's inputs and parameters and runs everything inside it
         again, so that a stack of such blocks holds one block's activations
-        at a time.  Eager calls are as before.  A block that registers
-        aux-state updates (BatchNorm's moving statistics, a router's
-        bias) cannot be run twice and is refused when it is traced.  In a
-        trace, the instructions run again carry ``rematted_computation`` in
-        their ``op_name`` (docs/observability.md)."""
+        at a time.  Eager calls are as before.  Aux-state updates that the
+        block registers (BatchNorm's moving statistics, a router's bias)
+        leave the checkpointed region as further outputs of it and are
+        registered outside, once: they carry no gradient, so the run in the
+        backward pass does not compute them again.  In a trace, the
+        instructions run again carry ``rematted_computation`` in their
+        ``op_name`` (docs/observability.md)."""
         self.__dict__["_recompute"] = bool(active)
         return self
 
@@ -272,22 +284,30 @@ class Block:
         outer = _trace_map()
         mine = [p for p in self.collect_params().values() if p in outer]
 
+        updated = []        # the parameters whose new values ``pure`` returns
+        sinks = [sink for sink in (live() for live in _trace_sinks)
+                 if sink is not None]
+        held = [len(sink) for sink in sinks]
+
         def pure(values, inputs):
             inner = dict(outer)
             inner.update(zip(mine, map(NDArray, values)))
             with _TraceParams(inner), _CollectStateUpdates() as updates:
                 out = self.forward(*map(NDArray, inputs))
-            if updates:
-                raise ValueError(
-                    f"{type(self).__name__} registers aux-state updates "
-                    f"({', '.join(sorted(p.name for p, _ in updates))}); a "
-                    "block under recompute() runs twice a step and its "
-                    "updates cannot leave the checkpointed region: ask for "
-                    "recomputation on blocks that hold no such state")
-            return jax.tree_util.tree_map(lambda o: o.data, out)
+            updated[:] = [p for p, _ in updates]
+            sunk = [sink[n:] for sink, n in zip(sinks, held)]
+            for sink, n in zip(sinks, held):
+                del sink[n:]
+            return (jax.tree_util.tree_map(lambda o: o.data, out),
+                    [jax.lax.stop_gradient(getattr(v, "data", v))
+                     for _, v in updates], sunk)
 
-        out = jax.checkpoint(pure)([outer[p].data for p in mine],
-                                   [a.data for a in args])
+        out, new_values, sunk = jax.checkpoint(pure)(
+            [outer[p].data for p in mine], [a.data for a in args])
+        for param, value in zip(updated, new_values):
+            register_state_update(param, NDArray(value))
+        for sink, values in zip(sinks, sunk):
+            sink.extend(values)
         return jax.tree_util.tree_map(NDArray, out)
 
     def summary(self, *inputs):

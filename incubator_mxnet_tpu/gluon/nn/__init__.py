@@ -10,4 +10,5 @@ from .conv_layers import (
     GlobalMaxPool1D, GlobalMaxPool2D, GlobalMaxPool3D,
     GlobalAvgPool1D, GlobalAvgPool2D, GlobalAvgPool3D, ReflectionPad2D,
 )
-from .transformer_layers import RMSNorm, SwiGLU, RoutedFFN
+from .transformer_layers import (RMSNorm, SwiGLU, ReLU2MLP, RoutedFFN,
+                                 Mamba2Mixer, GroupedQueryAttention)

@@ -7,9 +7,10 @@ them.  Three registered ops:
 * ``moe_route``  — the router: float32 sigmoid scores over *all* experts,
   the ``top_k`` largest of score + bias, normalised gates, and the
   gradient-free bias update from this chip's load.
-* ``moe_ffn``    — the held experts' SwiGLU over the rows routed to them.
-  The layer is told which experts it holds (``first``, ``count``); what the
-  absent experts would have added is left out.
+* ``moe_ffn``    — the held experts' feed-forward (SwiGLU, or ungated with
+  ``relu(·)²``) over the rows routed to them.  The layer is told which
+  experts it holds (``first``, ``count``); what the absent experts would
+  have added is left out.
 
 ``moe_ffn`` drops nothing and its device work does not follow the routing.
 All ``T·k`` assignments are ranked by expert; the rows of held experts are
@@ -25,7 +26,8 @@ buffer, further passes over the same code compute the rest (a
 ``(tokens, experts, capacity)`` tensor exists.
 
 Trace names (docs/observability.md): kernel scopes ``rope``, ``moe_route``,
-``moe_dispatch`` (ranking, gathers, combine) and ``moe_experts``.
+``moe_dispatch`` (ranking, gathers, combine) and ``moe_experts``;
+``moe_plans`` says what each traced signature of ``moe_ffn`` was cut into.
 """
 from __future__ import annotations
 
@@ -36,11 +38,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import profiler as _profiler
+from ..locks import named_lock
 from . import pallas_kernels as pk
 from .registry import register
 
 __all__ = ["rope", "moe_route", "moe_ffn", "moe_experts", "buffer_rows",
-           "TILE"]
+           "moe_plans", "TILE"]
 
 # Rows of one tile of the buffer: every held expert's group is padded to
 # it, so a tile belongs to one expert.
@@ -265,7 +269,7 @@ def _experts(a, b, tile_expert, kind, n_experts=None):
 
 
 # ======================================================================
-# moe_ffn: rank, gather, grouped SwiGLU, combine — in passes
+# moe_ffn: rank, gather, grouped feed-forward, combine — in passes
 # ======================================================================
 
 def buffer_rows(tokens, top_k, n_experts, count, factor):
@@ -351,21 +355,40 @@ def _swiglu(h):
     return gate, up, sig, gate * sig
 
 
-def _forward_pass(p, plan, x, gates, w_in, w_out, cap):
+def _activate(h, activation):
+    """The experts' hidden activation, float32, from their first product
+    ``h``: ``silu(gate) · up`` of the two halves, or ``relu(h)²``."""
+    if activation == "relu2":
+        return jnp.square(jnp.maximum(h.astype(F32), 0.0))
+    _, up, _, silu = _swiglu(h)
+    return silu * up
+
+
+def _activate_bwd(h, dact, activation):
+    """``(act, dh)``: the activation again and the gradient by ``h`` from
+    the gradient by the activation, both float32."""
+    if activation == "relu2":
+        pos = jnp.maximum(h.astype(F32), 0.0)
+        return pos * pos, 2.0 * pos * dact
+    gate, up, sig, silu = _swiglu(h)
+    return silu * up, jnp.concatenate(
+        [dact * up * sig * (1.0 + gate * (1.0 - sig)), dact * silu], axis=-1)
+
+
+def _forward_pass(p, plan, x, gates, w_in, w_out, cap, activation):
     k = gates.shape[1]
     with jax.named_scope("moe_dispatch"):
         src, te = _pass_rows(p, plan, cap)
         xb = _gather_rows(x, src // k, src >= 0)
     h = _experts(xb, w_in, te, "nn")
     with jax.named_scope("moe_experts"):
-        _, up, _, silu = _swiglu(h)
-        act = (silu * up).astype(x.dtype)
+        act = _activate(h, activation).astype(x.dtype)
     yb = _experts(act, w_out, te, "nn")
     with jax.named_scope("moe_dispatch"):
         return _combine(yb, plan, p, cap, gates.shape, gates)
 
 
-def _backward_pass(p, plan, x, gates, w_in, w_out, dy, cap):
+def _backward_pass(p, plan, x, gates, w_in, w_out, dy, cap, activation):
     """Pass ``p``'s part of every gradient; the hidden activations are
     computed again, so a pass keeps nothing between forward and backward."""
     count, k = w_in.shape[0], gates.shape[1]
@@ -381,13 +404,10 @@ def _backward_pass(p, plan, x, gates, w_in, w_out, dy, cap):
     h = _experts(xb, w_in, te, "nn")
     dact_unit = _experts(dyb, w_out, te, "nt")      # before the gate
     with jax.named_scope("moe_experts"):
-        gate, up, sig, silu = _swiglu(h)
-        act = silu * up
-        dact = dact_unit.astype(F32) * gb[:, None]
+        act, dh = _activate_bwd(h, dact_unit.astype(F32) * gb[:, None],
+                                activation)
         dgate_rows = jnp.sum(dact_unit.astype(F32) * act, axis=-1)
-        dh = jnp.concatenate(
-            [dact * up * sig * (1.0 + gate * (1.0 - sig)), dact * silu],
-            axis=-1).astype(dt)
+        dh = dh.astype(dt)
         act_gated = (act * gb[:, None]).astype(dt)
     dw_out = jnp.where(present, _experts(act_gated, dyb, te, "tn", count), 0)
     dw_in = jnp.where(present, _experts(xb, dh, te, "tn", count), 0)
@@ -412,23 +432,24 @@ def _over_passes(one_pass, passes):
                               (jnp.int32(1), one_pass(jnp.int32(0))))[1]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _routed(x, gates, w_in, w_out, plan, cap):
-    return _routed_fwd(x, gates, w_in, w_out, plan, cap)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _routed(x, gates, w_in, w_out, plan, cap, activation):
+    return _routed_fwd(x, gates, w_in, w_out, plan, cap, activation)[0]
 
 
-def _routed_fwd(x, gates, w_in, w_out, plan, cap):
+def _routed_fwd(x, gates, w_in, w_out, plan, cap, activation):
     y = _over_passes(
-        lambda p: _forward_pass(p, plan, x, gates, w_in, w_out, cap),
+        lambda p: _forward_pass(p, plan, x, gates, w_in, w_out, cap,
+                                activation),
         plan[3])
     return y.astype(x.dtype), (x, gates, w_in, w_out, plan)
 
 
-def _routed_bwd(cap, res, dy):
+def _routed_bwd(cap, activation, res, dy):
     x, gates, w_in, w_out, plan = res
     dx, dgates, dw_in, dw_out = _over_passes(
         lambda p: _backward_pass(p, plan, x, gates, w_in, w_out,
-                                 dy.astype(x.dtype), cap),
+                                 dy.astype(x.dtype), cap, activation),
         plan[3])
     return (dx.astype(x.dtype), dgates.astype(gates.dtype), dw_in, dw_out,
             None)
@@ -437,25 +458,58 @@ def _routed_bwd(cap, res, dy):
 _routed.defvjp(_routed_fwd, _routed_bwd)
 
 
+_plans = {}
+_plans_lock = named_lock("ops.moe_plans")
+
+
+def moe_plans(reset=False):
+    """``{signature: plan}`` of every ``moe_ffn`` call traced so far:
+    ``tokens``, ``top_k``, ``n_experts``, ``held``, ``assignments`` (``tokens
+    · top_k``, all of them ranked), ``buffer_rows`` and ``tile`` (every tile
+    of the buffer is computed), ``width`` (what the experts read and write),
+    ``hidden`` (their own) and ``activation``.  Counts signatures, not
+    calls.  The ``moe_plans`` provider of ``profiler.dumps()``."""
+    with _plans_lock:
+        out = {sig: dict(plan) for sig, plan in sorted(_plans.items())}
+        if reset:
+            _plans.clear()
+    return out
+
+
+_profiler.register_stats_provider("moe_plans", moe_plans)
+
+
 @register("moe_ffn")
 def moe_ffn(x, idx, gates, w_in, w_out, n_experts=None, first=0,
-            capacity_factor=1.5):
-    """The held experts' part of a routed SwiGLU layer.
+            capacity_factor=1.5, activation="swiglu"):
+    """The held experts' part of a routed feed-forward layer.
 
     ``x`` (T, H); ``idx`` (T, k) int32 over all ``n_experts``; ``gates``
     (T, k); ``w_in`` (count, H, 2·I) with gate and up projections side by
-    side; ``w_out`` (count, I, H): the weights of experts ``first`` …
-    ``first + count − 1``.  Returns ``(y (T, H), stats)`` with ``y[t] =
-    Σ_j gates[t, j] · Expert_{idx[t, j]}(x[t])`` over held experts only and
-    ``stats`` float32 ``[real rows held, rows of the buffer, passes taken,
-    largest held expert's rows / a balanced expert's]``."""
+    side for ``activation="swiglu"``, (count, H, I) for the ungated
+    ``"relu2"`` (``relu(x W_in)² W_out``); ``w_out`` (count, I, H): the
+    weights of experts ``first`` … ``first + count − 1``.  Returns ``(y (T,
+    H), stats)`` with ``y[t] = Σ_j gates[t, j] · Expert_{idx[t, j]}(x[t])``
+    over held experts only and ``stats`` float32 ``[real rows held, rows of
+    the buffer, passes taken, largest held expert's rows / a balanced
+    expert's]``."""
+    if activation not in ("swiglu", "relu2"):
+        raise ValueError(f"moe_ffn: activation {activation!r} is neither "
+                         "'swiglu' nor 'relu2'")
     count = w_in.shape[0]
     n_experts = n_experts or count
     cap = buffer_rows(x.shape[0], idx.shape[1], n_experts, count,
                       capacity_factor)
+    with _plans_lock:
+        _plans[f"t{x.shape[0]} k{idx.shape[1]} e{count}/{n_experts} "
+               f"w{x.shape[1]} i{w_out.shape[1]} {activation} {x.dtype}"] = {
+            "tokens": x.shape[0], "top_k": idx.shape[1],
+            "n_experts": n_experts, "held": count, "assignments": idx.size,
+            "buffer_rows": cap, "tile": TILE, "width": x.shape[1],
+            "hidden": w_out.shape[1], "activation": activation}
     with jax.named_scope("moe_dispatch"):
         plan = _plan(idx, first, count, cap)
-    y = _routed(x, gates, w_in, w_out, plan, cap)
+    y = _routed(x, gates, w_in, w_out, plan, cap, activation)
     sizes = plan[2].astype(F32)
     balanced = idx.size / n_experts
     stats = jnp.stack([jnp.sum(sizes), jnp.float32(cap),

@@ -148,15 +148,25 @@ def test_fused_step_recompute_matches_plain(recompute):
     assert none == 0 and some >= len(recompute)
 
 
-def test_recompute_refuses_a_block_with_aux_state_updates():
-    """BatchNorm's moving statistics cannot leave a checkpointed region."""
-    net = _net()
-    net[1].recompute()          # the BatchNorm
-    step = make_fused_train_step(
-        net, gluon.loss.SoftmaxCrossEntropyLoss(), "sgd",
-        {"learning_rate": 0.1})
-    with pytest.raises(ValueError, match="registers aux-state updates"):
-        step(*_data(bs=8))
+def test_recompute_carries_a_blocks_aux_state_updates_out():
+    """BatchNorm's moving statistics leave a checkpointed region as further
+    outputs of it: the same losses and the same statistics as without."""
+    def run(recompute):
+        mx.random.seed(0)
+        net = _net()
+        net[1].recompute(recompute)          # the BatchNorm
+        step = make_fused_train_step(
+            net, gluon.loss.SoftmaxCrossEntropyLoss(), "sgd",
+            {"learning_rate": 0.1})
+        x, y = _data(bs=8)
+        losses = [float(step(x, y)) for _ in range(3)]
+        return losses, {n: onp.asarray(v) for n, v in step.aux.items()}
+
+    (plain, stats), (again, stats_again) = run(False), run(True)
+    assert plain == again and stats
+    for name, value in stats.items():
+        assert (value == stats_again[name]).all(), name
+    assert any((v != 0).any() and (v != 1).any() for v in stats.values())
 
 
 def test_fused_step_rejects_unknown_optimizer():

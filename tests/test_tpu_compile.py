@@ -377,6 +377,102 @@ def test_the_falcon_h1_step_fits_the_chip_with_its_blocks_recomputed(
     assert text.count("flash_attention_fwd") >= 8
 
 
+def test_moe_ffn_ungated_in_a_latent_fwd_bwd(one_chip, for_the_chip):
+    """The routed layer's held experts at the other configuration's widths
+    and traffic: 4,096 tokens, top-22 over 512 experts of which 16 are held,
+    in a 1,024-wide latent, ungated ``relu²`` experts 2,688 wide: the same
+    seven grouped matmuls each way, a buffer of 7,040 rows."""
+    from incubator_mxnet_tpu.ops import moe_ops
+    s = functools.partial(_spec, one_chip)
+    ffn = functools.partial(moe_ops.moe_ffn.fn, n_experts=512, first=0,
+                            capacity_factor=1.75, activation="relu2")
+
+    def run(x, gates, w_in, w_out, idx):
+        return _fwd_bwd(lambda *d: ffn(d[0], idx, *d[1:])[0], 4)(
+            x, gates, w_in, w_out)
+
+    moe_ops.moe_plans(reset=True)
+    text = _compile(run, s((4096, 1024), BF16), s((4096, 22), F32),
+                    s((16, 1024, 2688), BF16), s((16, 2688, 1024), BF16),
+                    s((4096, 22), I32))
+    assert text.count("tpu_custom_call") == 14
+    assert "4096,16," not in text and "4096,512," not in text
+    assert moe_ops.moe_plans() == {
+        "t4096 k22 e16/512 w1024 i2688 relu2 bfloat16": {
+            "tokens": 4096, "top_k": 22, "n_experts": 512, "held": 16,
+            "assignments": 90112, "buffer_rows": 7040, "tile": 128,
+            "width": 1024, "hidden": 2688, "activation": "relu2"}}
+
+
+def test_the_nemotron_h_step_fits_the_chip_with_its_layers_recomputed(
+        one_chip, for_the_chip):
+    """The whole fused train step of ``nemotron3_super_120b`` at the cell's
+    size (1 x 4,097 tokens, one pattern period of 11 layers and the MTP
+    module at the published widths, 1.64 B parameters with their Adam
+    moments, every layer recomputed, the routed ones with their state) plans
+    under the chip's memory with room to spare — from shapes alone
+    (``jax.eval_shape`` over the configuration's ``build`` and
+    ``make_fused_train_step``: nothing is allocated) — and ``moe_plans`` and
+    ``ssm_plans`` hold the step's two signatures."""
+    import json
+    import os
+    import sys
+    from incubator_mxnet_tpu import amp
+    from incubator_mxnet_tpu.fuse import make_fused_train_step
+    from incubator_mxnet_tpu.ops import moe_ops, ssm_ops
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, repo)
+    from chipbench.configs import nemotron3_super_120b as model
+    with open(os.path.join(repo, "chipbench", "configs",
+                           "nemotron3_super_120b.json")) as f:
+        config = json.load(f)
+    held = {}
+
+    def make():
+        built = model.build(0, config)
+        amp.convert_block(built["net"], config["dtype"])
+        step = make_fused_train_step(
+            built["net"], built["loss"], built["optimizer"],
+            dict(built["optimizer_params"]))
+        held["step"] = step.step_fn
+        return step.params, step.aux, step.opt_state, step._key
+
+    *state, key = jax.eval_shape(make)
+    on_chip = lambda v: _spec(one_chip, v.shape, v.dtype)
+    moe_ops.moe_plans(reset=True)
+    ssm_ops.ssm_plans(reset=True)
+    with xc.compile_cache_bypassed():
+        compiled = jax.jit(held["step"], donate_argnums=(0, 1, 2)).lower(
+            *jax.tree_util.tree_map(on_chip, state),
+            _spec(one_chip, (1, 4097), I32),
+            _spec(one_chip, (1, 2, 4096), I32), on_chip(key)).compile()
+    plan = compiled.memory_analysis()
+    # the formula's 1,642,965,888 less the six routers' 512 biases, which
+    # are state
+    assert sum(v.size for v in state[0].values()) == 1_642_962_816
+    assert len(state[1]) == 12
+    assert plan.argument_size_in_bytes > 9.1 * 2 ** 30
+    assert plan.alias_size_in_bytes > 9.1 * 2 ** 30        # donated
+    peak = (plan.argument_size_in_bytes + plan.output_size_in_bytes
+            - plan.alias_size_in_bytes + plan.temp_size_in_bytes)
+    # what memory_stats()["bytes_limit"] reads on the chip (PR 34)
+    assert peak < (15.748 - 3.0) * 2 ** 30, peak / 2 ** 30
+    assert list(moe_ops.moe_plans()) == [
+        "t4096 k22 e16/512 w1024 i2688 relu2 bfloat16"]
+    assert moe_ops.moe_plans()[
+        "t4096 k22 e16/512 w1024 i2688 relu2 bfloat16"]["buffer_rows"] == 7040
+    assert ssm_ops.ssm_plans() == {
+        "b1 t4096 h128x64 g8 n128 bfloat16": {
+            "chunk": 128, "chunks": 32, "heads_a_step": 128,
+            "state_bytes_saved": 4 * 32 * 128 * 64 * 128, "padded_rows": 0}}
+    text = compiled.as_text()
+    assert "rematted_computation" in text
+    # 2 attention layers forward, again and backward; 6 routed layers'
+    # grouped matmuls
+    assert text.count("flash_attention_fwd") >= 4
+    assert "moe_experts_fwd" in text and "moe_experts_bwd_dw" in text
+
+
 def _embedding_fwd_bwd(one_chip, ids, rows, width):
     """`Embedding` forward and the gradient by its table for a cotangent
     that is an argument (a constant one would fold away), compiled."""
