@@ -42,7 +42,8 @@ from .. import profiler as _profiler
 from ..locks import named_lock
 
 __all__ = ["fused_softmax", "fused_layer_norm", "flash_attention",
-           "dispatch", "kernel_name", "kernel_routes", "attention_plans",
+           "dispatch", "route", "kernel_name", "kernel_routes",
+           "attention_plans",
            "repeat_kv_heads",
            "interpret_mode",
            "gspmd_trace", "fused_softmax_xent", "fused_rms_norm"]
@@ -75,6 +76,29 @@ def gspmd_trace(over_mesh=True):
         _trace.gspmd = was
 
 
+_FORCED = ("1", "true", "on")
+
+
+def _flag():
+    return os.environ.get("MXNET_USE_PALLAS", "auto").lower()
+
+
+def route(unless=None):
+    """What :func:`dispatch` decides for a call whose op gave ``unless``:
+    ``"kernel"`` or ``"xla:<why>"``."""
+    if unless is not None:
+        return "xla:" + unless
+    if _flag() in ("0", "false", "off"):
+        return "xla:flag"
+    if _flag() in _FORCED:
+        return "kernel"
+    if jax.default_backend() != "tpu":
+        return "xla:no_tpu"
+    if getattr(_trace, "gspmd", False):
+        return "xla:gspmd"
+    return "kernel"
+
+
 def dispatch(kernel, xla, *args, unless=None):
     """Route one op call to ``kernel(*args)`` (a Pallas wrapper) or to
     ``xla(*args)`` (its XLA composition, same contract), from what can
@@ -95,27 +119,13 @@ def dispatch(kernel, xla, *args, unless=None):
     Every decision is counted by kernel name and reason
     (:func:`kernel_routes`), when the call is traced.
     """
-    flag = os.environ.get("MXNET_USE_PALLAS", "auto").lower()
-    forced = flag in ("1", "true", "on")
-    name = kernel_name(kernel)
-    if unless is not None:
-        route = "xla:" + unless
-    elif flag in ("0", "false", "off"):
-        route = "xla:flag"
-    elif forced:
-        route = "kernel"
-    elif jax.default_backend() != "tpu":
-        route = "xla:no_tpu"
-    elif getattr(_trace, "gspmd", False):
-        route = "xla:gspmd"
-    else:
-        route = "kernel"
+    chosen, name = route(unless), kernel_name(kernel)
     with _routes_lock:
-        _routes[name][route] += 1
+        _routes[name][chosen] += 1
     with jax.named_scope(name):
-        if route != "kernel":
+        if chosen != "kernel":
             return xla(*args)
-        if forced:
+        if _flag() in _FORCED:
             return kernel(*args)
         return jax.lax.platform_dependent(*args, tpu=kernel, default=xla)
 

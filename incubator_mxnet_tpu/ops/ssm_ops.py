@@ -30,14 +30,24 @@ no multiple of the chunk is padded with ``Δ = 0`` rows, which leave the
 state alone.
 
 Both ops run behind :func:`pallas_kernels.dispatch` under the kernel scopes
-``ssd_scan`` and ``causal_conv1d`` as compositions that have no kernel yet
-(``xla:no_kernel`` in ``kernel_routes``); ``ssm_plans`` says what each traced
-signature of the scan was cut into (docs/observability.md).
+``ssd_scan`` and ``causal_conv1d``.  The convolution is a composition that has
+no kernel yet (``xla:no_kernel`` in ``kernel_routes``).  The scan has both
+sides: the composition below (the CPU, a mesh, shapes the kernels do not
+take, and the tests' parity reference) and a Pallas pair, ``ssd_scan_fwd`` and
+``ssd_scan_bwd``, that computes the same products with the same casts and
+keeps the same float32 entry states, but never writes a chunk's decay matrix,
+its scores or their cotangents to HBM.  Which one a call takes goes by its
+shape and dtype alone (:class:`_ScanPlan`); ``ssm_plans`` says what each
+traced signature of the scan got (docs/observability.md).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .. import profiler as _profiler
 from ..locks import named_lock
@@ -161,17 +171,434 @@ def _ssd_bwd(res, dy):
 _ssd_chunked.defvjp(_ssd_fwd, _ssd_bwd)
 
 
+# ======================================================================
+# the kernel pair.  One grid step takes one chunk of one group's heads:
+# grid (batch, group, chunk), the chunk axis sequential — forward in order
+# with the running state in VMEM scratch, backward in reverse with what the
+# loss feels of the outgoing state there.  Nothing is cut in HBM: ``x``
+# (b, T, heads·P) is read as it lies, a block of (chunk rows, the group's
+# heads on the lanes); ``B`` and ``C`` (b, T, groups·N) likewise.  Only Δ,
+# a sixty-fourth of ``x`` or less, is laid out twice outside, a group's
+# heads on the lanes (b, g, T, j) and on the sublanes (b, g, j, T): a decay
+# ``exp(cum_t − cum_s)`` needs ``cum`` down the rows and along them, and
+# both running sums are products with a triangle of ones on the MXU.
+#
+# Inside a step the lanes are walked a tile at a time (``_ScanPlan.tile``:
+# a head of 128 lanes or more, or the 128 // P heads that share 128 lanes,
+# so every slice of a block is whole lane tiles).  A head's (Q, Q) decay
+# matrix, its decay-weighted scores and their cotangents never leave the
+# core; what goes to HBM is ``y`` and the float32 entry state of each chunk
+# (the residual the composition keeps too), and from the backward ``dx``,
+# ``dΔ``, ``dB`` and ``dC`` (summed over the group's heads in the step)
+# once, ``dA`` and ``dD`` as each chunk's share (XLA adds them up).
+# ======================================================================
+
+_SCAN_VMEM = 32 * 1024 * 1024
+_SCAN_VMEM_MOST = 64 * 1024 * 1024
+_SCAN_VMEM_OWN = 6 * 1024 * 1024
+# The backward kernel takes a group whose float32 state is at most this.
+# At Falcon-H1's shape (16 heads x 128 x 256: 2 MiB) it runs alone, at any
+# address, and inside a step of two blocks, but the cell's whole step of four
+# blocks does not come back from its first call with it, nor with 8 heads a
+# step (my chip runs, PR 37: seven attempts; unexplained, PERF.md section 7).
+# So that shape keeps the composition's backward under the forward kernel;
+# Nemotron-H's (16 x 64 x 128: 0.5 MiB) takes the pair.
+_SCAN_BWD_STATE_MOST = 1024 * 1024
+
+
+class _ScanPlan:
+    """What one ``ssd_scan`` signature is cut into, and whether the kernel
+    pair takes it (``why_not`` is None) or the composition does (``"shape"``
+    or ``"dtype"``: the ``unless=`` of the dispatch).  The pair takes chunks
+    of whole lane tiles, a state of whole lane tiles, heads of whole lane
+    tiles or that share one evenly, bfloat16 or float32, and a group's
+    heads a step if their blocks fit the VMEM bill; ``backward_kernel`` says
+    whether the backward is the pair's or the composition's from the
+    forward kernel's residuals (``_SCAN_BWD_STATE_MOST``)."""
+
+    def __init__(self, batch, t, heads, p, groups, n, chunk, dtype):
+        self.batch, self.t, self.heads, self.p = batch, t, heads, p
+        self.groups, self.n, self.chunk = groups, n, chunk
+        self.dtype = jnp.dtype(dtype)
+        self.chunks = -(-t // chunk)
+        self.padded = self.chunks * chunk - t
+        self.per_group = j = heads // groups
+        self.tile = max(p, 128)
+        item = self.dtype.itemsize
+        wide, state = chunk * j * p * item, 4 * j * p * n
+        small = 2 * chunk * n * item + 8 * chunk * max(j, 8)
+        # a kernel's bill: its pipelined blocks twice, its scratch once, and
+        # the step's own values (a tile's float32 temporaries, a few (Q, Q)
+        # float32 matrices)
+        own = _SCAN_VMEM_OWN + 32 * chunk * chunk
+        self.vmem_fwd = 2 * (2 * wide + small + state) + state + own
+        self.backward_kernel = state <= _SCAN_BWD_STATE_MOST
+        self.vmem_bwd = (2 * (3 * wide + 2 * small + state) + state + own
+                         if self.backward_kernel else 0)
+        if self.dtype not in (jnp.dtype(jnp.bfloat16), jnp.dtype(F32)):
+            self.why_not = "dtype"
+        elif (chunk % 128 or n % 128 or p % 8 or self.tile % p
+              or j % (self.tile // p)
+              or max(self.vmem_fwd, self.vmem_bwd) > _SCAN_VMEM_MOST):
+            self.why_not = "shape"
+        else:
+            self.why_not = None
+
+    def __hash__(self):
+        return hash((self.signature(), self.chunk))
+
+    def __eq__(self, other):
+        return (self.signature(), self.chunk) == (other.signature(),
+                                                  other.chunk)
+
+    def signature(self):
+        return (f"b{self.batch} t{self.t} h{self.heads}x{self.p} "
+                f"g{self.groups} n{self.n} {self.dtype}")
+
+    def cut(self, v, *tail):
+        """(b, whole chunks, ...) → (b, chunks, chunk) + ``tail`` (or the
+        axes ``v`` has after the positions): the composition's view."""
+        return v.reshape((self.batch, self.chunks, self.chunk)
+                         + (tail or v.shape[2:]))
+
+    def stats(self, route):
+        """The ``ssm_plans`` entry of this signature on ``route``."""
+        steps = self.batch * self.groups * self.chunks
+        kernel = route == "kernel"
+        return {"chunk": self.chunk, "chunks": self.chunks,
+                "heads_a_step": self.per_group if kernel else self.heads,
+                "state_bytes_saved": 4 * self.batch * self.chunks
+                * self.heads * self.p * self.n,
+                "padded_rows": self.padded, "route": route,
+                "grid_steps_fwd": steps if kernel else 0,
+                "grid_steps_bwd": steps if kernel and self.backward_kernel
+                else 0,
+                "vmem_bytes": max(self.vmem_fwd, self.vmem_bwd)
+                if kernel else 0}
+
+
+def _dot(a, b, dims):
+    return pk._attn_dot(a, b.astype(a.dtype), dims)
+
+
+def _chunk_decays(dtc_ref, dtr_ref, ar_ref, ac_ref):
+    """Δ (Q, j), the triangle ``t ≥ s`` and the running sum of ``Δ A``
+    inside the chunk in both layouts, (Q, j) and (j, Q): products with the
+    triangle's ones at full float32 precision."""
+    dt = dtc_ref[...]
+    q = dt.shape[0]
+    ahead = (jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
+             >= jax.lax.broadcasted_iota(jnp.int32, (q, q), 1))
+    ones = ahead.astype(F32)
+    cum = _dot(ones, dt * ar_ref[...], pk._NN)
+    cum_r = _dot(dtr_ref[...] * ac_ref[...], ones, pk._NT)
+    return dt, ahead, cum, cum_r
+
+
+def _whole_decay(dtr_ref, ac_ref, n):
+    """(j, N): a head's ``exp(cum_end)`` along a whole row, as the state's
+    rows take it (a sum on the MXU again: Mosaic broadcasts one value along
+    the lanes or down the sublanes, not both)."""
+    a_r = dtr_ref[...] * ac_ref[...]
+    return jnp.exp(_dot(a_r, jnp.ones((a_r.shape[1], n), F32), pk._NN))
+
+
+def _decay_of(head, ahead, cum, cum_r):
+    """A head's (Q, Q) float32 ``exp(cum_t − cum_s)`` for ``t ≥ s``, else 0:
+    the difference first, so no exponent is above 0."""
+    return jnp.exp(jnp.where(
+        ahead, cum[:, head:head + 1] - cum_r[head:head + 1, :], -jnp.inf))
+
+
+class _Tile:
+    """Lane tile ``k`` of a step: its lanes in a (Q, j·P) block, which are
+    its rows in a (j·P, N) state, and the heads that share it."""
+
+    def __init__(self, k, q, tile, p):
+        self.q, self.tile, self.p = q, tile, p
+        self.lanes = slice(k * tile, (k + 1) * tile)
+        self.heads = range(k * (tile // p), (k + 1) * (tile // p))
+        self.lane = (jax.lax.broadcasted_iota(jnp.int32, (q, tile), 1)
+                     if len(self.heads) > 1 else None)
+
+    def rows(self, head, of_block=False):
+        """A head's rows in this tile's (tile, N) slice of a state, or in
+        the whole block's."""
+        at = head * self.p - (0 if of_block else self.lanes.start)
+        return slice(at, at + self.p)
+
+    def only(self, head, v):
+        """``v`` (Q, tile) with the other heads' lanes at zero."""
+        if self.lane is None:
+            return v
+        mine = self.rows(head)
+        return jnp.where((self.lane >= mine.start) & (self.lane < mine.stop),
+                         v, jnp.zeros_like(v))
+
+    def spread(self, v):
+        """(Q, j), a value a head → (Q, tile): a head's column over its own
+        lanes."""
+        out = None
+        for head in reversed(self.heads):
+            col = jnp.broadcast_to(v[:, head:head + 1], (self.q, self.tile))
+            out = col if out is None else jnp.where(
+                self.lane < self.rows(head).stop, col, out)
+        return out
+
+    def sums(self, head, v):
+        """(Q, 1): ``v`` (Q, tile) summed over a head's lanes."""
+        return jnp.sum(self.only(head, v), axis=1, keepdims=True)
+
+
+def _scan_fwd_kernel(dtc_ref, dtr_ref, ar_ref, ac_ref, d_ref, x_ref, b_ref,
+                     c_ref, y_ref, entry_ref, state, *, p, tile):
+    q, lanes = x_ref.shape
+    n, dtype = b_ref.shape[1], x_ref.dtype
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        state[...] = jnp.zeros(state.shape, F32)
+
+    dt, ahead, cum, cum_r = _chunk_decays(dtc_ref, dtr_ref, ar_ref, ac_ref)
+    grow = jnp.exp(cum)
+    keep = jnp.exp(cum[q - 1:q] - cum) * dt
+    whole = _whole_decay(dtr_ref, ac_ref, n)
+    b_, c_ = b_ref[...], c_ref[...]
+    scores = _dot(c_, b_, pk._NT)
+    for k in range(lanes // tile):
+        tl = _Tile(k, q, tile, p)
+        xf = x_ref[:, tl.lanes].astype(F32)
+        entry = state[tl.lanes, :]
+        entry_ref[tl.lanes, :] = entry
+        y = (_dot(c_, entry.astype(dtype), pk._NT) * tl.spread(grow)
+             + d_ref[:, tl.lanes] * xf)
+        xdt = (xf * tl.spread(dt)).astype(dtype)
+        for head in tl.heads:
+            mixed = (scores * _decay_of(head, ahead, cum, cum_r)).astype(dtype)
+            y += _dot(mixed, tl.only(head, xdt), pk._NN)
+        y_ref[:, tl.lanes] = y.astype(dtype)
+        own = _dot((xf * tl.spread(keep)).astype(dtype), b_, pk._TN)
+        for head in tl.heads:
+            mine = tl.rows(head)
+            state[tl.rows(head, of_block=True), :] = (
+                whole[head:head + 1] * entry[mine] + own[mine])
+
+
+def _scan_bwd_kernel(dtc_ref, dtr_ref, ar_ref, ac_ref, d_ref, x_ref, b_ref,
+                     c_ref, entry_ref, dy_ref, dx_ref, db_ref, dc_ref,
+                     ddt_ref, da_ref, dd_ref, felt, *, p, tile):
+    """One chunk of the mirror recurrence; ``felt`` is what the loss feels
+    of the state this chunk leaves behind."""
+    q, lanes = x_ref.shape
+    n, dtype, j = b_ref.shape[1], x_ref.dtype, dtc_ref.shape[1]
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        felt[...] = jnp.zeros(felt.shape, F32)
+
+    dt, ahead, cum, cum_r = _chunk_decays(dtc_ref, dtr_ref, ar_ref, ac_ref)
+    grow = jnp.exp(cum)
+    tail = jnp.exp(cum[q - 1:q] - cum)
+    whole = _whole_decay(dtr_ref, ac_ref, n)
+    b_, c_ = b_ref[...], c_ref[...]
+    scores = _dot(c_, b_, pk._NT)
+    d_scores = jnp.zeros((q, q), F32)
+    d_b = jnp.zeros((q, n), F32)
+    d_c = jnp.zeros((q, n), F32)
+    d_cum = jnp.zeros((q, j), F32)
+    d_cum_r = jnp.zeros((j, q), F32)
+    d_dt = jnp.zeros((q, j), F32)
+    d_keep = jnp.zeros((q, j), F32)
+    d_total = jnp.zeros((1, j), F32)
+    head_of = jax.lax.broadcasted_iota(jnp.int32, (q, j), 1)
+    head_of_r = jax.lax.broadcasted_iota(jnp.int32, (j, q), 0)
+    for k in range(lanes // tile):
+        tl = _Tile(k, q, tile, p)
+        dy = dy_ref[:, tl.lanes]
+        xf, dyf = x_ref[:, tl.lanes].astype(F32), dy.astype(F32)
+        entry, out = entry_ref[tl.lanes, :], felt[tl.lanes, :]
+        entry_low, out_low = entry.astype(dtype), out.astype(dtype)
+        dt_w, tail_w = tl.spread(dt), tl.spread(tail)
+        xdt = (xf * dt_w).astype(dtype)
+        d_inner = dyf * tl.spread(grow)
+        d_inner_low = d_inner.astype(dtype)
+        through = d_inner * _dot(c_, entry_low, pk._NT)
+        d_c += _dot(d_inner_low, entry_low, pk._NN)
+        d_entry = _dot(d_inner_low, c_, pk._TN)
+        d_b += _dot((xf * (tail_w * dt_w)).astype(dtype), out_low, pk._NN)
+        d_xdt = jnp.zeros((q, tile), F32)
+        for head in tl.heads:
+            decay = _decay_of(head, ahead, cum, cum_r)
+            mixed = (scores * decay).astype(dtype)
+            dy_h = tl.only(head, dy)
+            d_sc = _dot(dy_h, xdt, pk._NT) * decay
+            d_xdt += _dot(mixed, dy_h, pk._TN)
+            d_scores += d_sc
+            # what cum feels through the decays: as cum_t along a row, as
+            # −cum_s down a column, both from the one float32 matrix (the
+            # two nearly cancel in dA, and must to the last bit)
+            pull = d_sc * scores
+            d_cum = jnp.where(
+                head_of == head, jnp.sum(pull, axis=1, keepdims=True)
+                + tl.sums(head, through), d_cum)
+            d_cum_r = jnp.where(head_of_r == head,
+                                jnp.sum(pull, axis=0, keepdims=True), d_cum_r)
+        kept = _dot(b_, out_low, pk._NT)
+        z = d_xdt + kept * tail_w
+        dx_ref[:, tl.lanes] = (dt_w * z + d_ref[:, tl.lanes] * dyf
+                               ).astype(dtype)
+        dd_ref[:, tl.lanes] = jnp.sum(dyf * xf, axis=0, keepdims=True)
+        xz, xkept = xf * z, xf * kept
+        for head in tl.heads:
+            d_dt = jnp.where(head_of == head, tl.sums(head, xz), d_dt)
+            d_keep = jnp.where(head_of == head, tl.sums(head, xkept), d_keep)
+            mine = tl.rows(head)
+            passed = whole[head:head + 1] * out[mine]
+            felt[tl.rows(head, of_block=True), :] = passed + d_entry[mine]
+            d_total = jnp.where(
+                head_of[:1] == head,
+                jnp.sum(jnp.sum(passed * entry[mine], axis=0, keepdims=True),
+                        axis=1, keepdims=True), d_total)
+    # the chunk's own state holds exp(cum_end − cum_s) Δ_s x_s: cum_end
+    # feels those weights' pull and the entry state's decay, −cum_s its own
+    # weight's.  Then back through the running sum (Σ over t ≥ s), cum_end's
+    # share to every position.
+    pull = dt * tail * d_keep
+    behind = (jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
+              <= jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)).astype(F32)
+    d_a = (_dot(behind, d_cum - pull, pk._NN) - _dot(behind, d_cum_r, pk._NT)
+           + d_total + jnp.sum(pull, axis=0, keepdims=True))
+    ddt_ref[...] = d_dt + ar_ref[...] * d_a
+    da_ref[...] = jnp.sum(d_a * dt, axis=0, keepdims=True)
+    d_scores_low = d_scores.astype(dtype)
+    db_ref[...] = (d_b + _dot(d_scores_low, c_, pk._TN)).astype(dtype)
+    dc_ref[...] = (d_c + _dot(d_scores_low, b_, pk._NN)).astype(dtype)
+
+
+def _scan_call(kernel, name, pn, vmem, reverse, ins, outs, scratch, operands):
+    """The ``pallas_call`` of one kernel of the pair over grid (batch,
+    group, chunk), chunks in reverse for the backward.  ``ins`` and ``outs``
+    name each operand's kind of block."""
+    q, j, n, lanes = pn.chunk, pn.per_group, pn.n, pn.per_group * pn.p
+    at = (lambda c: pn.chunks - 1 - c) if reverse else (lambda c: c)
+    t = pn.chunks * q
+    # kind: (array shape, dtype, block, index map)
+    kinds = {
+        # a chunk's rows of a group: its heads' lanes, the state's width
+        "x": ((pn.batch, t, pn.heads * pn.p), pn.dtype, (None, q, lanes),
+              lambda b, g, c: (b, at(c), g)),
+        "n": ((pn.batch, t, pn.groups * n), pn.dtype, (None, q, n),
+              lambda b, g, c: (b, at(c), g)),
+        "state": ((pn.batch, pn.chunks, pn.heads * pn.p, n), F32,
+                  (None, None, lanes, n), lambda b, g, c: (b, at(c), g, 0)),
+        # Δ with the group's heads on the lanes, and on the sublanes
+        "dtc": ((pn.batch, pn.groups, t, j), F32, (None, None, q, j),
+                lambda b, g, c: (b, g, at(c), 0)),
+        "dtr": ((pn.batch, pn.groups, j, t), F32, (None, None, j, q),
+                lambda b, g, c: (b, g, 0, at(c))),
+        # a value a head: A both ways, D over the head's lanes
+        "ar": ((pn.groups, 1, j), F32, (None, 1, j),
+               lambda b, g, c: (g, 0, 0)),
+        "ac": ((pn.groups, j, 1), F32, (None, j, 1),
+               lambda b, g, c: (g, 0, 0)),
+        "d": ((pn.groups, 1, lanes), F32, (None, 1, lanes),
+              lambda b, g, c: (g, 0, 0)),
+        # a chunk's share of a sum over positions (XLA adds them up)
+        "sum_j": ((pn.batch, pn.groups, pn.chunks, 1, j), F32,
+                  (None, None, None, 1, j), lambda b, g, c: (b, g, c, 0, 0)),
+        "sum_x": ((pn.batch, pn.groups, pn.chunks, 1, lanes), F32,
+                  (None, None, None, 1, lanes),
+                  lambda b, g, c: (b, g, c, 0, 0)),
+    }
+    spec = lambda kind: pl.BlockSpec(*kinds[kind][2:],
+                                     memory_space=pltpu.VMEM)
+    return pl.pallas_call(
+        functools.partial(kernel, p=pn.p, tile=pn.tile),
+        out_shape=[jax.ShapeDtypeStruct(*kinds[o][:2]) for o in outs],
+        grid=(pn.batch, pn.groups, pn.chunks),
+        in_specs=[spec(i) for i in ins], out_specs=[spec(o) for o in outs],
+        scratch_shapes=scratch,
+        interpret=pk.interpret_mode(),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=min(_SCAN_VMEM_MOST, max(_SCAN_VMEM, vmem)),
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name=name,
+    )(*operands)
+
+
+_DECAYS = ("dtc", "dtr", "ar", "ac", "d")
+
+
+def _decay_operands(pn, dt, a, d):
+    """Δ (b, T, heads), ``A`` and ``D`` (heads,) as the kernels read them."""
+    by_group = dt.reshape(pn.batch, -1, pn.groups, pn.per_group)
+    return (by_group.transpose(0, 2, 1, 3), by_group.transpose(0, 2, 3, 1),
+            a.reshape(pn.groups, 1, -1), a.reshape(pn.groups, -1, 1),
+            jnp.repeat(d, pn.p).reshape(pn.groups, 1, -1))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _ssd_kernels(pn, x, dt, a, b_, c_, d):
+    """The scan over whole chunks: ``x`` (b, T, heads·P), ``dt`` (b, T,
+    heads) float32, ``a`` and ``d`` (heads,) float32, ``b_`` and ``c_``
+    (b, T, groups·N)."""
+    return _ssd_kernels_fwd(pn, x, dt, a, b_, c_, d)[0]
+
+
+def _ssd_kernels_fwd(pn, x, dt, a, b_, c_, d):
+    lanes = pn.per_group * pn.p
+    y, entry = _scan_call(
+        _scan_fwd_kernel, "ssd_scan_fwd", pn, pn.vmem_fwd, False,
+        _DECAYS + ("x", "n", "n"), ("x", "state"),
+        [pltpu.VMEM((lanes, pn.n), F32)],
+        _decay_operands(pn, dt, a, d) + (x, b_, c_))
+    return y, (x, dt, a, b_, c_, d, entry)
+
+
+def _ssd_kernels_bwd(pn, res, dy):
+    x, dt, a, b_, c_, d, entry = res
+    if not pn.backward_kernel:
+        # the composition's backward from the same residuals, cut its way
+        by_group = (pn.groups, pn.per_group)
+        grads = _ssd_bwd(
+            (pn.cut(x, *by_group, pn.p), pn.cut(dt, *by_group),
+             a.reshape(by_group), pn.cut(b_, pn.groups, pn.n),
+             pn.cut(c_, pn.groups, pn.n), d.reshape(by_group),
+             entry.reshape((pn.batch, pn.chunks) + by_group + (pn.p, pn.n))),
+            pn.cut(dy.astype(x.dtype), *by_group, pn.p))
+        return tuple(g.reshape(v.shape) for g, v in zip(grads, res))
+    lanes = pn.per_group * pn.p
+    dx, db, dc, ddt, da, dd = _scan_call(
+        _scan_bwd_kernel, "ssd_scan_bwd", pn, pn.vmem_bwd, True,
+        _DECAYS + ("x", "n", "n", "state", "x"),
+        ("x", "n", "n", "dtc", "sum_j", "sum_x"),
+        [pltpu.VMEM((lanes, pn.n), F32)],
+        _decay_operands(pn, dt, a, d) + (x, b_, c_, entry,
+                                         dy.astype(x.dtype)))
+    return (dx, ddt.transpose(0, 2, 1, 3).reshape(dt.shape),
+            jnp.sum(da, axis=(0, 2)).reshape(a.shape), db, dc,
+            jnp.sum(dd, axis=(0, 2)).reshape(pn.heads, pn.p).sum(axis=1))
+
+
+_ssd_kernels.defvjp(_ssd_kernels_fwd, _ssd_kernels_bwd)
+
+
 _plans = {}
 _plans_lock = named_lock("ops.ssm_plans")
 
 
 def ssm_plans(reset=False):
     """``{signature: plan}`` of every ``ssd_scan`` call traced so far:
-    ``chunk``, ``chunks`` a sequence, ``heads_a_step`` (the composition
-    takes all heads of a chunk in one batched product), ``state_bytes_saved``
-    (the entry states the backward pass keeps) and ``padded_rows`` (the
-    ``Δ = 0`` rows that fill the last chunk).  Counts signatures, not calls.
-    The ``ssm_plans`` provider of ``profiler.dumps()``."""
+    ``chunk``, ``chunks`` a sequence, ``route`` (``kernel``, or the
+    ``xla:<why>`` that :func:`pallas_kernels.dispatch` counted),
+    ``heads_a_step`` (a group's heads in a step of the kernels; the
+    composition takes all heads of a chunk in one batched product),
+    ``grid_steps_fwd`` / ``grid_steps_bwd`` and ``vmem_bytes`` (the larger
+    bill of the two kernels; 0 on the composition), ``state_bytes_saved``
+    (the entry states the backward pass keeps, on either route) and
+    ``padded_rows`` (the ``Δ = 0`` rows that fill the last chunk).  Counts
+    signatures, not calls.  The ``ssm_plans`` provider of
+    ``profiler.dumps()``."""
     with _plans_lock:
         out = {sig: dict(plan) for sig, plan in sorted(_plans.items())}
         if reset:
@@ -195,19 +622,15 @@ def ssd_scan(x, dt, A, B, C, D, chunk=128):
     if heads % groups:
         raise ValueError(f"ssd_scan: {heads} heads do not divide into "
                          f"{groups} groups")
-    chunks = -(-t // chunk)
-    padded = chunks * chunk - t
-    with _plans_lock:
-        _plans[f"b{batch} t{t} h{heads}x{p} g{groups} n{n} {x.dtype}"] = {
-            "chunk": chunk, "chunks": chunks, "heads_a_step": heads,
-            "state_bytes_saved": 4 * batch * chunks * heads * p * n,
-            "padded_rows": padded}
+    pn = _ScanPlan(batch, t, heads, p, groups, n, chunk, x.dtype)
+    chunks, padded = pn.chunks, pn.padded
 
-    def ssd_scan(x, dt, a, b_, c_, d):
-        def cut(v, *tail):
-            v = jnp.pad(v, ((0, 0), (0, padded)) + ((0, 0),) * (v.ndim - 2))
-            return v.reshape((batch, chunks, chunk) + (tail or v.shape[2:]))
+    def fill(v):
+        """Whole chunks: ``Δ = 0`` rows after the sequence."""
+        return jnp.pad(v, ((0, 0), (0, padded)) + ((0, 0),) * (v.ndim - 2))
 
+    def ssd_scan_xla(x, dt, a, b_, c_, d):
+        cut = lambda v, *tail: pn.cut(fill(v), *tail)
         by_group = (groups, heads // groups)
         y = _ssd_chunked(cut(x, *by_group, p),
                          cut(dt.astype(F32), *by_group),
@@ -215,5 +638,14 @@ def ssd_scan(x, dt, A, B, C, D, chunk=128):
                          d.astype(F32).reshape(by_group))
         return y.reshape((batch, chunks * chunk, heads, p))[:, :t]
 
-    return pk.dispatch(ssd_scan, ssd_scan, x, dt, A, B, C, D,
-                       unless="no_kernel")
+    def ssd_scan(x, dt, a, b_, c_, d):
+        flat = lambda v: fill(v).reshape(batch, chunks * chunk, -1)
+        y = _ssd_kernels(pn, flat(x), flat(dt.astype(F32)), a.astype(F32),
+                         flat(b_.astype(x.dtype)), flat(c_.astype(x.dtype)),
+                         d.astype(F32))
+        return y.reshape((batch, chunks * chunk, heads, p))[:, :t]
+
+    with _plans_lock:
+        _plans[pn.signature()] = pn.stats(pk.route(pn.why_not))
+    return pk.dispatch(ssd_scan, ssd_scan_xla, x, dt, A, B, C, D,
+                       unless=pn.why_not)

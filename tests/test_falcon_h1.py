@@ -123,9 +123,12 @@ def test_ssd_scan_pads_with_rows_that_leave_the_state_alone():
     short = ssm_ops.ssd_scan.fn(x[:, :21], dt[:, :21], a, b[:, :21],
                                 c[:, :21], d, chunk=8)
     (plan,) = ssm_ops.ssm_plans().values()
+    # a chunk of 8 is no lane tile: the composition, all heads a step
     assert plan == {"chunk": 8, "chunks": 3, "heads_a_step": 4,
                     "state_bytes_saved": 4 * 2 * 3 * 4 * 8 * 16,
-                    "padded_rows": 3}
+                    "padded_rows": 3, "route": "xla:shape",
+                    "grid_steps_fwd": 0, "grid_steps_bwd": 0,
+                    "vmem_bytes": 0}
     whole = ssm_ops.ssd_scan.fn(x, dt, a, b, c, d, chunk=8)
     assert _rel(short, whole[:, :21]) < 1e-6
     assert "ssm_plans" in profiler.provider_stats()
@@ -399,7 +402,9 @@ def test_the_steps_names_carry_the_block_keys_and_the_kernel_scopes(config):
     assert parsed.phase == "backward" and parsed.op == "ssd_scan"
     assert parsed.blocks[0] == "layers" and parsed.blocks[1] in "01"
     routes = pk.kernel_routes()     # an op is traced once a signature
-    assert set(routes["ssd_scan"]) == {"xla:no_kernel"}
+    # the toy heads (P 8, state 16, chunks of 8) are no lane tiles: the
+    # kernel pair leaves them to the composition
+    assert set(routes["ssd_scan"]) == {"xla:shape"}
     assert set(routes["causal_conv1d"]) == {"xla:no_kernel"}
 
 

@@ -223,6 +223,8 @@ def test_a_fused_step_recomputes_layers_that_hold_a_routers_state(config):
                 42, 4, 16, 4, 1.75), "tile": 128, "width": 32, "hidden": 24,
             "activation": "relu2"}}
     assert list(ssm_ops.ssm_plans()) == ["b2 t21 h8x8 g2 n16 bfloat16"]
+    assert ssm_ops.ssm_plans()["b2 t21 h8x8 g2 n16 bfloat16"][
+        "route"] == "xla:shape"       # toy heads: the composition
     assert "moe_plans" in profiler.provider_stats()
     losses = [float(step(x, y)) for _ in range(3)]
     assert losses[-1] < losses[0]
@@ -409,6 +411,10 @@ def test_ssd_scan_at_eight_heads_of_64_in_four_groups():
         lambda *a: jnp.sum(fn(*a) * weigh), argnums=range(6))(*args)
         for fn in (chunked, _recurrence))
     assert list(ssm_ops.ssm_plans()) == ["b2 t40 h8x64 g4 n16 float32"]
+    # heads of 64 share a lane tile, but a state of 16 and chunks of 16 are
+    # none: the composition, all 8 heads a step
+    plan = ssm_ops.ssm_plans()["b2 t40 h8x64 g4 n16 float32"]
+    assert (plan["route"], plan["heads_a_step"]) == ("xla:shape", 8)
     assert _rel(chunked(*args), _recurrence(*args)) < 2e-6
     assert abs(got[0] - want[0]) < 2e-5 * abs(want[0]) + 1e-4
     for name, mine, theirs in zip("x dt A B C D".split(), got[1], want[1]):

@@ -280,30 +280,48 @@ def test_dropout_mask_is_drawn_once_and_kept(one_chip):
         "kept_bytes": 16384 * 768}
 
 
-def test_ssd_scan_fwd_bwd(one_chip, for_the_chip):
-    """The chunked state-space scan at the published widths and the
-    benchmark's length: 4,096 positions of 32 heads x 128 over a 256-wide
-    state in 2 groups, 32 chunks of 128, forward and backward.  It is the
-    XLA composition on both sides of the dispatch (no Mosaic call), keeps
-    32 float32 entry states a sequence for the backward pass and no
+# the two published shapes of the scan: Falcon-H1's 32 heads of 128 over a
+# 256-wide state in 2 groups, Nemotron-H's 128 heads of 64 over a 128-wide
+# state in 8 groups; 16 heads a group in both
+@pytest.mark.parametrize("heads,p,groups,n", [(32, 128, 2, 256),
+                                              (128, 64, 8, 128)])
+def test_ssd_scan_fwd_bwd(one_chip, for_the_chip, heads, p, groups, n):
+    """The chunked state-space scan at a published shape and the benchmark's
+    length (4,096 positions, 32 chunks of 128), forward and backward, a
+    group's 16 heads a grid step.  Nemotron-H's shape is the Mosaic calls
+    ``ssd_scan_fwd`` and ``ssd_scan_bwd`` with the 32 float32 entry states a
+    sequence between them — no decay or score matrix of a chunk in HBM, in
+    either dtype.  Falcon-H1's, whose group's state is 2 MiB, is
+    ``ssd_scan_fwd`` and the composition's backward from the same entry
+    states (``ops/ssm_ops.py``, ``_SCAN_BWD_STATE_MOST``).  Neither has a
     (T, T) tensor."""
+    import re
     from incubator_mxnet_tpu.ops import ssm_ops
     s = functools.partial(_spec, one_chip)
     ssm_ops.ssm_plans(reset=True)
+    pk.kernel_routes(reset=True)
     text = _compile(
         _fwd_bwd(functools.partial(ssm_ops.ssd_scan.fn, chunk=128), 6),
-        s((1, 4096, 32, 128), BF16), s((1, 4096, 32), F32), s((32,), F32),
-        s((1, 4096, 2, 256), BF16), s((1, 4096, 2, 256), BF16),
-        s((32,), F32))
-    assert "tpu_custom_call" not in text and "4096,4096]" not in text
-    assert ssm_ops.ssm_plans() == {
-        "b1 t4096 h32x128 g2 n256 bfloat16": {
-            "chunk": 128, "chunks": 32, "heads_a_step": 32,
-            "state_bytes_saved": 4 * 32 * 32 * 128 * 256, "padded_rows": 0}}
-    # the entry states (chunk, batch, group, head, P, N) are float32, the
-    # decay-weighted scores (chunk, group, head, Q, Q) go to the MXU bfloat16
-    assert "f32[32,1,2,16,128,256]" in text
-    assert "bf16[32,2,16,128,128]" in text
+        s((1, 4096, heads, p), BF16), s((1, 4096, heads), F32),
+        s((heads,), F32), s((1, 4096, groups, n), BF16),
+        s((1, 4096, groups, n), BF16), s((heads,), F32))
+    pair = heads == 128
+    assert text.count("tpu_custom_call") == (2 if pair else 1)
+    assert "ssd_scan_fwd" in text and ("ssd_scan_bwd" in text) == pair
+    # nothing (T, T) but Falcon-H1's x itself, whose heads are 4,096 wide
+    assert set(re.findall(r"\[[\d,]*4096,4096\]", text)) <= {"[1,4096,4096]"}
+    assert bool(re.search(r"(f32|bf16)\[[\d,]*128,128\]", text)) != pair
+    assert f"f32[1,32,{heads * p},{n}]" in text     # the entry states
+    assert pk.kernel_routes()["ssd_scan"] == {"kernel": 1}
+    (plan,) = ssm_ops.ssm_plans().values()
+    assert list(ssm_ops.ssm_plans()) == [
+        f"b1 t4096 h{heads}x{p} g{groups} n{n} bfloat16"]
+    assert 8 * 2 ** 20 < plan.pop("vmem_bytes") < 32 * 2 ** 20
+    assert plan == {
+        "chunk": 128, "chunks": 32, "heads_a_step": 16, "route": "kernel",
+        "state_bytes_saved": 4 * 32 * heads * p * n, "padded_rows": 0,
+        "grid_steps_fwd": 32 * groups,
+        "grid_steps_bwd": 32 * groups if pair else 0}
 
 
 def test_flash_attention_with_fewer_key_heads(one_chip, for_the_chip):
@@ -333,12 +351,13 @@ def test_the_falcon_h1_step_fits_the_chip_with_its_blocks_recomputed(
     parameters with their Adam moments) plans under the chip's memory with
     half a GiB to spare — from shapes alone (``jax.eval_shape`` over the
     configuration's ``build`` and ``make_fused_train_step``: nothing is
-    allocated)."""
+    allocated) — and ``ssm_plans`` holds the step's one signature."""
     import json
     import os
     import sys
     from incubator_mxnet_tpu import amp
     from incubator_mxnet_tpu.fuse import make_fused_train_step
+    from incubator_mxnet_tpu.ops import ssm_ops
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     sys.path.insert(0, repo)
     from chipbench.configs import falcon_h1_34b as model
@@ -358,6 +377,7 @@ def test_the_falcon_h1_step_fits_the_chip_with_its_blocks_recomputed(
 
     *state, key = jax.eval_shape(make)
     on_chip = lambda v: _spec(one_chip, v.shape, v.dtype)
+    ssm_ops.ssm_plans(reset=True)
     with xc.compile_cache_bypassed():
         compiled = jax.jit(held["step"], donate_argnums=(0, 1, 2)).lower(
             *jax.tree_util.tree_map(on_chip, state),
@@ -375,6 +395,16 @@ def test_the_falcon_h1_step_fits_the_chip_with_its_blocks_recomputed(
     assert "rematted_computation" in text
     # 4 blocks' attention forward, again and backward, and the norms
     assert text.count("flash_attention_fwd") >= 8
+    # and their scans, a group's 16 heads a step of the forward kernel
+    # (this shape's backward is the composition's: _SCAN_BWD_STATE_MOST)
+    assert text.count("ssd_scan_fwd") >= 8 and "ssd_scan_bwd" not in text
+    (plan,) = ssm_ops.ssm_plans().values()
+    assert list(ssm_ops.ssm_plans()) == ["b1 t4096 h32x128 g2 n256 bfloat16"]
+    assert plan == {
+        "chunk": 128, "chunks": 32, "heads_a_step": 16, "route": "kernel",
+        "state_bytes_saved": 4 * 32 * 32 * 128 * 256, "padded_rows": 0,
+        "grid_steps_fwd": 64, "grid_steps_bwd": 0,
+        "vmem_bytes": plan["vmem_bytes"]}
 
 
 def test_moe_ffn_ungated_in_a_latent_fwd_bwd(one_chip, for_the_chip):
@@ -461,12 +491,17 @@ def test_the_nemotron_h_step_fits_the_chip_with_its_layers_recomputed(
         "t4096 k22 e16/512 w1024 i2688 relu2 bfloat16"]
     assert moe_ops.moe_plans()[
         "t4096 k22 e16/512 w1024 i2688 relu2 bfloat16"]["buffer_rows"] == 7040
-    assert ssm_ops.ssm_plans() == {
-        "b1 t4096 h128x64 g8 n128 bfloat16": {
-            "chunk": 128, "chunks": 32, "heads_a_step": 128,
-            "state_bytes_saved": 4 * 32 * 128 * 64 * 128, "padded_rows": 0}}
+    (plan,) = ssm_ops.ssm_plans().values()
+    assert list(ssm_ops.ssm_plans()) == ["b1 t4096 h128x64 g8 n128 bfloat16"]
+    assert plan == {
+        "chunk": 128, "chunks": 32, "heads_a_step": 16, "route": "kernel",
+        "state_bytes_saved": 4 * 32 * 128 * 64 * 128, "padded_rows": 0,
+        "grid_steps_fwd": 256, "grid_steps_bwd": 256,
+        "vmem_bytes": plan["vmem_bytes"]}
     text = compiled.as_text()
     assert "rematted_computation" in text
+    # five mixer layers: the scan's forward, again, and its backward
+    assert text.count("ssd_scan_fwd") >= 10 and "ssd_scan_bwd" in text
     # 2 attention layers forward, again and backward; 6 routed layers'
     # grouped matmuls
     assert text.count("flash_attention_fwd") >= 4
