@@ -23,6 +23,9 @@ Typical use::
     y.backward()
 """
 
+# first: this module's import reads the clock the process trace starts
+# at (trace.py), before JAX or anything else of the package is imported
+from . import trace
 from .libinfo import __version__  # single source of truth
 
 
@@ -94,7 +97,6 @@ from . import base
 from .base import MXNetError
 from . import error
 from . import fault
-from . import trace
 from . import libinfo
 from . import log
 from . import checkpoint
@@ -154,3 +156,8 @@ from .gluon import metric
 def tpu_context_available():
     """True when a real TPU backend is attached to this process."""
     return num_tpus() > 0
+
+
+# last: the package's import, from trace.py's clock reading to here
+trace.record_process_span("process.import",
+                          jax_preloaded=trace.JAX_PRELOADED)

@@ -21,6 +21,7 @@ import threading
 
 import jax.numpy as jnp
 
+from .. import trace
 from ..base import dtype_from_any
 from .loss_scaler import LossScaler
 from . import lists
@@ -155,10 +156,14 @@ def convert_block(block, target_dtype="bfloat16", target_dtype_ops=None,
     policy = CastPolicy(target_dtype, target_dtype_ops=target_dtype_ops,
                         fp32_ops=fp32_ops, widest_dtype_ops=widest_dtype_ops,
                         excluded_ops=excluded_ops)
-    for name, p in block.collect_params().items():
-        if name.endswith(_KEEP_FP32_SUFFIXES):
-            continue
-        p.cast(target_dtype)
+    with trace.process_span("amp.convert_block",
+                            dtype=str(target_dtype)) as sp:
+        cast = [p for name, p in block.collect_params().items()
+                if not name.endswith(_KEEP_FP32_SUFFIXES)]
+        for p in cast:
+            p.cast(target_dtype)
+        sp.set(leaves=len(cast), bytes=sum(
+            p._nbytes() for p in cast if p._shape_complete()))
     block._amp_policy = policy
     return block
 

@@ -47,14 +47,19 @@ minutes to seconds) stacks two layers on this choke point:
   different toolchain instead of crashing inside an unpickler.
 
 Observability: a ``cold_start`` profiler stats provider reports time
-from this module's import to first executable build, per-site build
-counts, the persistent-cache configuration, AOT load hits/failures,
-and -- under ``"jit"`` -- what JAX itself reports of every compile
+since this module's import, per-site build counts, the persistent-cache
+configuration, AOT load hits/failures, under ``"setup"`` the process
+trace folded by span name (:func:`trace.process_summary`: the import,
+each leaf's initialisation, the step's build and first call, every
+compile, with when each began and ended) and -- under ``"jit"`` -- what
+JAX itself reports of every compile
 (``jax.monitoring``): seconds tracing, lowering, in backend compile
 and retrieving from the persistent cache, and counts of compiles and
 of cache hits and misses, per jitted function (the calling
 :class:`Executor`'s site where one is calling, else the function's
-name).  The listeners run only when something compiles.
+name).  The listeners run only when something compiles; each compile
+is also the process span ``jit.compile`` and the flight recorder's
+``jit.compiled``, with its time of day.
 ``Executor.__call__`` times the call as the ``executor.call`` span of
 :mod:`.trace`.
 """
@@ -88,12 +93,11 @@ _lock = named_lock("executor.state")
 _state = {
     "cache_init_done": False,
     "cache_dir": None,
-    "first_build_ms": None,        # process start -> first Executor build
     "aot_loads": 0,
     "aot_load_failures": 0,
     "analyses": 0,
 }
-_sites: dict[str, dict] = {}       # site -> {"executors": n, "built_ms": t}
+_sites: dict[str, dict] = {}       # site -> {"executors": n}
 _provider_registered = False
 
 # what jax.monitoring reports of each compile, folded per jitted function
@@ -265,6 +269,18 @@ def _on_jit_duration(event, seconds, fun_name=None, **_):
             for field in _JIT_FIELDS:
                 into[field] += record[field]
             _jit_log.append(record)
+        # every compile of the process with its time of day, sampling on
+        # or off: in the process trace (under the span that paid it) and
+        # on the flight ring, where a postmortem looks for recompiles
+        spent = {f: record[f] for f in (
+            "trace_s", "lower_s", "backend_compile_s", "cache_retrieval_s")}
+        _trace.record_process_span(
+            "jit.compile", record["trace_s"] + record["lower_s"] + seconds,
+            fun=record["fun"], site=record["site"],
+            cache_hit=record["cache_hits"] > 0, **spent)
+        from . import flightrec as _flightrec
+        _flightrec.record(_flightrec.COMPILE, "jit.compiled",
+                          fun=record["fun"], site=record["site"], **spent)
 
 
 def _on_jit_event(event, **_):
@@ -289,7 +305,7 @@ class Executor:
     cache (the serving "must flatline after warmup" counter).
     """
 
-    __slots__ = ("site", "fn", "jfn", "donate_argnums", "_built_at")
+    __slots__ = ("site", "fn", "jfn", "donate_argnums")
 
     def __init__(self, fn, site, donate_argnums=(), in_shardings=None,
                  static_argnums=None, static_argnames=None,
@@ -324,15 +340,8 @@ class Executor:
         from . import flightrec as _flightrec
         _flightrec.record(_flightrec.COMPILE, "executor.created",
                           site=site)
-        self._built_at = time.monotonic()
         with _lock:
-            if _state["first_build_ms"] is None:
-                _state["first_build_ms"] = round(
-                    (self._built_at - _IMPORT_T0) * 1000.0, 3)
-            st = _sites.setdefault(site, {"executors": 0})
-            st["executors"] += 1
-            st["built_ms_after_import"] = round(
-                (self._built_at - _IMPORT_T0) * 1000.0, 3)
+            _sites.setdefault(site, {"executors": 0})["executors"] += 1
 
     def __call__(self, *args, **kwargs):
         """The jitted call, timed as the ``executor.call`` span; what it
@@ -677,7 +686,6 @@ def stats():
         jit["per_function"] = {k: dict(v) for k, v in _jit.items()}
         out = {
             "since_import_ms": since_import_ms(),
-            "first_executor_build_ms_after_import": _state["first_build_ms"],
             "persistent_cache_dir": _state["cache_dir"],
             "aot_loads": _state["aot_loads"],
             "aot_load_failures": _state["aot_load_failures"],
@@ -687,6 +695,7 @@ def stats():
             "per_site": per_site,
             "jit": jit,
         }
+    out["setup"] = _trace.process_summary()
     return out
 
 
@@ -698,7 +707,6 @@ def reset_stats():
         _sites.clear()
         _jit.clear()
         _jit_log.clear()
-        _state["first_build_ms"] = None
         _state["aot_loads"] = 0
         _state["aot_load_failures"] = 0
         _state["analyses"] = 0

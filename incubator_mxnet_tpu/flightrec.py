@@ -107,6 +107,7 @@ EVENTS = (
     "compile.storm",
     "executor.created",
     "fleet.rolling_reload",
+    "jit.compiled",
     "lock.order_violation",
     "model.loaded",
     "model.unloaded",

@@ -110,31 +110,55 @@ class FusedTrainStep:
         self.momentum = opt_params.get("momentum", 0.0)
         self.wd = opt_params.get("wd", 0.0)
         self.optimizer = optimizer
+        # chunk budget for the whole-loop compilation path (fuse_loop):
+        # K == 1 stays on this per-step program, K > 1 lets a
+        # ChunkedTrainLoop scan K steps per dispatch
+        self.chunk_steps = resolve_chunk_steps(chunk_steps)
+        self._site = f"fused_step:{type(block).__name__}"
+        # from a Block's parameters to a committed train state and a
+        # jitted function, as process spans (trace.py: always recorded)
+        with trace.process_span("fused_step.build", site=self._site):
+            self._init_state(block, optimizer, mesh)
+            # kept for the chunked loop (fuse_loop): the scanned program
+            # re-applies the same batch sharding to its (K, batch, ...)
+            # blocks, with the scan axis unsharded
+            self._mesh = mesh
+            self._batch_spec = batch_spec
+            self._lint_done = False
+            self._memlint_done = False
+            self._shardlint_done = False
+            with trace.process_span("fused_step.program"):
+                self._step_fn = self._build(mesh, batch_spec, donate)
+        self._first_call_pending = True
+        self._last = None
+
+    def _init_state(self, block, optimizer, mesh):
         params_all, apply_fn = block.functional()
         self._apply = apply_fn
         # split trainable vs aux (grad_req null → moving stats etc.)
         named = list(block.collect_params().items())
         self._trainable_names = [n for n, p in named if p.grad_req != "null"]
         self._aux_names = [n for n, p in named if p.grad_req == "null"]
-        # copy the initial values: the step donates its param buffers, and
-        # donating the Block's live arrays would delete them out from
-        # under any eval pass on the block itself
-        self.params = {n: jnp.array(params_all[n])
-                       for n in self._trainable_names}
-        self.aux = {n: jnp.array(params_all[n]) for n in self._aux_names}
-        if optimizer in ("sgd", "nag"):
-            self.opt_state = sgd_init(self.params)
-        elif optimizer in ("adam", "adamw"):
-            self.opt_state = adam_init(self.params)
-        else:
-            raise ValueError(
-                f"fused step supports sgd/nag/adam/adamw; got {optimizer!r} "
-                f"(use the eager Trainer for others)")
-        # chunk budget for the whole-loop compilation path (fuse_loop):
-        # K == 1 stays on this per-step program, K > 1 lets a
-        # ChunkedTrainLoop scan K steps per dispatch
-        self.chunk_steps = resolve_chunk_steps(chunk_steps)
-        self._key = jax.random.PRNGKey(0)
+        with trace.process_span("fused_step.state_copy") as sp:
+            # copy the initial values: the step donates its param buffers,
+            # and donating the Block's live arrays would delete them out
+            # from under any eval pass on the block itself
+            self.params = {n: jnp.array(params_all[n])
+                           for n in self._trainable_names}
+            self.aux = {n: jnp.array(params_all[n]) for n in self._aux_names}
+            if optimizer in ("sgd", "nag"):
+                self.opt_state = sgd_init(self.params)
+            elif optimizer in ("adam", "adamw"):
+                self.opt_state = adam_init(self.params)
+            else:
+                raise ValueError(
+                    f"fused step supports sgd/nag/adam/adamw; got "
+                    f"{optimizer!r} (use the eager Trainer for others)")
+            self._key = jax.random.PRNGKey(0)
+            state = (self.params, self.aux, self.opt_state, self._key)
+            leaves = jax.tree_util.tree_leaves(state)
+            nbytes = sum(leaf.nbytes for leaf in leaves)
+            sp.set(leaves=len(leaves), bytes=nbytes)
         # commit the whole train state to where it will run, up front:
         # jit outputs are committed arrays, so an uncommitted first
         # call would compile one executable for step 1 and a second —
@@ -146,23 +170,15 @@ class FusedTrainStep:
         # gradients of replicated parameters).
         if mesh is None:
             placement = jax.devices()[0]
+            devices = 1
         else:
             from jax.sharding import NamedSharding, PartitionSpec
             placement = NamedSharding(mesh, PartitionSpec())
-        self.params, self.aux, self.opt_state, self._key = \
-            jax.device_put(
-                (self.params, self.aux, self.opt_state, self._key),
-                placement)
-        # kept for the chunked loop (fuse_loop): the scanned program
-        # re-applies the same batch sharding to its (K, batch, ...)
-        # blocks, with the scan axis unsharded
-        self._mesh = mesh
-        self._batch_spec = batch_spec
-        self._lint_done = False
-        self._memlint_done = False
-        self._shardlint_done = False
-        self._step_fn = self._build(mesh, batch_spec, donate)
-        self._last = None
+            devices = mesh.size
+        with trace.process_span("fused_step.place", bytes=nbytes,
+                                devices=devices):
+            self.params, self.aux, self.opt_state, self._key = \
+                jax.device_put(state, placement)
 
     def _build(self, mesh, batch_spec, donate):
         loss_block = self.loss_block
@@ -228,13 +244,15 @@ class FusedTrainStep:
             bspec = NamedSharding(mesh, batch_spec or P("dp"))
             in_shardings = (None, None, None, bspec, bspec, None)
         self._executor = _xc.Executor(
-            step, f"fused_step:{type(self.block).__name__}",
+            step, self._site,
             donate_argnums=donate_argnums, in_shardings=in_shardings)
         # called through the Executor, not its bare jfn: the one choke
         # point times every jitted entry point (``executor.call``)
         return self._executor
 
     def __call__(self, x, y):
+        if self._first_call_pending:
+            return self._first_call(x, y)
         # the host's side of a step, span by span (trace.py; written
         # into the profiler's trace when a session is on): the key
         # split is a jitted program of its own, the analyses' latches
@@ -252,6 +270,19 @@ class FusedTrainStep:
                 self.params, self.aux, self.opt_state, xv, yv, sub)
         self._last = loss
         return loss
+
+    def _first_call(self, x, y):
+        """Until a call has returned: the key split, the analyses, trace,
+        lower, compile or cache read, the executable's load and the dispatch
+        of step 1, as one process span around the call's ordinary ones."""
+        self._first_call_pending = False
+        try:
+            with trace.process_span("fused_step.first_call",
+                                    site=self._site):
+                return self(x, y)
+        except BaseException:
+            self._first_call_pending = True
+            raise
 
     def _analyze(self, xv, yv, sub):
         if not (self._lint_done and self._memlint_done):

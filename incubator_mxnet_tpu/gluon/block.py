@@ -32,6 +32,7 @@ import numpy as onp
 from .. import autograd
 from .. import executor_cache as _xc
 from .. import random as _random
+from .. import trace
 from ..context import current_context
 from ..ndarray import NDArray
 from .parameter import Parameter, ParameterDict, _TraceParams, \
@@ -40,6 +41,7 @@ from .parameter import Parameter, ParameterDict, _TraceParams, \
 __all__ = ["Block", "HybridBlock", "CachedOp"]
 
 _state_updates = threading.local()
+_first_forward_open = threading.local()     # .on inside gluon.first_forward
 
 
 def register_state_update(param: Parameter, new_value):
@@ -242,7 +244,49 @@ class Block:
         return _HookHandle(self._forward_pre_hooks, hook)
 
     # -- call -------------------------------------------------------------
+    # the latch of ``gluon.first_forward``: open until the block's first
+    # call of any kind, so that every later call pays this one test
+    _first_forward_pending = True
+
+    def _first_forward(self, args, kwargs):
+        """The block's first call, which ``__call__`` hands over and which
+        calls it again with the latch closed.  Where it is eager (no parameters
+        mapped to tracers, no traced argument) and outermost (no other
+        block's first call open on this thread), it is the pass that
+        resolves the net's deferred shapes, one small program an op, and
+        runs as the process span ``gluon.first_forward`` (trace.py) with
+        the leaves that got their shape and value inside it as
+        ``deferred``; it closes the latch of every block below, called or
+        not.  Any other first call only closes this block's latch."""
+        self._first_forward_pending = False
+        if getattr(_first_forward_open, "on", False) \
+                or _trace_map() is not None or any(
+                    isinstance(getattr(a, "data", a), jax.core.Tracer)
+                    for a in args):
+            return self(*args, **kwargs)
+
+        def deferred():
+            return sum(p._deferred_init_args is not None
+                       for p in self.collect_params().values())
+
+        waiting = deferred()
+        _first_forward_open.on = True
+        try:
+            with trace.process_span("gluon.first_forward",
+                                    block=type(self).__name__) as sp:
+                out = self(*args, **kwargs)
+                sp.set(deferred=waiting - deferred())
+        finally:
+            _first_forward_open.on = False
+            self.apply(Block._close_first_forward)
+        return out
+
+    def _close_first_forward(self):
+        self._first_forward_pending = False
+
     def __call__(self, *args, **kwargs):
+        if self._first_forward_pending:
+            return self._first_forward(args, kwargs)
         for hook in self._forward_pre_hooks:
             hook(self, args)
         policy = getattr(self, "_amp_policy", None)
@@ -518,6 +562,8 @@ class HybridBlock(Block):
         return self._cached_op
 
     def __call__(self, *args, **kwargs):
+        if self._first_forward_pending:
+            return self._first_forward(args, kwargs)
         if self._active and args and all(
                 isinstance(a, NDArray) and
                 not isinstance(a.data, jax.core.Tracer) for a in args):

@@ -1,11 +1,13 @@
 """Parameter and ParameterDict (reference python/mxnet/gluon/parameter.py)."""
 from __future__ import annotations
 
+import math
 import re
 import threading
 
 import jax.numpy as jnp
 
+from .. import trace
 from ..base import dtype_from_any
 from ..context import Context, current_context
 from ..ndarray import NDArray
@@ -124,18 +126,28 @@ class Parameter:
                 f"Parameter {self.name} has unknown shape {self._shape}")
         self._finish_init(init, ctx, default_init)
 
+    def _nbytes(self):
+        return math.prod(self._shape) * jnp.dtype(self.dtype).itemsize
+
     def _finish_init(self, init, ctx, default_init=init_mod.Uniform):
-        data = NDArray(jnp.zeros(self._shape, self.dtype), ctx=ctx)
         initializer = init or self.init or default_init()
         if isinstance(initializer, str):
             initializer = init_mod.create(initializer)
         elif isinstance(initializer, type):
             initializer = initializer()
-        initializer(self.name, data)
-        self._data = data
-        if self._grad_req != "null":
-            self._data.attach_grad(self._grad_req)
-        self._deferred_init_args = None
+        # the one place a leaf gets its value: the zeros, the draw, the
+        # write (host time as it runs; what the draw dispatched and did
+        # not wait for lands in whoever reads the leaf first)
+        with trace.process_span(
+                "gluon.param_init", name=self.name, bytes=self._nbytes(),
+                initializer=type(initializer).__name__,
+                deferred=self._deferred_init_args is not None):
+            data = NDArray(jnp.zeros(self._shape, self.dtype), ctx=ctx)
+            initializer(self.name, data)
+            self._data = data
+            if self._grad_req != "null":
+                self._data.attach_grad(self._grad_req)
+            self._deferred_init_args = None
 
     def _finish_deferred_init(self):
         if self._deferred_init_args is None:
@@ -296,8 +308,14 @@ class ParameterDict:
 
     def initialize(self, init=None, ctx=None, verbose=False,
                    force_reinit=False):
-        for p in self.values():
-            p.initialize(init=init, ctx=ctx, force_reinit=force_reinit)
+        with trace.process_span("gluon.initialize") as sp:
+            done = []
+            for p in self.values():
+                had = p._data is not None and not force_reinit
+                p.initialize(init=init, ctx=ctx, force_reinit=force_reinit)
+                if not had and p._data is not None:
+                    done.append(p)
+            sp.set(leaves=len(done), bytes=sum(p._nbytes() for p in done))
 
     def zero_grad(self):
         for p in self.values():

@@ -51,7 +51,14 @@ one ``FusedTrainStep.__call__`` with its children
 ``fused_step.key_split`` (the PRNG split, a jitted program of its
 own), ``fused_step.analyses`` (while a lint latch is open) and
 ``executor.call`` (``Executor.__call__``: the jitted call itself, with
-its ``site``).  ``fault.py`` injections add
+its ``site``).  Process spans: ``process.import``;
+``gluon.param_init`` (one leaf) under ``gluon.initialize`` or
+``gluon.first_forward`` (the eager pass that resolves deferred
+shapes); ``amp.convert_block``; ``fused_step.build`` with its children
+``fused_step.state_copy``, ``fused_step.place`` and
+``fused_step.program``; ``fused_step.first_call`` around the first
+``fused_step.call``; ``jit.compile``, one a compile of the process,
+recorded when its backend compile ends.  ``fault.py`` injections add
 a ``fault.<point>`` event to the active span, so a chaos-run artifact
 shows the injected fault and the recovery path in one timeline.  The
 HA router tier adds ``router.forwarded`` events (mis-hashed session
@@ -61,12 +68,29 @@ request stays ONE trace across both routers.
 """
 from __future__ import annotations
 
+import sys
+import time
+
+# ONE clock anchor per process, read before anything else is imported:
+# the package's ``__init__`` imports this module first, so the anchor is
+# the start of the package's import and the process trace's ``t0``.
+# Every span timestamp is monotonic (durations can never jump on an NTP
+# step — the MX-TIME001 contract); export maps them onto a shared
+# cross-process timeline by adding the delta-to-anchor to this single
+# wall reading, :func:`process_spans` onto ``time.perf_counter()``'s
+# clock by the same delta.
+_ANCHOR_WALL = time.time()  # mxlint: allow-wall-clock(single per-process anchor aligning monotonic span times across processes at export; all arithmetic stays monotonic)
+_ANCHOR_MONO = time.monotonic()
+_ANCHOR_PERF = time.perf_counter()
+#: whether the process had imported JAX before this package: if not,
+#: ``process.import`` holds JAX's import too
+JAX_PRELOADED = "jax" in sys.modules
+
 import contextvars
 import json
 import os
 import random
 import threading
-import time
 from collections import deque
 
 from jax.profiler import TraceAnnotation as _TraceAnnotation
@@ -78,21 +102,15 @@ __all__ = [
     "HEADER", "Span", "enabled", "active", "sample_rate", "configure",
     "reset", "start_trace", "record_span", "from_header",
     "parse_header", "header_value", "current_span", "current_trace_id",
-    "activate", "span", "add_event", "export", "spans", "stats",
-    "health_block", "slow_k",
+    "activate", "span", "process_span", "record_process_span",
+    "add_event", "export", "spans", "process_spans", "process_summary",
+    "stats", "health_block", "slow_k", "JAX_PRELOADED",
 ]
 
 #: The propagation header: ``traceid(16 hex)-spanid(8 hex)-sampled``.
 HEADER = "X-MXNET-TRACE"
 
 _HEX = set("0123456789abcdef")
-
-# ONE wall-clock anchor per process: every span timestamp is monotonic
-# (durations can never jump on an NTP step — the MX-TIME001 contract);
-# export maps them onto a shared cross-process timeline by adding the
-# delta-to-anchor to this single wall reading.
-_ANCHOR_WALL = time.time()  # mxlint: allow-wall-clock(single per-process anchor aligning monotonic span times across processes at export; all arithmetic stays monotonic)
-_ANCHOR_MONO = time.monotonic()
 
 _current: contextvars.ContextVar = contextvars.ContextVar(
     "mxnet_trace_span", default=None)
@@ -115,7 +133,7 @@ class Span:
     __slots__ = ("trace_id", "span_id", "parent_id", "name", "t0",
                  "t1", "args", "events", "tid", "_done")
 
-    def __init__(self, name, trace_id, parent_id=None, t0=None,
+    def __init__(self, name, /, trace_id, parent_id=None, t0=None,
                  **args):
         self.name = name
         self.trace_id = trace_id
@@ -137,20 +155,25 @@ class Span:
         cache hits, failover notes)."""
         self.events.append((time.monotonic(), name, args))
 
-    def child(self, name, **args):
+    def child(self, name, /, **args):
         return Span(name, self.trace_id, parent_id=self.span_id,
                     **args)
 
     def finish(self, outcome=None, t1=None):
-        """Close the span and push it into the ring.  Idempotent —
-        double-finish records once.  ``outcome`` defaults to ``"ok"``;
-        error paths pass the typed error's class name."""
+        """Close the span and push it into the ring (the process
+        trace's spans into its own store).  Idempotent — double-finish
+        records once.  ``outcome`` defaults to ``"ok"``; error paths
+        pass the typed error's class name."""
         if self._done:
             return self
         self._done = True
         self.t1 = time.monotonic() if t1 is None else float(t1)
         self.args.setdefault("outcome", outcome or "ok")
-        _ring().push(self)
+        if self.trace_id == _PROCESS.trace_id:
+            _process_store.push(self)
+        else:
+            _ring().push(self)
+            _ensure_provider()
         return self
 
     @property
@@ -213,7 +236,6 @@ class _Ring:
             while len(self._d) > self.cap:
                 self._d.popleft()
                 self.dropped += 1
-        _ensure_provider()
 
     def snapshot(self, trace_id=None):
         with self._lock:
@@ -230,6 +252,14 @@ class _Ring:
 
 
 _ring_obj = None
+
+#: spans the process trace's store holds: one a leaf and one a compile,
+#: a few hundred a net — a constant, not a knob
+_PROCESS_CAP = 8192
+#: the process trace's root: never finished, so never in a store;
+#: export closes it at the last process span's end
+_PROCESS = Span("process", _new_id(16), t0=_ANCHOR_MONO)
+_process_store = _Ring(_PROCESS_CAP)
 
 
 def _ring():
@@ -259,14 +289,15 @@ def configure(sample=None, ring=None, slow=None):
 
 
 def reset():
-    """Forget overrides and recorded spans; next use re-reads the env
-    (test isolation)."""
+    """Forget overrides and recorded spans, the process trace's too;
+    next use re-reads the env (test isolation)."""
     global _ring_obj
     with _lock:
         _cfg["sample"] = None
         _cfg["ring"] = None
         _cfg["slow_k"] = None
         _ring_obj = None
+    _process_store.clear()
 
 
 def active():
@@ -301,7 +332,7 @@ def start_trace(name, **args):
     return Span(name, _new_id(16), **args)
 
 
-def record_span(name, parent, t0, t1, **args):
+def record_span(name, /, parent, t0, t1, **args):
     """Create AND finish a child span with explicit monotonic
     timestamps — for recorders that learn about a region after the
     fact (the batcher's queue-wait split)."""
@@ -357,7 +388,7 @@ class span:
 
     __slots__ = ("_name", "_args", "_span", "_token", "_annotation")
 
-    def __init__(self, name, **args):
+    def __init__(self, name, /, **args):
         self._name = name
         self._args = args
         self._span = None
@@ -380,6 +411,35 @@ class span:
                 outcome=etype.__name__ if etype is not None else None)
         self._annotation.__exit__(etype, evalue, tb)
         return False
+
+
+class process_span(span):
+    """``with trace.process_span("fused_step.build", site=s):`` — a
+    :class:`span` that always records: it marks something that happens
+    once a process, once a net or once a compiled signature, never once
+    a step or a request, so it needs no sampling decision and no
+    profiler session.  Child of the current span where there is one (a
+    model loaded by a sampled request stays in that request's trace),
+    of the ``process`` root where there is none; current for its body,
+    so the ordinary spans inside it record as its children."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        self._annotation.__enter__()
+        parent = _current.get() or _PROCESS
+        self._span = parent.child(self._name, **self._args)
+        self._token = _current.set(self._span)
+        return self._span
+
+
+def record_process_span(name, /, seconds=None, **args):
+    """A process span learnt about after the fact: it ended now and
+    lasted ``seconds`` (``None``: since the process trace began, the
+    start of the package's import)."""
+    t1 = time.monotonic()
+    t0 = _ANCHOR_MONO if seconds is None else t1 - seconds
+    return record_span(name, _current.get() or _PROCESS, t0, t1, **args)
 
 
 def add_event(name, **args):
@@ -459,19 +519,68 @@ def _wall_us(t_mono):
 
 
 def spans(trace_id=None):
-    """Recorded spans, newest last (optionally one trace's)."""
+    """The ring's recorded spans, newest last (optionally one trace's);
+    the process trace is :func:`process_spans`."""
     return _ring().snapshot(trace_id)
+
+
+def _process_trace(trace_id=None):
+    """The process trace's spans, its root first, closed at the last
+    one's end; nothing while the store is empty."""
+    held = _process_store.snapshot(trace_id)
+    if not held:
+        return []
+    _PROCESS.t1 = max(s.t1 for s in held)
+    return [_PROCESS] + held
+
+
+def process_spans():
+    """The process trace as plain records, in the order the spans
+    ended: ``name``, ``parent`` (the parent span's name; None where it
+    has left the store), ``t0`` and ``t1`` on ``time.perf_counter()``'s
+    clock (that of ``executor_cache.compile_log()``'s ``at``), ``args``,
+    and the ``span_id`` / ``parent_id`` the tree is rebuilt from."""
+    held = _process_trace()
+    names = {s.span_id: s.name for s in held}
+    shift = _ANCHOR_PERF - _ANCHOR_MONO
+    return [{"name": s.name, "parent": names.get(s.parent_id),
+             "t0": s.t0 + shift, "t1": s.t1 + shift, "args": dict(s.args),
+             "span_id": s.span_id, "parent_id": s.parent_id}
+            for s in held[1:]]
+
+
+def process_summary():
+    """The process trace folded by span name: ``count``, ``total_s``,
+    ``first_start_s`` and ``last_end_s`` (seconds after the start of
+    the package's import), and the store's ``dropped`` count — what the
+    ``cold_start`` provider reports under ``"setup"``."""
+    by_name = {}
+    for s in _process_store.snapshot():
+        into = by_name.setdefault(s.name, {
+            "count": 0, "total_s": 0.0,
+            "first_start_s": float("inf"), "last_end_s": 0.0})
+        into["count"] += 1
+        into["total_s"] += s.t1 - s.t0
+        into["first_start_s"] = min(into["first_start_s"],
+                                    s.t0 - _ANCHOR_MONO)
+        into["last_end_s"] = max(into["last_end_s"], s.t1 - _ANCHOR_MONO)
+    for into in by_name.values():
+        for key in ("total_s", "first_start_s", "last_end_s"):
+            into[key] = round(into[key], 6)
+    return {"spans": by_name, "cap": _process_store.cap,
+            "dropped": _process_store.dropped}
 
 
 def export(trace_id=None, service=None):
     """Chrome trace-event JSON (``chrome://tracing`` /
     ``ui.perfetto.dev`` loadable): one ``ph:"X"`` complete event per
-    span, one ``ph:"i"`` instant per span event.  ``service`` labels
+    span — the process trace's first, under its ``process`` root, then
+    the ring's — one ``ph:"i"`` instant per span event.  ``service`` labels
     the process (router/replica) for merged views."""
     pid = os.getpid()
     svc = service or f"pid:{pid}"
     events = []
-    for s in _ring().snapshot(trace_id):
+    for s in _process_trace(trace_id) + _ring().snapshot(trace_id):
         t1 = s.t1 if s.t1 is not None else s.t0
         args = dict(s.args)
         args.update(trace_id=s.trace_id, span_id=s.span_id,

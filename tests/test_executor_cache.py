@@ -184,9 +184,15 @@ class TestPersistentCache:
         assert not (tmp_path / "m").exists()
 
     def test_cold_start_provider_registered(self):
-        xc.Executor(lambda a: a + 1, "test:provider")
+        ex = xc.Executor(lambda a: a + 1, "test:provider")
+        ex(jnp.ones((3,))).block_until_ready()
         stats = profiler.provider_stats()["cold_start"]
-        assert stats["first_executor_build_ms_after_import"] is not None
+        # when set-up's phases began and ended is the process trace's,
+        # folded by span name under "setup": this compile is in it
+        compiled = stats["setup"]["spans"]["jit.compile"]
+        assert compiled["count"] >= 1 and compiled["total_s"] > 0
+        assert 0 < compiled["first_start_s"] < compiled["last_end_s"]
+        assert stats["setup"]["dropped"] == 0
         assert "test:provider" in stats["per_site"]
         assert stats["since_import_ms"] > 0
 

@@ -204,3 +204,168 @@ def test_pallas_names_reach_the_traced_program():
     found = [eqn.params["name"] for eqn in jaxpr.jaxpr.eqns
              if eqn.primitive.name == "pallas_call"]
     assert found == ["layer_norm_fwd", "layer_norm_bwd"]
+
+
+# ---------------------------------------------------------------------------
+# set-up on the process trace (trace.py): the spans a net's initialisation, a
+# step's build and its first call leave, with sampling off and no profiler
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def process_trace():
+    """An empty process trace before the test and after it."""
+    from incubator_mxnet_tpu import trace
+    trace.reset()
+    yield trace
+    trace.reset()
+
+
+def _small_net():
+    from incubator_mxnet_tpu.gluon import nn
+    mx.random.seed(3)
+    net = nn.HybridSequential()
+    net.add(nn.Dense(8, in_units=4), nn.Dense(4))       # 4 leaves, 1 deferred
+    return net
+
+
+def _named(trace, name):
+    return [r for r in trace.process_spans() if r["name"] == name]
+
+
+def _batch(rows):
+    rng = onp.random.RandomState(rows)
+    return (jnp.asarray(rng.rand(rows, 4).astype("f")),
+            jnp.asarray(rng.rand(rows, 4).astype("f")))
+
+
+def test_initialize_and_first_forward_leave_one_param_init_a_leaf(
+        process_trace):
+    net = _small_net()
+    net.initialize()
+    net(mx.nd.random.uniform(shape=(1, 4)))
+    net(mx.nd.random.uniform(shape=(1, 4)))             # a later call: nothing
+    (init,) = _named(process_trace, "gluon.initialize")
+    (first,) = _named(process_trace, "gluon.first_forward")
+    leaves = _named(process_trace, "gluon.param_init")
+    # the second Dense's weight knows its shape only inside the eager
+    # pass; a float32 leaf's bytes are 4 a value
+    assert [(r["parent"], r["args"]["bytes"], r["args"]["deferred"])
+            for r in leaves] == [
+        ("gluon.initialize", 8 * 4 * 4, False),
+        ("gluon.initialize", 8 * 4, False),
+        ("gluon.initialize", 4 * 4, False),
+        ("gluon.first_forward", 4 * 8 * 4, True)]
+    assert all(r["args"]["initializer"] and r["args"]["name"] for r in leaves)
+    assert init["args"]["leaves"] == 3 and init["args"]["bytes"] == 176
+    assert first["args"] == {"block": "HybridSequential", "deferred": 1,
+                             "outcome": "ok"}
+    # the blocks inside it were not outermost: no span of their own, and
+    # every latch below the root is closed
+    assert not any(b._first_forward_pending
+                   for b in [net] + list(net._children.values()))
+
+
+def test_a_first_call_under_a_trace_is_no_first_forward(process_trace):
+    net = _small_net()
+    net[0].initialize()
+    net[1].weight.shape = (4, 8)
+    net.initialize()
+    step = make_fused_train_step(net, mx.gluon.loss.L2Loss(), "sgd",
+                                 {"learning_rate": 0.1})
+    step(*_batch(2))            # the children's first call has tracers
+    assert _named(process_trace, "gluon.first_forward") == []
+    assert len(_named(process_trace, "gluon.param_init")) == 4
+    assert not net[0]._first_forward_pending
+
+
+def test_convert_block_is_one_span_with_its_leaves_and_bytes(process_trace):
+    net = _small_net()
+    net.initialize()
+    net(mx.nd.random.uniform(shape=(1, 4)))
+    amp.convert_block(net, "bfloat16")
+    (cast,) = _named(process_trace, "amp.convert_block")
+    assert cast["parent"] == "process"
+    assert cast["args"]["dtype"] == "bfloat16" and cast["args"]["leaves"] == 4
+    assert cast["args"]["bytes"] == 2 * (32 + 8 + 32 + 4)
+
+
+@pytest.fixture
+def built_step(process_trace):
+    net = _small_net()
+    net.initialize()
+    net(mx.nd.random.uniform(shape=(1, 4)))
+    step = make_fused_train_step(net, mx.gluon.loss.L2Loss(), "sgd",
+                                 {"learning_rate": 0.1})
+    return process_trace, step
+
+
+def test_build_leaves_one_span_with_its_three_children(built_step):
+    trace, step = built_step
+    (build,) = _named(trace, "fused_step.build")
+    assert build["parent"] == "process"
+    assert build["args"]["site"] == "fused_step:HybridSequential"
+    children = [r for r in trace.process_spans()
+                if r["parent_id"] == build["span_id"]]
+    assert [r["name"] for r in children] == [
+        "fused_step.state_copy", "fused_step.place", "fused_step.program"]
+    copy, place, _ = children
+    # 4 parameters, their 4 momenta and the key: 76 values of 4 bytes + 8
+    assert copy["args"]["leaves"] == 9
+    assert copy["args"]["bytes"] == place["args"]["bytes"] == 2 * 76 * 4 + 8
+    assert place["args"]["devices"] == 1
+    assert build["t0"] <= copy["t0"] <= copy["t1"] <= place["t0"] \
+        <= place["t1"] <= children[2]["t0"] <= build["t1"]
+    assert _named(trace, "fused_step.first_call") == []
+
+
+def test_first_call_holds_the_calls_spans_and_the_second_leaves_none(
+        built_step):
+    trace, step = built_step
+    x, y = _batch(2)
+    step(x, y).block_until_ready()
+    (first,) = _named(trace, "fused_step.first_call")
+    assert first["parent"] == "process"
+    assert first["args"]["site"] == "fused_step:HybridSequential"
+    (call,) = _named(trace, "fused_step.call")
+    assert call["parent_id"] == first["span_id"]
+    inside = {r["name"]: r for r in trace.process_spans()
+              if r["parent_id"] == call["span_id"]}
+    assert sorted(inside) == ["executor.call", "fused_step.analyses",
+                              "fused_step.key_split"]
+    # the step's compile is recorded under the jitted call that paid it
+    compiled = [r for r in _named(trace, "jit.compile")
+                if r["args"]["site"] == first["args"]["site"]]
+    assert len(compiled) == 1
+    assert compiled[0]["parent_id"] == inside["executor.call"]["span_id"]
+    assert compiled[0]["args"]["fun"] == "step"
+    assert compiled[0]["args"]["backend_compile_s"] > 0
+    assert first["t0"] <= compiled[0]["t1"] <= first["t1"]
+    held = len(trace.process_spans())
+    for _ in range(3):
+        step(x, y)
+    step(x, y).block_until_ready()
+    assert len(trace.process_spans()) == held
+    assert trace.spans() == [] and not trace.active()
+
+
+def test_a_recompile_leaves_one_jit_compile_and_a_flight_event(built_step):
+    from incubator_mxnet_tpu import flightrec
+    trace, step = built_step
+    site = "fused_step:HybridSequential"
+    step(*_batch(2)).block_until_ready()
+    step(*_batch(2)).block_until_ready()
+    held = trace.process_spans()
+    events = len(flightrec.events(name="jit.compiled"))
+    step(*_batch(6)).block_until_ready()        # a new batch shape
+    new = trace.process_spans()[len(held):]
+    # a recompile is no first call: the one record, under the root
+    assert [(r["name"], r["parent"], r["args"]["site"]) for r in new] == [
+        ("jit.compile", "process", site)]
+    assert new[0]["args"]["trace_s"] > 0 and not new[0]["args"]["cache_hit"]
+    assert abs((new[0]["t1"] - new[0]["t0"]) - sum(
+        new[0]["args"][k] for k in ("trace_s", "lower_s",
+                                    "backend_compile_s"))) < 1e-6
+    fresh = flightrec.events(name="jit.compiled")[events:]
+    assert [(e.category, e.fields["site"], e.fields["fun"])
+            for e in fresh] == [("compile", site, "step")]
+    assert step._executor.compile_count == 2

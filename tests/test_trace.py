@@ -688,11 +688,119 @@ def test_step_spans_reach_the_ring_under_a_sampled_parent():
     root.finish()
     spans = {s.name: s for s in trace.spans(root.trace_id)}
     call = spans["fused_step.call"]
-    assert call.parent_id == root.span_id
+    # the step's first call is a process span, which joins the trace of
+    # the request that pays it and encloses the call's own span
+    first = spans["fused_step.first_call"]
+    assert first.parent_id == root.span_id
+    assert call.parent_id == first.span_id
     assert spans["fused_step.key_split"].parent_id == call.span_id
     assert spans["executor.call"].parent_id == call.span_id
     assert spans["executor.call"].args["site"] == \
         "fused_step:HybridSequential"
+
+
+# ---------------------------------------------------------------------------
+# the process trace: what happens once a process, a net or a compiled
+# signature records always, in a store of its own
+# ---------------------------------------------------------------------------
+
+def test_process_span_records_with_sampling_off_and_no_profiler():
+    assert not trace.enabled()
+    with trace.process_span("unit.once", what="x") as sp:
+        assert trace.current_span() is sp
+    assert trace.current_span() is None
+    (rec,) = trace.process_spans()
+    assert rec["name"] == "unit.once" and rec["parent"] == "process"
+    assert rec["args"]["what"] == "x" and rec["args"]["outcome"] == "ok"
+    # the ring, its provider's counters and the healthz gate are the
+    # requests': the process trace moves none of them
+    assert trace.spans() == [] and not trace.active()
+    assert trace.stats()["spans_recorded"] == 0
+
+
+def test_ordinary_span_records_as_a_process_spans_child_and_not_outside():
+    with trace.span("before"):
+        pass
+    with trace.process_span("unit.once"):
+        with trace.span("inner", k=1):
+            with trace.span("innermost"):
+                pass
+    with trace.span("after"):
+        pass
+    by_name = {r["name"]: r for r in trace.process_spans()}
+    assert sorted(by_name) == ["inner", "innermost", "unit.once"]
+    assert by_name["inner"]["parent"] == "unit.once"
+    assert by_name["inner"]["parent_id"] == by_name["unit.once"]["span_id"]
+    assert by_name["innermost"]["parent"] == "inner"
+    assert by_name["unit.once"]["t0"] <= by_name["inner"]["t0"] \
+        <= by_name["inner"]["t1"] <= by_name["unit.once"]["t1"]
+
+
+def test_process_span_joins_the_trace_of_a_current_request():
+    trace.configure(sample=1.0, ring=64)
+    root = trace.start_trace("server.request")
+    with trace.activate(root):
+        with trace.process_span("unit.once", model="m"):
+            pass
+        trace.record_process_span("jit.compile", 0.25, fun="f")
+    root.finish()
+    spans = {s.name: s for s in trace.spans(root.trace_id)}
+    assert spans["unit.once"].parent_id == root.span_id
+    assert spans["jit.compile"].parent_id == root.span_id
+    assert abs(spans["jit.compile"].t1 - spans["jit.compile"].t0
+               - 0.25) < 1e-9
+    assert trace.process_spans() == []
+
+
+def test_process_store_evicts_oldest_first_and_counts(monkeypatch):
+    monkeypatch.setattr(trace, "_process_store", trace._Ring(4))
+    for i in range(7):
+        with trace.process_span("unit.once", i=i):
+            pass
+    assert [r["args"]["i"] for r in trace.process_spans()] == [3, 4, 5, 6]
+    summary = trace.process_summary()
+    assert summary["dropped"] == 3 and summary["cap"] == 4
+    assert summary["spans"]["unit.once"]["count"] == 4
+    # request traffic has its own ring and evicts nothing here
+    trace.configure(sample=1.0, ring=2)
+    for _ in range(5):
+        trace.start_trace("server.request").finish()
+    assert len(trace.process_spans()) == 4
+
+
+def test_process_spans_are_on_perf_counters_clock():
+    before = time.perf_counter()
+    with trace.process_span("unit.once"):
+        inside = time.perf_counter()
+    after = time.perf_counter()
+    trace.record_process_span("unit.import")    # since the package's import
+    once, imported = trace.process_spans()
+    assert before <= once["t0"] <= inside <= once["t1"] <= after
+    assert imported["t0"] < before and after <= imported["t1"]
+    summary = trace.process_summary()["spans"]
+    assert summary["unit.import"]["first_start_s"] == 0.0
+    assert summary["unit.once"]["first_start_s"] > 0.0
+    assert abs(summary["unit.once"]["total_s"]
+               - (once["t1"] - once["t0"])) < 1e-5
+
+
+def test_export_carries_the_process_trace_under_its_root():
+    assert trace.export()["traceEvents"] == []      # an empty store: no root
+    with trace.process_span("unit.once", site="s"):
+        with trace.span("inner"):
+            pass
+    events = {e["name"]: e for e in trace.export(service="t")["traceEvents"]}
+    assert sorted(events) == ["inner", "process", "unit.once"]
+    root, once = events["process"], events["unit.once"]
+    assert once["args"]["parent_id"] == root["args"]["span_id"]
+    assert events["inner"]["args"]["parent_id"] == once["args"]["span_id"]
+    assert len({e["args"]["trace_id"] for e in events.values()}) == 1
+    assert root["ts"] <= once["ts"] \
+        and once["ts"] + once["dur"] <= root["ts"] + root["dur"] + 1
+    # a request's id selects the ring's spans alone
+    assert trace.export("ab" * 8)["traceEvents"] == []
+    assert {e["name"] for e in trace.export(
+        root["args"]["trace_id"])["traceEvents"]} == set(events)
 
 
 def test_compile_counters_move_at_the_first_call_and_then_never():
