@@ -30,15 +30,18 @@ no multiple of the chunk is padded with ``Δ = 0`` rows, which leave the
 state alone.
 
 Both ops run behind :func:`pallas_kernels.dispatch` under the kernel scopes
-``ssd_scan`` and ``causal_conv1d``.  The convolution is a composition that has
-no kernel yet (``xla:no_kernel`` in ``kernel_routes``).  The scan has both
-sides: the composition below (the CPU, a mesh, shapes the kernels do not
-take, and the tests' parity reference) and a Pallas pair, ``ssd_scan_fwd`` and
-``ssd_scan_bwd``, that computes the same products with the same casts and
-keeps the same float32 entry states, but never writes a chunk's decay matrix,
-its scores or their cotangents to HBM.  Which one a call takes goes by its
-shape and dtype alone (:class:`_ScanPlan`); ``ssm_plans`` says what each
-traced signature of the scan got (docs/observability.md).
+``ssd_scan`` and ``causal_conv1d``, and both have two sides: the composition
+(the CPU, a mesh, shapes the kernels do not take, and the tests' parity
+reference) and a Pallas pair.  The scan's, ``ssd_scan_fwd`` and
+``ssd_scan_bwd``, computes the same products with the same casts and keeps
+the same float32 entry states, but never writes a chunk's decay matrix, its
+scores or their cotangents to HBM.  The convolution's, ``causal_conv1d_fwd``
+and ``causal_conv1d_bwd``, reads ``x`` (and ``dy``) once and writes ``y`` (or
+``dx``) once: no padded float32 copy, no sum before SiLU kept for the
+backward, no product of a tap in HBM; the taps' and the bias's gradients are
+summed in the core.  Which side a call takes goes by its shape and dtype
+alone (:class:`_ScanPlan`, :class:`_ConvPlan`); ``ssm_plans`` says what each
+traced signature of either op got (docs/observability.md).
 """
 from __future__ import annotations
 
@@ -66,9 +69,261 @@ def _einsum(spec, a, b):
                       precision=_precision(a.dtype))
 
 
+_plans = {}
+_plans_lock = named_lock("ops.ssm_plans")
+
+
+def ssm_plans(reset=False):
+    """``{signature: plan}`` of every ``ssd_scan`` and ``causal_conv1d``
+    call traced so far.  A scan's (``b1 t4096 h128x64 g8 n128 bfloat16``):
+    ``chunk``, ``chunks`` a sequence, ``route`` (``kernel``, or the
+    ``xla:<why>`` that :func:`pallas_kernels.dispatch` counted),
+    ``heads_a_step`` (a group's heads in a step of the kernels; the
+    composition takes all heads of a chunk in one batched product),
+    ``grid_steps_fwd`` / ``grid_steps_bwd`` and ``vmem_bytes`` (the larger
+    bill of the two kernels; 0 on the composition), ``state_bytes_saved``
+    (the entry states the backward pass keeps, on either route) and
+    ``padded_rows`` (the ``Δ = 0`` rows that fill the last chunk).  A
+    convolution's (``conv b1 t4096 c10240 k4 bfloat16``): ``route``,
+    ``taps``, ``channels_a_step`` (a lane tile in a step of the kernels, all
+    of them on the composition), ``grid_steps_fwd`` / ``grid_steps_bwd``,
+    ``vmem_bytes`` and ``padded_rows`` (the zero rows that fill the last
+    turn of a step's walk).  Counts signatures, not calls.  The
+    ``ssm_plans`` provider of ``profiler.dumps()``."""
+    with _plans_lock:
+        out = {sig: dict(plan) for sig, plan in sorted(_plans.items())}
+        if reset:
+            _plans.clear()
+    return out
+
+
+_profiler.register_stats_provider("ssm_plans", ssm_plans)
+
+
+def _note_plan(pn):
+    """The ``ssm_plans`` entry of the signature being traced."""
+    with _plans_lock:
+        _plans[pn.signature()] = pn.stats(pk.route(pn.why_not))
+
+
 # ======================================================================
-# the short convolution
+# the short convolution.  Its kernel pair takes one lane tile of channels a
+# grid step over its whole sequence, grid (batch, tile): ``x`` (b, T, C) is
+# read as it lies, a block of (T rows, 128 lanes).  A step walks its block
+# ``_CONV_ROWS`` positions a turn over a float32 copy of the block in VMEM
+# with ``_CONV_HALO`` rows of zeros before it, so that tap ``k`` of a turn
+# is a plain load ``K − 1 − k`` rows up (Mosaic addresses VMEM by the row;
+# only a block one lane tile wide may be read at an unaligned row, which is
+# why a step is no wider).  Backward the walk runs from the last turn to the
+# first over a second copy, what the loss feels of the sum before SiLU, with
+# the zeros after it.
 # ======================================================================
+
+_CONV_VMEM = 16 * 1024 * 1024
+_CONV_VMEM_MOST = 48 * 1024 * 1024
+_CONV_VMEM_OWN = 2 * 1024 * 1024
+_CONV_LANES = 128       # channels a grid step: one lane tile
+_CONV_ROWS = 128        # positions a turn of a step's walk over the sequence
+_CONV_HALO = 8          # float32 rows of zeros before (and after) a sequence
+_CONV_TAPS_MOST = 7     # taps and the bias are the rows of one (8, C) block
+
+
+class _ConvPlan:
+    """What one ``causal_conv1d`` signature is cut into, and whether the
+    kernel pair takes it (``why_not`` is None) or the composition does
+    (``"shape"`` or ``"dtype"``: the ``unless=`` of the dispatch).  The pair
+    takes channels that are whole lane tiles, at most ``_CONV_TAPS_MOST``
+    taps, bfloat16 or float32, and a sequence whose blocks — the whole
+    sequence of ``_CONV_LANES`` channels a step, pipelined, and its float32
+    copies — fit the VMEM bill."""
+
+    def __init__(self, batch, t, channels, taps, dtype):
+        self.batch, self.t, self.channels, self.taps = batch, t, channels, taps
+        self.dtype = jnp.dtype(dtype)
+        self.rows = -(-t // _CONV_ROWS) * _CONV_ROWS
+        self.padded = self.rows - t
+        wide = self.rows * _CONV_LANES * self.dtype.itemsize
+        copy = 4 * (self.rows + _CONV_HALO) * _CONV_LANES
+        # a kernel's bill: its pipelined blocks twice, its float32 copies of
+        # a block (x; in the backward also what the loss feels of the sum
+        # before SiLU) once, and the step's own values
+        self.vmem_fwd = 2 * 2 * wide + copy + _CONV_VMEM_OWN
+        self.vmem_bwd = 2 * 3 * wide + 2 * copy + _CONV_VMEM_OWN
+        if self.dtype not in (jnp.dtype(jnp.bfloat16), jnp.dtype(F32)):
+            self.why_not = "dtype"
+        elif (channels % _CONV_LANES or not 1 <= taps <= _CONV_TAPS_MOST
+              or self.vmem_bwd > _CONV_VMEM_MOST):
+            self.why_not = "shape"
+        else:
+            self.why_not = None
+
+    def __hash__(self):
+        return hash(self.signature())
+
+    def __eq__(self, other):
+        return self.signature() == other.signature()
+
+    def signature(self):
+        return (f"conv b{self.batch} t{self.t} c{self.channels} "
+                f"k{self.taps} {self.dtype}")
+
+    def fill(self, v):
+        """(b, T, C) → (b, whole turns, C): zero rows after the sequence."""
+        return jnp.pad(v, ((0, 0), (0, self.padded), (0, 0)))
+
+    def stats(self, route):
+        """The ``ssm_plans`` entry of this signature on ``route``."""
+        kernel = route == "kernel"
+        steps = self.batch * (self.channels // _CONV_LANES) if kernel else 0
+        return {"route": route, "taps": self.taps,
+                "channels_a_step": _CONV_LANES if kernel else self.channels,
+                "padded_rows": self.padded if kernel else 0,
+                "grid_steps_fwd": steps, "grid_steps_bwd": steps,
+                "vmem_bytes": max(self.vmem_fwd, self.vmem_bwd)
+                if kernel else 0}
+
+
+def _conv_turn(i):
+    """The first row of turn ``i`` of a step's walk over its block."""
+    return pl.multiple_of(i * _CONV_ROWS, _CONV_ROWS)
+
+
+def _conv_sum(wb_ref, xf, at, taps, now=None):
+    """The sum before SiLU of ``_CONV_ROWS`` positions from ``at``, float32:
+    the bias and tap ``k`` times the rows ``K − 1 − k`` earlier in the float32
+    copy ``xf`` (whose first ``_CONV_HALO`` rows are the zeros before the
+    sequence); also the rows each tap read."""
+    past = [xf[pl.ds(_CONV_HALO + at - (taps - 1 - k), _CONV_ROWS), :]
+            for k in range(taps - 1)] + [
+        xf[pl.ds(_CONV_HALO + at, _CONV_ROWS), :] if now is None else now]
+    acc = wb_ref[taps:taps + 1, :] + wb_ref[0:1, :] * past[0]
+    for k in range(1, taps):
+        acc += wb_ref[k:k + 1, :] * past[k]
+    return acc, past
+
+
+def _conv_fwd_kernel(wb_ref, x_ref, y_ref, xf, *, taps):
+    """One lane tile of channels over its whole sequence, ``_CONV_ROWS``
+    positions a turn: each turn writes its rows' float32 copy behind the
+    earlier turns' and reads the taps' rows back at their offsets."""
+    xf[0:_CONV_HALO, :] = jnp.zeros((_CONV_HALO, xf.shape[1]), F32)
+
+    def turn(i, _):
+        at = _conv_turn(i)
+        now = x_ref[pl.ds(at, _CONV_ROWS), :].astype(F32)
+        xf[pl.ds(_CONV_HALO + at, _CONV_ROWS), :] = now
+        acc, _ = _conv_sum(wb_ref, xf, at, taps, now)
+        y_ref[pl.ds(at, _CONV_ROWS), :] = (
+            acc / (1.0 + jnp.exp(-acc))).astype(y_ref.dtype)
+
+    jax.lax.fori_loop(0, x_ref.shape[0] // _CONV_ROWS, turn, None)
+
+
+def _conv_bwd_kernel(wb_ref, x_ref, dy_ref, dx_ref, dwb_ref, xf, felt, *,
+                     taps):
+    """The same block backward in time.  ``felt`` holds what the loss feels
+    of the sum before SiLU, float32, with zeros after the last position: a
+    turn writes its rows there and reads the later turns' for ``dx``.  The
+    taps' and the bias's gradients are summed over the turns in registers
+    and written once a step, as the rows of an (8, lanes) block."""
+    rows, lanes = x_ref.shape
+    turns = rows // _CONV_ROWS
+    xf[0:_CONV_HALO, :] = jnp.zeros((_CONV_HALO, lanes), F32)
+    felt[rows:rows + _CONV_HALO, :] = jnp.zeros((_CONV_HALO, lanes), F32)
+
+    def copy(i, _):
+        at = _conv_turn(i)
+        xf[pl.ds(_CONV_HALO + at, _CONV_ROWS), :] = x_ref[
+            pl.ds(at, _CONV_ROWS), :].astype(F32)
+
+    jax.lax.fori_loop(0, turns, copy, None)
+    fold = lambda v: jnp.sum(v.reshape(-1, 8, lanes), axis=0)
+
+    def turn(i, sums):
+        at = _conv_turn(turns - 1 - i)
+        acc, past = _conv_sum(wb_ref, xf, at, taps)
+        sig = 1.0 / (1.0 + jnp.exp(-acc))
+        d_acc = (dy_ref[pl.ds(at, _CONV_ROWS), :].astype(F32)
+                 * (sig * (1.0 + acc * (1.0 - sig))))
+        felt[pl.ds(at, _CONV_ROWS), :] = d_acc
+        dx = wb_ref[taps - 1:taps, :] * d_acc
+        for k in range(taps - 1):
+            dx += wb_ref[k:k + 1, :] * felt[
+                pl.ds(at + taps - 1 - k, _CONV_ROWS), :]
+        dx_ref[pl.ds(at, _CONV_ROWS), :] = dx.astype(dx_ref.dtype)
+        return tuple(s + fold(d_acc * v) for s, v in zip(sums, past)) + (
+            sums[taps] + fold(d_acc),)
+
+    sums = jax.lax.fori_loop(
+        0, turns, turn, (jnp.zeros((8, lanes), F32),) * (taps + 1))
+    dwb_ref[...] = jnp.concatenate(
+        [jnp.sum(s, axis=0, keepdims=True) for s in sums]
+        + [jnp.zeros((8 - len(sums), lanes), F32)], axis=0)
+
+
+def _conv_call(kernel, name, pn, vmem, ins, outs, copies, operands):
+    """The ``pallas_call`` of one kernel of the pair over grid (batch, lane
+    tile of channels).  ``ins`` and ``outs`` name each operand's kind of
+    block: ``x`` the whole (padded) sequence of a lane tile, ``wb`` the taps,
+    the bias or their gradients as the rows of an (8, C) float32 array."""
+    kinds = {
+        "x": ((pn.batch, pn.rows, pn.channels), pn.dtype,
+              (None, pn.rows, _CONV_LANES), lambda b, c: (b, 0, c)),
+        "wb": ((8, pn.channels), F32, (8, _CONV_LANES), lambda b, c: (0, c)),
+        "dwb": ((pn.batch, 8, pn.channels), F32, (None, 8, _CONV_LANES),
+                lambda b, c: (b, 0, c)),
+    }
+    spec = lambda kind: pl.BlockSpec(*kinds[kind][2:],
+                                     memory_space=pltpu.VMEM)
+    return pl.pallas_call(
+        functools.partial(kernel, taps=pn.taps),
+        out_shape=[jax.ShapeDtypeStruct(*kinds[o][:2]) for o in outs],
+        grid=(pn.batch, pn.channels // _CONV_LANES),
+        in_specs=[spec(i) for i in ins], out_specs=[spec(o) for o in outs],
+        scratch_shapes=[pltpu.VMEM((pn.rows + _CONV_HALO, _CONV_LANES), F32)
+                        ] * copies,
+        interpret=pk.interpret_mode(),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=max(_CONV_VMEM, vmem),
+            dimension_semantics=("parallel", "parallel")),
+        name=name,
+    )(*operands)
+
+
+def _taps_and_bias(pn, weight, bias):
+    """``weight`` (C, K) and ``bias`` (C,) as the kernels read them: the
+    rows of an (8, C) float32 array, tap ``k`` row ``k``, the bias row K."""
+    return jnp.concatenate(
+        [weight.astype(F32).T, bias.astype(F32)[None],
+         jnp.zeros((_CONV_TAPS_MOST - pn.taps, pn.channels), F32)], axis=0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _conv_kernels(pn, x, weight, bias):
+    return _conv_kernels_fwd(pn, x, weight, bias)[0]
+
+
+def _conv_kernels_fwd(pn, x, weight, bias):
+    (y,) = _conv_call(_conv_fwd_kernel, "causal_conv1d_fwd", pn, pn.vmem_fwd,
+                      ("wb", "x"), ("x",), 1,
+                      (_taps_and_bias(pn, weight, bias), pn.fill(x)))
+    return y[:, :pn.t], (x, weight, bias)
+
+
+def _conv_kernels_bwd(pn, res, dy):
+    x, weight, bias = res
+    dx, dwb = _conv_call(
+        _conv_bwd_kernel, "causal_conv1d_bwd", pn, pn.vmem_bwd,
+        ("wb", "x", "x"), ("x", "dwb"), 2,
+        (_taps_and_bias(pn, weight, bias), pn.fill(x),
+         pn.fill(dy.astype(x.dtype))))
+    dwb = jnp.sum(dwb, axis=0)
+    return (dx[:, :pn.t], dwb[:pn.taps].T.astype(weight.dtype),
+            dwb[pn.taps].astype(bias.dtype))
+
+
+_conv_kernels.defvjp(_conv_kernels_fwd, _conv_kernels_bwd)
+
 
 @register("causal_conv1d")
 def causal_conv1d(x, weight, bias):
@@ -76,7 +331,9 @@ def causal_conv1d(x, weight, bias):
     ``weight`` (C, K) and ``bias`` (C,): each channel its own ``K`` taps
     over its own past, zeros before the first position.  Summed in
     float32; the result has ``x``'s dtype."""
-    def causal_conv1d(x, weight, bias):
+    pn = _ConvPlan(*x.shape, weight.shape[1], x.dtype)
+
+    def causal_conv1d_xla(x, weight, bias):
         taps, t = weight.shape[1], x.shape[1]
         padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0))).astype(F32)
         w = weight.astype(F32)
@@ -84,8 +341,12 @@ def causal_conv1d(x, weight, bias):
             padded[:, k:k + t] * w[:, k] for k in range(taps))
         return jax.nn.silu(acc).astype(x.dtype)
 
-    return pk.dispatch(causal_conv1d, causal_conv1d, x, weight, bias,
-                       unless="no_kernel")
+    def causal_conv1d(x, weight, bias):
+        return _conv_kernels(pn, x, weight, bias)
+
+    _note_plan(pn)
+    return pk.dispatch(causal_conv1d, causal_conv1d_xla, x, weight, bias,
+                       unless=pn.why_not)
 
 
 # ======================================================================
@@ -583,32 +844,6 @@ def _ssd_kernels_bwd(pn, res, dy):
 _ssd_kernels.defvjp(_ssd_kernels_fwd, _ssd_kernels_bwd)
 
 
-_plans = {}
-_plans_lock = named_lock("ops.ssm_plans")
-
-
-def ssm_plans(reset=False):
-    """``{signature: plan}`` of every ``ssd_scan`` call traced so far:
-    ``chunk``, ``chunks`` a sequence, ``route`` (``kernel``, or the
-    ``xla:<why>`` that :func:`pallas_kernels.dispatch` counted),
-    ``heads_a_step`` (a group's heads in a step of the kernels; the
-    composition takes all heads of a chunk in one batched product),
-    ``grid_steps_fwd`` / ``grid_steps_bwd`` and ``vmem_bytes`` (the larger
-    bill of the two kernels; 0 on the composition), ``state_bytes_saved``
-    (the entry states the backward pass keeps, on either route) and
-    ``padded_rows`` (the ``Δ = 0`` rows that fill the last chunk).  Counts
-    signatures, not calls.  The ``ssm_plans`` provider of
-    ``profiler.dumps()``."""
-    with _plans_lock:
-        out = {sig: dict(plan) for sig, plan in sorted(_plans.items())}
-        if reset:
-            _plans.clear()
-    return out
-
-
-_profiler.register_stats_provider("ssm_plans", ssm_plans)
-
-
 @register("ssd_scan")
 def ssd_scan(x, dt, A, B, C, D, chunk=128):
     """The selective state-space recurrence (module docstring) over ``x``
@@ -645,7 +880,6 @@ def ssd_scan(x, dt, A, B, C, D, chunk=128):
                          d.astype(F32))
         return y.reshape((batch, chunks * chunk, heads, p))[:, :t]
 
-    with _plans_lock:
-        _plans[pn.signature()] = pn.stats(pk.route(pn.why_not))
+    _note_plan(pn)
     return pk.dispatch(ssd_scan, ssd_scan_xla, x, dt, A, B, C, D,
                        unless=pn.why_not)

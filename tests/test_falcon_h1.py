@@ -405,7 +405,9 @@ def test_the_steps_names_carry_the_block_keys_and_the_kernel_scopes(config):
     # the toy heads (P 8, state 16, chunks of 8) are no lane tiles: the
     # kernel pair leaves them to the composition
     assert set(routes["ssd_scan"]) == {"xla:shape"}
-    assert set(routes["causal_conv1d"]) == {"xla:no_kernel"}
+    # the toy's convolution is one lane tile wide (4 x 16 + 2 x 2 x 16
+    # channels): the pair's, where there is a chip
+    assert set(routes["causal_conv1d"]) == {"xla:no_tpu"}
 
 
 def test_one_layer_has_the_published_parameter_count():

@@ -204,7 +204,7 @@ def test_a_fused_step_recomputes_layers_that_hold_a_routers_state(config):
     """Every layer is under ``recompute()``, the routed ones too: their
     bias and counters leave the checkpointed region and move, the program
     holds the instructions run again, and ``moe_plans`` and ``ssm_plans``
-    say what the step's two signatures were cut into."""
+    say what the step's three signatures were cut into."""
     built = _net(config, 9)
     amp.convert_block(built["net"], "bfloat16")
     step = make_fused_train_step(built["net"], built["loss"],
@@ -222,9 +222,14 @@ def test_a_fused_step_recomputes_layers_that_hold_a_routers_state(config):
             "assignments": 168, "buffer_rows": moe_ops.buffer_rows(
                 42, 4, 16, 4, 1.75), "tile": 128, "width": 32, "hidden": 24,
             "activation": "relu2"}}
-    assert list(ssm_ops.ssm_plans()) == ["b2 t21 h8x8 g2 n16 bfloat16"]
+    assert list(ssm_ops.ssm_plans()) == ["b2 t21 h8x8 g2 n16 bfloat16",
+                                         "conv b2 t21 c128 k4 bfloat16"]
     assert ssm_ops.ssm_plans()["b2 t21 h8x8 g2 n16 bfloat16"][
         "route"] == "xla:shape"       # toy heads: the composition
+    # the toy's convolution is one lane tile wide, which the pair takes
+    # where there is a chip
+    assert ssm_ops.ssm_plans()["conv b2 t21 c128 k4 bfloat16"][
+        "route"] == "xla:no_tpu"
     assert "moe_plans" in profiler.provider_stats()
     losses = [float(step(x, y)) for _ in range(3)]
     assert losses[-1] < losses[0]

@@ -324,6 +324,40 @@ def test_ssd_scan_fwd_bwd(one_chip, for_the_chip, heads, p, groups, n):
         "grid_steps_bwd": 32 * groups if pair else 0}
 
 
+# the two published widths of the mixer's convolution: Nemotron-H's 8,192 +
+# 2 x 8 x 128 channels, Falcon-H1's 4,096 + 2 x 2 x 256
+@pytest.mark.parametrize("channels", [10240, 5120])
+def test_causal_conv1d_fwd_bwd(one_chip, for_the_chip, channels):
+    """The mixer's short convolution at a published width and the
+    benchmark's length (4,096 positions, 4 taps, bfloat16), forward and
+    backward: exactly the Mosaic calls ``causal_conv1d_fwd`` and
+    ``causal_conv1d_bwd``, a lane tile of channels a grid step, and no
+    float32 tensor of the sequence's size in HBM — neither the
+    composition's padded input nor a sum before SiLU kept for the
+    backward."""
+    import re
+    from incubator_mxnet_tpu.ops import ssm_ops
+    s = functools.partial(_spec, one_chip)
+    ssm_ops.ssm_plans(reset=True)
+    pk.kernel_routes(reset=True)
+    text = _compile(_fwd_bwd(ssm_ops.causal_conv1d.fn, 3),
+                    s((1, 4096, channels), BF16), s((channels, 4), BF16),
+                    s((channels,), BF16))
+    assert text.count("tpu_custom_call") == 2
+    assert "causal_conv1d_fwd" in text and "causal_conv1d_bwd" in text
+    assert not re.search(r"f32\[1,40\d\d,%d\]" % channels, text)
+    assert pk.kernel_routes()["causal_conv1d"] == {"kernel": 1}
+    (plan,) = ssm_ops.ssm_plans().values()
+    assert list(ssm_ops.ssm_plans()) == [
+        f"conv b1 t4096 c{channels} k4 bfloat16"]
+    # the bill is under the limit the calls ask for
+    assert 8 * 2 ** 20 < plan.pop("vmem_bytes") <= ssm_ops._CONV_VMEM
+    assert plan == {
+        "route": "kernel", "taps": 4, "channels_a_step": 128,
+        "padded_rows": 0, "grid_steps_fwd": channels // 128,
+        "grid_steps_bwd": channels // 128}
+
+
 def test_flash_attention_with_fewer_key_heads(one_chip, for_the_chip):
     """Grouped-query attention at the published widths and the benchmark's
     length: 20 query heads over 4 key heads, 128 wide, causal, 4,096 keys.
@@ -351,7 +385,7 @@ def test_the_falcon_h1_step_fits_the_chip_with_its_blocks_recomputed(
     parameters with their Adam moments) plans under the chip's memory with
     half a GiB to spare — from shapes alone (``jax.eval_shape`` over the
     configuration's ``build`` and ``make_fused_train_step``: nothing is
-    allocated) — and ``ssm_plans`` holds the step's one signature."""
+    allocated) — and ``ssm_plans`` holds the step's two signatures."""
     import json
     import os
     import sys
@@ -398,8 +432,13 @@ def test_the_falcon_h1_step_fits_the_chip_with_its_blocks_recomputed(
     # and their scans, a group's 16 heads a step of the forward kernel
     # (this shape's backward is the composition's: _SCAN_BWD_STATE_MOST)
     assert text.count("ssd_scan_fwd") >= 8 and "ssd_scan_bwd" not in text
-    (plan,) = ssm_ops.ssm_plans().values()
-    assert list(ssm_ops.ssm_plans()) == ["b1 t4096 h32x128 g2 n256 bfloat16"]
+    plan, conv = ssm_ops.ssm_plans().values()
+    assert list(ssm_ops.ssm_plans()) == [
+        "b1 t4096 h32x128 g2 n256 bfloat16", "conv b1 t4096 c5120 k4 bfloat16"]
+    # the convolutions are the pair's, a lane tile of channels a step
+    assert text.count("causal_conv1d_fwd") >= 8
+    assert text.count("causal_conv1d_bwd") >= 4
+    assert (conv["route"], conv["grid_steps_bwd"]) == ("kernel", 40)
     assert plan == {
         "chunk": 128, "chunks": 32, "heads_a_step": 16, "route": "kernel",
         "state_bytes_saved": 4 * 32 * 32 * 128 * 256, "padded_rows": 0,
@@ -443,7 +482,7 @@ def test_the_nemotron_h_step_fits_the_chip_with_its_layers_recomputed(
     under the chip's memory with room to spare — from shapes alone
     (``jax.eval_shape`` over the configuration's ``build`` and
     ``make_fused_train_step``: nothing is allocated) — and ``moe_plans`` and
-    ``ssm_plans`` hold the step's two signatures."""
+    ``ssm_plans`` hold the step's three signatures."""
     import json
     import os
     import sys
@@ -491,8 +530,11 @@ def test_the_nemotron_h_step_fits_the_chip_with_its_layers_recomputed(
         "t4096 k22 e16/512 w1024 i2688 relu2 bfloat16"]
     assert moe_ops.moe_plans()[
         "t4096 k22 e16/512 w1024 i2688 relu2 bfloat16"]["buffer_rows"] == 7040
-    (plan,) = ssm_ops.ssm_plans().values()
-    assert list(ssm_ops.ssm_plans()) == ["b1 t4096 h128x64 g8 n128 bfloat16"]
+    plan, conv = ssm_ops.ssm_plans().values()
+    assert list(ssm_ops.ssm_plans()) == [
+        "b1 t4096 h128x64 g8 n128 bfloat16",
+        "conv b1 t4096 c10240 k4 bfloat16"]
+    assert (conv["route"], conv["grid_steps_bwd"]) == ("kernel", 80)
     assert plan == {
         "chunk": 128, "chunks": 32, "heads_a_step": 16, "route": "kernel",
         "state_bytes_saved": 4 * 32 * 128 * 64 * 128, "padded_rows": 0,
@@ -502,6 +544,9 @@ def test_the_nemotron_h_step_fits_the_chip_with_its_layers_recomputed(
     assert "rematted_computation" in text
     # five mixer layers: the scan's forward, again, and its backward
     assert text.count("ssd_scan_fwd") >= 10 and "ssd_scan_bwd" in text
+    # and the convolution's, the pair's
+    assert text.count("causal_conv1d_fwd") >= 10
+    assert text.count("causal_conv1d_bwd") >= 5
     # 2 attention layers forward, again and backward; 6 routed layers'
     # grouped matmuls
     assert text.count("flash_attention_fwd") >= 4
